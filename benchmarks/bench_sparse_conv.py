@@ -8,7 +8,7 @@ path's numbers are diffable PR-over-PR:
     kernel_ms_roofline  -- analytic max(compute, memory) kernel time under
                            the blocking and the double-buffered (pipelined)
                            halo DMA schedule, at that layer's preferred
-                           (tm, te, tf) tiling — plus a ``bsr`` schedule
+                           (tm, te) tiling — plus a ``bsr`` schedule
                            row: the BCSR MXU conv at the layer's best
                            block shape + spatial tiling (the MXU-vs-VPU
                            crossover, priced per layer)
@@ -148,7 +148,7 @@ def resolve_plan_fallback(g: ConvGeometry, pe) -> Optional[str]:
         k = g.k_est(pe.pad_to or 8)
         _, reason = resolve_schedule(
             g.m, g.c, g.e, g.f, k, g.r, g.s, g.stride,
-            tm=pe.tm, te=pe.te, tf=pe.tf,
+            tm=pe.tm, te=pe.te,
             fuse_res=pe.fuse and g.residual, pipeline=pe.pipeline,
             value_dtype=pe.value_dtype)
         return reason
@@ -159,7 +159,7 @@ def resolve_plan_fallback(g: ConvGeometry, pe) -> Optional[str]:
         _, reason = resolve_bsr_schedule(
             g.c, g.e, g.f, g.r, g.s, g.stride,
             pe.block_m, pe.block_n, gbm, gbn,
-            te=pe.te, tf=pe.tf, fuse_res=pe.fuse and g.residual,
+            te=pe.te, fuse_res=pe.fuse and g.residual,
             value_dtype=pe.value_dtype)
         return reason
     return None  # dense / lowered / csr-direct never fall back
@@ -179,12 +179,12 @@ def layer_record(g: ConvGeometry) -> Optional[Dict[str, Any]]:
         tiles = tile_candidates(g.m, g.c, g.e, g.f, k, g.r, g.s, g.stride)
     if not tiles:
         return None  # no Pallas tiling at all: layer runs the fallback
-    tm, te, tf = tiles[0]
-    base = dict(tm=tm, pad_to=8, te=te, tf=tf)
+    tm, te = tiles[0]
+    base = dict(tm=tm, pad_to=8, te=te)
     rec: Dict[str, Any] = {
         "name": g.name, "m": g.m, "c": g.c, "h": g.h, "w": g.w, "r": g.r,
         "stride": g.stride, "pad": g.pad, "sparsity": g.sparsity,
-        "tiling": {"tm": tm, "te": te, "tf": tf},
+        "tiling": {"tm": tm, "te": te},
         "schedules": {
             "blocking": _schedule_record(g, Candidate("pallas", **base)),
         },
@@ -199,10 +199,11 @@ def layer_record(g: ConvGeometry) -> Optional[Dict[str, Any]]:
     if bsr is not None:
         brec = _schedule_record(g, bsr)
         brec["block"] = [bsr.block_m, bsr.block_n]
-        brec["te"], brec["tf"] = bsr.te, bsr.tf
+        brec["te"] = bsr.te
         rec["schedules"]["bsr"] = brec
         _with_quantised_rows(g, rec["schedules"], "bsr", bsr)
-    pe = plan_layer(g, mode="roofline", quantize=True)
+    pe = plan_layer(g, mode="roofline", quantize=True,
+                    backend=jax.devices()[0].platform)
     rec["auto_roofline"] = {
         "method": pe.method, "kernel_ms_roofline": pe.est_s * 1e3,
         "block": ([pe.block_m, pe.block_n]

@@ -176,6 +176,7 @@ def _smoke_traced_forward() -> None:
     JSON that passes schema validation."""
     import tempfile
 
+    import jax
     import numpy as np
 
     from repro import telemetry
@@ -191,7 +192,8 @@ def _smoke_traced_forward() -> None:
     rng = np.random.default_rng(0)
     program = lower(micro, (3, 8, 8))
     params = cnn.init_cnn(micro, 3, rng, 8)
-    plan = plan_program(program, batch=1, mode="roofline", cache=PlanCache())
+    plan = plan_program(program, batch=1, mode="roofline", cache=PlanCache(),
+                        backend=jax.devices()[0].platform)
     apply_plan_to_params(params, plan)
     engine = CnnEngine(program, params, plan)
     x = rng.standard_normal((1, 3, 8, 8)).astype(np.float32)
@@ -231,6 +233,7 @@ def _smoke_quantised_forward() -> None:
     must agree with the f32-bank forward to quantisation tolerance."""
     import dataclasses
 
+    import jax
     import numpy as np
 
     from repro import telemetry
@@ -246,7 +249,8 @@ def _smoke_quantised_forward() -> None:
     rng = np.random.default_rng(0)
     program = lower(micro, (3, 8, 8))
     params = cnn.init_cnn(micro, 3, rng, 8)
-    plan = plan_program(program, batch=1, mode="roofline", cache=PlanCache())
+    plan = plan_program(program, batch=1, mode="roofline", cache=PlanCache(),
+                        backend=jax.devices()[0].platform)
     plan = {name: (dataclasses.replace(pe, value_dtype="int8")
                    if pe.method in ("pallas", "bsr") else pe)
             for name, pe in plan.items()}
@@ -283,6 +287,7 @@ def _smoke_chaos_forward() -> None:
     import tempfile
     import warnings
 
+    import jax
     import numpy as np
 
     from repro.engine import init_conv_params, lower
@@ -301,7 +306,7 @@ def _smoke_chaos_forward() -> None:
         cache_path = str(pathlib.Path(td) / "plans.json")
         cache = PlanCache(cache_path)
         plan_program(program, batch=2, mode="roofline", cache=cache,
-                     params=params)
+                     params=params, backend=jax.devices()[0].platform)
         corrupt_plan_cache_file(cache_path, mode="garbage")
         chaos = ChaosInjector(ChaosConfig(
             seed=0, step_fault_rate=0.4, plan_corruption_rate=1.0,

@@ -1,7 +1,7 @@
 """Beyond-paper Fig. 12: tuned-vs-fixed speedup per layer.
 
 For every model, the autotuner (``repro.tuning``) measures each candidate
-(method x (tm, te, tf) x pad_to x fuse) per *distinct* sparse conv geometry
+(method x (tm, te) x pad_to x fuse) per *distinct* sparse conv geometry
 and picks a winner; this table reports, per geometry, the tuned wall time
 against each fixed single-method baseline — the measured counterpart of the
 paper's kernel-customization table (§3.3-3.4).
@@ -17,6 +17,7 @@ from __future__ import annotations
 
 from typing import Dict, List
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -38,7 +39,8 @@ def bench_model(name: str, *, iters: int = 3) -> List[str]:
     program = lower(net, (3, image, image))
     cache = PlanCache()
     plan = plan_program(program, batch=batch, mode="wall",
-                        cache=cache, params=params, iters=iters)
+                        cache=cache, params=params, iters=iters,
+                        backend=jax.devices()[0].platform)
     lines: List[str] = []
     seen: Dict[str, Dict[str, float]] = {}
     totals = {m: 0.0 for m in FIXED}
@@ -65,7 +67,7 @@ def bench_model(name: str, *, iters: int = 3) -> List[str]:
             lines.append(row(
                 f"fig12/{name}/{op.name}", pe.est_s,
                 f"method={pe.method};tm={pe.tm or '-'};"
-                f"te={pe.te or '-'};tf={pe.tf or '-'};"
+                f"te={pe.te or '-'};"
                 f"pad_to={pe.pad_to or '-'};fuse={int(pe.fuse)};"
                 f"stride={op.stride};"
                 f"speedup_vs_dense={fixed['dense'] / pe.est_s:.2f};"

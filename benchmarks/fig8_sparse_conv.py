@@ -116,7 +116,8 @@ def bench_model(name: str, *, iters: int = 3, autotune: bool = False) -> List[st
                                   measure_candidate, plan_program)
         program = lower(net, (3, image, image))
         plan = plan_program(program, batch=batch, mode="wall",
-                            cache=PlanCache(), params=params, iters=iters)
+                            cache=PlanCache(), params=params, iters=iters,
+                            backend=jax.devices()[0].platform)
         t_auto = t_dense_epi = 0.0
         for op in program.conv_ops:
             if op.sparsity == 0:

@@ -152,7 +152,6 @@ def _check_entry_schedule(
             g.stride,
             tm=tm,
             te=entry.get("te"),
-            tf=entry.get("tf"),
             fuse_res=fuse_res,
             pipeline=bool(entry.get("pipeline", False)),
             value_dtype=vdt,
@@ -167,7 +166,7 @@ def _check_entry_schedule(
                     key,
                 )
             )
-        elif entry.get("pipeline", False) and not sched[3]:
+        elif entry.get("pipeline", False) and not sched[2]:
             out.append(
                 _diag(
                     "sched.pipeline_demoted",
@@ -196,7 +195,6 @@ def _check_entry_schedule(
             gbn,
             itemsize=itemsize,
             te=entry.get("te"),
-            tf=entry.get("tf"),
             fuse_res=fuse_res,
             value_dtype=vdt,
         )

@@ -46,7 +46,7 @@ from typing import Any, Dict, List, Optional
 
 from repro.analysis.diagnostics import REASON_RULES, Diagnostic
 from repro.engine.program import ConvOp, Program
-from repro.kernels.budget import halo_extent
+from repro.kernels.window import halo_extent
 from repro.kernels.bsr_conv.ops import resolve_bsr_schedule
 from repro.kernels.sparse_conv.ops import resolve_schedule
 from repro.tuning.planner import geometry_of_op
@@ -123,26 +123,26 @@ def _ell_k(
 def _halo_check(
     op: ConvOp,
     te: int,
-    tf: int,
     *,
     net: Optional[str],
 ) -> List[Diagnostic]:
     """Invariant: a resolved tile's halo'd input window must stay inside
-    the padded input.  ``resolve_*`` clamps te/tf to (e, f), which bounds
-    the halo by the padded extent — this guards that contract."""
+    the padded input.  ``resolve_*`` clamps te to e and a tile spans all f
+    columns, which bounds the halo by the padded extent — this guards that
+    contract."""
     out = []
     hp, wp = op.h + 2 * op.pad, op.w + 2 * op.pad
     if halo_extent(te, op.stride, op.k) > hp or (
-        halo_extent(tf, op.stride, op.k) > wp
+        halo_extent(op.f, op.stride, op.k) > wp
     ):
         out.append(
             Diagnostic(
                 rule="sched.halo_bounds",
                 severity="error",
                 message=(
-                    f"tile ({te}, {tf}) halo "
+                    f"tile ({te}, {op.f}) halo "
                     f"({halo_extent(te, op.stride, op.k)}x"
-                    f"{halo_extent(tf, op.stride, op.k)}) exceeds padded "
+                    f"{halo_extent(op.f, op.stride, op.k)}) exceeds padded "
                     f"input {hp}x{wp}"
                 ),
                 net=net,
@@ -277,7 +277,6 @@ def check_pallas_entry(
         op.stride,
         tm=entry.tm,
         te=entry.te,
-        tf=entry.tf,
         fuse_res=fuse_res,
         pipeline=entry.pipeline,
         value_dtype=vdt,
@@ -289,7 +288,7 @@ def check_pallas_entry(
                 severity="error",
                 message=(
                     f"plan pins pallas (tm={entry.tm} te={entry.te} "
-                    f"tf={entry.tf} pad_to={entry.pad_to} k={k}) but "
+                    f"pad_to={entry.pad_to} k={k}) but "
                     f"dispatch falls back to csr-direct: {reason}"
                 ),
                 net=net,
@@ -297,7 +296,7 @@ def check_pallas_entry(
             )
         )
         return out
-    tm, te, tf, pipeline = sched
+    tm, te, pipeline = sched
     if entry.pipeline and not pipeline:
         out.append(
             Diagnostic(
@@ -305,15 +304,15 @@ def check_pallas_entry(
                 severity="warning",
                 message=(
                     f"plan asks for the double-buffered halo DMA but the "
-                    f"second halo buffer does not fit at (tm={tm}, te={te}, "
-                    f"tf={tf}); the kernel silently runs the blocking "
+                    f"second halo buffer does not fit at (tm={tm}, "
+                    f"te={te}); the kernel silently runs the blocking "
                     f"schedule"
                 ),
                 net=net,
                 layer=op.name,
             )
         )
-    out += _halo_check(op, te, tf, net=net)
+    out += _halo_check(op, te, net=net)
     return out
 
 
@@ -380,7 +379,6 @@ def check_bsr_entry(
         gbn,
         itemsize=_itemsize(dtype),
         te=entry.te,
-        tf=entry.tf,
         fuse_res=fuse_res,
         value_dtype=vdt,
     )
@@ -390,17 +388,15 @@ def check_bsr_entry(
                 rule=REASON_RULES[reason],
                 severity="error",
                 message=(
-                    f"plan pins bsr (block={bm}x{bn} te={entry.te} "
-                    f"tf={entry.tf}) but dispatch falls back to dense: "
-                    f"{reason}"
+                    f"plan pins bsr (block={bm}x{bn} te={entry.te}) but "
+                    f"dispatch falls back to dense: {reason}"
                 ),
                 net=net,
                 layer=op.name,
             )
         )
         return out
-    te, tf = sched
-    out += _halo_check(op, te, tf, net=net)
+    out += _halo_check(op, sched, net=net)
     return out
 
 

@@ -103,6 +103,14 @@ jax.tree_util.register_pytree_node(
     EllConv, EllConv.tree_flatten, EllConv.tree_unflatten)
 
 
+def ell_k(longest_row: int, pad_to: int = 8) -> int:
+    """The ELL row length K of a bank whose longest row holds
+    ``longest_row`` nonzeros: rounded up to a multiple of ``pad_to``, and
+    at least ``pad_to`` (a fully-pruned bank keeps nonzero-width arrays)."""
+    pad_to = max(1, int(pad_to))
+    return max(pad_to, -(-max(1, longest_row) // pad_to) * pad_to)
+
+
 def ell_from_dense_conv(w, pad_to: int = 8, balance: bool = False) -> EllConv:
     """Convert a dense (M, C, R, S) filter bank to ``EllConv``.
 
@@ -126,8 +134,7 @@ def ell_from_dense_conv(w, pad_to: int = 8, balance: bool = False) -> EllConv:
         rows_r.append(ri)
         rows_s.append(si)
         nnz.append(len(ci))
-    k = max(1, max(nnz))
-    k = max(pad_to, ((k + pad_to - 1) // pad_to) * pad_to)
+    k = ell_k(max(nnz), pad_to)
     val = np.zeros((m, k), dtype=w.dtype)
     cid = np.zeros((m, k), dtype=np.int32)
     rid = np.zeros((m, k), dtype=np.int32)

@@ -15,7 +15,6 @@ the pre-engine executor ran — bit-for-bit compatible.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import functools
 import time
@@ -63,7 +62,6 @@ class _Decision:
     method_planned: str           # what the plan/caller asked for
     tm: Optional[int]
     te: Optional[int]
-    tf: Optional[int]
     pipeline: Optional[bool]
     permute: bool
     fuse: bool
@@ -140,6 +138,10 @@ class CnnEngine:
     every plan-pinned Pallas/BCSR schedule is verified to actually
     dispatch — a configuration that would silently fall back at serving
     time raises :class:`repro.analysis.PreflightError` here instead.
+
+    The engine runs on JAX's default device, read once at bind: the Pallas
+    kernels compile for it when it is a TPU and run in interpret mode on
+    any other platform — never interpreted on a TPU.
     """
 
     def __init__(self, program: Program, params: Dict[str, Any],
@@ -148,6 +150,8 @@ class CnnEngine:
         self.program = program
         self.params = params
         self.plan = plan
+        self.platform = jax.devices()[0].platform
+        self.interpret = self.platform != "tpu"
         if strict:
             # Lazy import: repro.analysis imports this module's kernel deps.
             from repro.analysis import PreflightError
@@ -197,7 +201,7 @@ class CnnEngine:
             # (unstructured banks keep nearly every tile and must not be
             # routed to the MXU path on the block-pruned estimate).
             plan = plan_program(self.program, batch=batch, mode="roofline",
-                                params=self.params)
+                                params=self.params, backend=self.platform)
             self._auto_plans[batch] = plan
         return plan
 
@@ -208,7 +212,7 @@ class CnnEngine:
         """Resolve one conv op's dispatch knobs from the plan (or the
         caller's direct method) — the pure-Python half of ``_conv``."""
         auto = method == "auto"
-        tm = te = tf = None
+        tm = te = None
         pipeline = None  # ops.sparse_conv auto-picks when the 2nd halo fits
         permute = False
         block = None     # bsr: None = any prebuilt bank (or the default)
@@ -221,7 +225,7 @@ class CnnEngine:
             method = pe.method if pe is not None else "dense"
             provenance = pe.provenance if pe is not None else "default"
             if pe is not None:
-                tm, te, tf = pe.tm, pe.te, pe.tf
+                tm, te = pe.tm, pe.te
                 pipeline, permute = pe.pipeline, pe.permute
                 if fuse_override is None:
                     fuse = pe.fuse
@@ -267,7 +271,7 @@ class CnnEngine:
                 method = "dense"
                 engine_reason = "value_dtype_mismatch"
         return _Decision(auto=auto, pe=pe, method=method,
-                         method_planned=method_planned, tm=tm, te=te, tf=tf,
+                         method_planned=method_planned, tm=tm, te=te,
                          pipeline=pipeline, permute=permute, fuse=fuse,
                          block=block, value_dtype=value_dtype,
                          quantize_in_trace=quantize_in_trace,
@@ -277,17 +281,21 @@ class CnnEngine:
     def _bcsr_for(self, op: ConvOp, entry: Dict[str, Any], block):
         """The BCSR bank this op runs: the prebuilt ``bcsr_auto`` when its
         block matches, else one blocked from the bound dense weights —
-        built host-side once per (layer, block) and cached on the engine
-        (``entry["w"]`` is a concrete bound array, so the conversion is
-        trace-safe and baked into the compile)."""
+        built host-side once per (layer, block) and cached on the engine.
+        The build is evaluated eagerly even when called inside a trace: the
+        cached bank outlives the trace, so it must hold concrete arrays, not
+        the tracer a staged ``jnp.asarray`` would give (a later retrace of
+        another method or of ``lowered`` would otherwise read a leaked
+        tracer)."""
         bcc = entry.get("bcsr_auto")
         if bcc is not None and (block is None or bcc.block == block):
             return bcc
         key = (op.name, block or DEFAULT_BSR_BLOCK)
         bcc = self._bcc_cache.get(key)
         if bcc is None:
-            bcc = bcsr_conv_from_dense(np.asarray(entry["w"]),
-                                       block=block or DEFAULT_BSR_BLOCK)
+            with jax.ensure_compile_time_eval():
+                bcc = bcsr_conv_from_dense(np.asarray(entry["w"]),
+                                           block=block or DEFAULT_BSR_BLOCK)
             self._bcc_cache[key] = bcc
         return bcc
 
@@ -298,7 +306,7 @@ class CnnEngine:
         entry = self.params[op.name]
         d = self._plan_decision(op, method, plan, fuse_override)
         method, fuse = d.method, d.fuse
-        tm, te, tf, pipeline = d.tm, d.te, d.tf, d.pipeline
+        tm, te, pipeline = d.tm, d.te, d.pipeline
         if d.auto:
             ell = entry.get("ell_auto", entry.get("ell"))
             ell2d = entry.get("ell2d_auto", entry.get("ell2d"))
@@ -338,24 +346,24 @@ class CnnEngine:
         elif method == "csr-direct":
             y = direct_sparse_conv(x, ell, stride=op.stride, padding=op.pad)
         elif method == "pallas":
-            interp = jax.default_backend() != "tpu"
+            interp = self.interpret
             if fuse:
                 return pallas_sparse_conv(
                     x, ell, stride=op.stride, padding=op.pad, tm=tm, te=te,
-                    tf=tf, bias=b, fuse_relu=op.fuse_relu, residual=res,
+                    bias=b, fuse_relu=op.fuse_relu, residual=res,
                     pipeline=pipeline, interpret=interp, layer=op.name)
             y = pallas_sparse_conv(x, ell, stride=op.stride, padding=op.pad,
-                                   tm=tm, te=te, tf=tf, pipeline=pipeline,
+                                   tm=tm, te=te, pipeline=pipeline,
                                    interpret=interp, layer=op.name)
         elif method == "bsr":
-            interp = jax.default_backend() != "tpu"
+            interp = self.interpret
             if fuse:
                 return bsr_conv(
-                    x, bcc, stride=op.stride, padding=op.pad, te=te, tf=tf,
+                    x, bcc, stride=op.stride, padding=op.pad, te=te,
                     bias=b, fuse_relu=op.fuse_relu, residual=res,
                     interpret=interp, layer=op.name)
             y = bsr_conv(x, bcc, stride=op.stride, padding=op.pad, te=te,
-                         tf=tf, interpret=interp, layer=op.name)
+                         interpret=interp, layer=op.name)
         else:
             raise ValueError(method)
         # Unfused epilogue: the exact op sequence of the pre-engine executor.
@@ -408,6 +416,17 @@ class CnnEngine:
         still compiles exactly once.  ``rung`` is a label recorded on the
         forward's :class:`ExecutionReport` naming the ladder rung executed.
         """
+        fn, plan, jit_hit = self._jitted(x, method, fuse, plan_override)
+        if telemetry.is_enabled():
+            # Dispatch-time observation: the report is built from the same
+            # _plan_decision the trace uses, never from inside the jit.
+            self._record_forward(tuple(x.shape), str(x.dtype), method, plan,
+                                 fuse, jit_hit, rung=rung)
+        return fn(x)
+
+    def _jitted(self, x, method: str, fuse: Optional[bool],
+                plan_override: Optional[Dict[str, Any]]):
+        """(jitted forward, resolved plan, memo hit) for one call shape."""
         if method not in METHODS:
             raise ValueError(f"unknown method {method!r}; one of {METHODS}")
         plan = plan_override if plan_override is not None else self.plan
@@ -420,12 +439,16 @@ class CnnEngine:
             fn = jax.jit(functools.partial(
                 self._execute, method=method, plan=plan, fuse_override=fuse))
             self._fns[key] = fn
-        if telemetry.is_enabled():
-            # Dispatch-time observation: the report is built from the same
-            # _plan_decision the trace uses, never from inside the jit.
-            self._record_forward(tuple(x.shape), str(x.dtype), method, plan,
-                                 fuse, jit_hit, rung=rung)
-        return fn(x)
+        return fn, plan, jit_hit
+
+    def lowered(self, x, method: str = "dense", *,
+                fuse: Optional[bool] = None,
+                plan_override: Optional[Dict[str, Any]] = None):
+        """The ``jax.stages.Lowered`` forward :meth:`__call__` would run for
+        ``x`` (an array or a ``ShapeDtypeStruct``) — ``.compile()`` then
+        gives the device program, e.g. to count its Pallas custom calls."""
+        fn, _, _ = self._jitted(x, method, fuse, plan_override)
+        return fn.lower(x)
 
     # -- observability -----------------------------------------------------
 
@@ -483,32 +506,31 @@ class CnnEngine:
             k = ell.k if ell is not None else g.k_est(pad_to or 8)
             sched, kreason = resolve_schedule(
                 op.m, op.c, op.e, op.f, k, op.k, op.k, op.stride, tm=d.tm,
-                te=d.te, tf=d.tf, fuse_res=fuse_res, pipeline=d.pipeline,
+                te=d.te, fuse_res=fuse_res, pipeline=d.pipeline,
                 value_dtype=d.value_dtype)
             if sched is None:
                 reason, executed = kreason, "csr-direct"
             else:
-                tm, te, tf, pipe = sched
-                tiling = {"tm": tm, "te": te, "tf": tf, "pipeline": pipe}
+                tm, te, pipe = sched
+                tiling = {"tm": tm, "te": te, "pipeline": pipe}
         elif executed == "bsr":
             bcc = self._bcsr_for(op, entry, d.block)
             gbm, kb, bm, bn = bcc.blocks.shape
             itemsize = 2 if dtype in ("bfloat16", "float16") else 4
             sched, kreason = resolve_bsr_schedule(
                 op.c, op.e, op.f, op.k, op.k, op.stride, bm, bn, gbm, kb,
-                itemsize=itemsize, te=d.te, tf=d.tf, fuse_res=fuse_res,
+                itemsize=itemsize, te=d.te, fuse_res=fuse_res,
                 value_dtype=d.value_dtype)
             if sched is None:
                 reason, executed = kreason, "dense"
             else:
-                te, tf = sched
-                tiling = {"te": te, "tf": tf, "block_m": bm, "block_n": bn}
+                tiling = {"te": sched, "block_m": bm, "block_n": bn}
         # Attribute cost at the schedule that actually runs — a fallback op
         # is charged for its fallback path, not the method it asked for.
         vdtype = d.value_dtype if executed in ("pallas", "bsr") else "float32"
         cand = Candidate(
             method=executed, tm=tiling.get("tm"), pad_to=pad_to,
-            te=tiling.get("te"), tf=tiling.get("tf"),
+            te=tiling.get("te"),
             fuse=d.fuse if executed in ("pallas", "bsr") else False,
             pipeline=bool(tiling.get("pipeline", False)),
             permute=d.permute if executed == "pallas" else False,
@@ -554,7 +576,7 @@ class CnnEngine:
                       fuse: Optional[bool] = None) -> jax.Array:
         """Opt-in timed mode: execute op-by-op with ``block_until_ready``
         at every op boundary, recording real per-op wall spans on the
-        tracer's ``wall`` lane and (when available) wrapping each op in a
+        tracer's ``wall`` lane and wrapping each op in a
         ``jax.profiler`` named scope so XLA profiles map back to layer
         names.
 
@@ -574,15 +596,12 @@ class CnnEngine:
                                     plan, fuse, jit_hit=None)
         report.timed = True
         tracer = telemetry.get_tracer()
-        annotate = getattr(jax.profiler, "TraceAnnotation", None)
         walls: Dict[str, float] = {}
         vals: Dict[int, jax.Array] = {0: x}
         for op in self.program.ops:
             name = getattr(op, "name", None) or f"{type(op).__name__}:{op.out}"
-            scope = (annotate(name) if annotate is not None
-                     else contextlib.nullcontext())
             t0 = time.perf_counter()
-            with scope:
+            with jax.profiler.TraceAnnotation(name):
                 vals[op.out] = self._exec_op(op, vals, method, plan, fuse)
                 jax.block_until_ready(vals[op.out])
             dt = time.perf_counter() - t0

@@ -15,11 +15,13 @@ tile.
 
 Mechanics:
 
-  * grid = (N, ceil(E/TE), ceil(F/TF), gbm, KB) with KB innermost so the
-    (bm, TE, TF) f32 output block stays VMEM-resident and accumulates
-    across the kept weight tiles of its block-row (the ``bsr_matmul``
-    accumulation pattern, spatially tiled).
-  * the halo'd (C, halo_h, halo_w) input block for one spatial cell is
+  * grid = (N, ceil(E/TE), gbm, KB) with KB innermost so the (bm, TE*F)
+    f32 output block stays VMEM-resident and accumulates across the kept
+    weight tiles of its block-row (the ``bsr_matmul`` accumulation
+    pattern, tiled over output rows).  The output is laid out flat,
+    (N, M, E*F), so the accumulator fills the 128 vector lanes whatever
+    the feature map's width; the wrapper reshapes it back to NCHW.
+  * the halo'd input block for one band of TE output rows is
     DMA'd HBM->VMEM once — at the cell's first (mt, kb) step — and reused
     by every weight tile of every block-row of that cell (the ELL kernel's
     staging discipline; overlapping halo blocks cannot be expressed with
@@ -28,12 +30,13 @@ Mechanics:
   * per kept tile, the *gather* stage decodes each of the tile's bn flat
     weight columns ``j = blockcol*bn + jl`` into ``(c, r, s)`` (two static
     divmods — the same index arithmetic weight stretching trades bytes for)
-    and writes the strided (TE, TF) input window into row ``jl`` of a
-    (bn, TE, TF) VMEM patch buffer: an im2col patch tile, built on-chip
+    and writes the strided (TE, F) input window into row ``jl`` of a
+    (bn, TE, F) VMEM patch buffer: an im2col patch tile, built on-chip
     from the staged halo block instead of materialised in HBM (the
     bandwidth waste the paper's direct method exists to remove).
-  * the *contract* stage is one ``dot_general`` of the (bm, bn) weight tile
-    against the (bn, TE, TF) patch tile with f32 accumulation — MXU work.
+  * the *contract* stage is one 2-D matmul of the (bm, bn) weight tile
+    against the patch tile flattened to (bn, TE*F), with f32 accumulation
+    — MXU work (Mosaic contracts 2-D operands only).
     The gather is VPU work; the autotuner's roofline prices exactly this
     gather-vs-systolic tradeoff (``tuning/measure.py:_bsr_terms``).
   * rows shorter than KB mask the tail via ``pl.when`` on ``nblocks``;
@@ -43,10 +46,12 @@ Mechanics:
     runs on the resident f32 accumulator at the last KB step — one output
     write, exactly like the ELL kernel's epilogue.
 
-Strides and edge tiles follow the ELL kernel: dynamic-start windows with a
-static ``[::stride]`` slice, ceiling-division spatial grids with masked
-out-of-range writes, and input zero-padding so every halo window stays in
-bounds.
+Strides, windows and edge tiles follow the ELL kernel: the wrapper's
+column phase split with a static-lane, sublane-strided window load per
+decoded column (``kernels/window.py``), a ceiling-division
+row grid with masked out-of-range writes, and input zero-padding so every
+halo window stays in bounds.  Block shapes follow the TPU's (8, 128) tile
+rule: the flat output block's TE*F is a multiple of 128 or covers E*F.
 """
 from __future__ import annotations
 
@@ -58,16 +63,18 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.window import load_window, phase_split, stage_shape
+
 
 def _kernel(blockcol_ref, nblocks_ref,   # scalar prefetch (SMEM)
-            x_ref,                       # HBM/ANY: halo-padded input
+            x_ref,                       # HBM: phase-split padded input
             w_ref,                       # VMEM in: (1, 1, bm, bn)
-            b_ref,                       # VMEM in: (1, bm) f32 bias
+            b_ref,                       # VMEM in: (1, bm, 1) f32 bias
             *rest,                       # [scale_ref,] [res_ref,] out_ref,
                                          # xblk, patch, sem
-            bm: int, bn: int, rs: int, s: int, c_in: int, stride: int,
-            te: int, tf: int, halo_h: int, halo_w: int,
-            fuse_relu: bool, has_res: bool, quantized: bool):
+            bn: int, rs: int, s: int, c_in: int, stride: int, te: int,
+            f: int, halo_h: int, fuse_relu: bool, has_res: bool,
+            quantized: bool):
     rest = list(rest)
     scale_ref = rest.pop(0) if quantized else None
     if has_res:
@@ -77,20 +84,17 @@ def _kernel(blockcol_ref, nblocks_ref,   # scalar prefetch (SMEM)
         out_ref, xblk_ref, patch_ref, sem = rest
     ni = pl.program_id(0)
     et = pl.program_id(1)
-    ft = pl.program_id(2)
-    mt = pl.program_id(3)
-    kb = pl.program_id(4)
-    kb_n = pl.num_programs(4)
+    mt = pl.program_id(2)
+    kb = pl.program_id(3)
+    kb_n = pl.num_programs(3)
 
-    # Stage the halo'd input block once per (image, spatial tile); the
-    # (mt, kb) dims are innermost, so it persists for every weight tile of
-    # this cell (TPU grids run sequentially).
+    # Stage the halo'd input band once per (image, row tile); the (mt, kb)
+    # dims are innermost, so it persists for every weight tile of this
+    # cell (TPU grids run sequentially).
     @pl.when(jnp.logical_and(mt == 0, kb == 0))
     def _stage():
         dma = pltpu.make_async_copy(
-            x_ref.at[ni, :, pl.ds(et * te * stride, halo_h),
-                     pl.ds(ft * tf * stride, halo_w)],
-            xblk_ref, sem)
+            x_ref.at[ni, :, pl.ds(et * te * stride, halo_h)], xblk_ref, sem)
         dma.start()
         dma.wait()
 
@@ -98,19 +102,14 @@ def _kernel(blockcol_ref, nblocks_ref,   # scalar prefetch (SMEM)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    # Dynamic-start window extent for a static [::stride] landing exactly on
-    # the TE (resp. TF) output positions of this tile.
-    e_ext = (te - 1) * stride + 1
-    f_ext = (tf - 1) * stride + 1
-
     @pl.when(kb < nblocks_ref[mt])
     def _accum():
         j0 = blockcol_ref[mt, kb] * bn
-        # Gather (VPU): build the (bn, TE, TF) im2col patch tile for this
+
+        # Gather (VPU): build the (bn, TE, F) im2col patch tile for this
         # block column from the staged halo block, one decoded weight
-        # column per row.  jl is static (unrolled), j0 is a prefetched
-        # scalar.
-        for jl in range(bn):
+        # column per row.
+        def gather(jl, _):
             j = j0 + jl
             cj = j // rs
             rem = j - cj * rs
@@ -119,28 +118,29 @@ def _kernel(blockcol_ref, nblocks_ref,   # scalar prefetch (SMEM)
             # Right-padding columns (j >= C*R*S) carry zero weights; clamp
             # the channel so their gather stays in bounds (value is inert).
             cj = jnp.minimum(cj, c_in - 1)
-            win = xblk_ref[cj, pl.ds(r, e_ext), pl.ds(ss, f_ext)]
-            patch_ref[jl] = win[::stride, ::stride]
-        # Contract (MXU): one (bm, bn) x (bn, TE*TF) systolic pass, f32
+            patch_ref[jl] = load_window(xblk_ref, (), cj, r, ss, s=s,
+                                        stride=stride, te=te, f=f)
+            return 0
+
+        lax.fori_loop(0, bn, gather, 0)
+        # Contract (MXU): one (bm, bn) x (bn, TE*F) systolic pass, f32
         # accumulate into the resident output block.
-        contrib = lax.dot_general(
-            w_ref[0, 0].astype(jnp.float32),
-            patch_ref[...].astype(jnp.float32),
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        patch = patch_ref[...].astype(jnp.float32).reshape(bn, te * f)
+        contrib = jnp.dot(w_ref[0, 0].astype(jnp.float32), patch,
+                          preferred_element_type=jnp.float32)
         if quantized:
             # Dequantise after the contraction: the int8/fp8 tile is
             # contracted as-is in f32 and each output row's contribution is
             # scaled by its per-channel f32 scale before accumulating —
             # accumulation stays f32 throughout.
-            contrib = scale_ref[0][:, None, None] * contrib
+            contrib = scale_ref[0] * contrib
         out_ref[0] += contrib
 
     # Fused epilogue on the resident f32 accumulator at the last KB step:
     # one output write instead of separate bias / residual / ReLU passes.
     @pl.when(kb == kb_n - 1)
     def _epilogue():
-        acc = out_ref[0] + b_ref[0][:, None, None]
+        acc = out_ref[0] + b_ref[0]
         if has_res:
             acc = acc + res_ref[0].astype(jnp.float32)
         if fuse_relu:
@@ -150,14 +150,14 @@ def _kernel(blockcol_ref, nblocks_ref,   # scalar prefetch (SMEM)
 
 @functools.partial(
     jax.jit,
-    static_argnames=("rs", "s", "e", "f", "stride", "te", "tf",
-                     "fuse_relu", "interpret"))
+    static_argnames=("rs", "s", "e", "f", "stride", "te", "fuse_relu",
+                     "interpret"))
 def bsr_conv_pallas(xpad: jax.Array, blocks: jax.Array, blockcol: jax.Array,
                     nblocks: jax.Array, bias: jax.Array,
                     residual: jax.Array | None = None,
                     scale: jax.Array | None = None, *, rs: int, s: int,
                     e: int, f: int, stride: int = 1, te: int | None = None,
-                    tf: int | None = None, fuse_relu: bool = False,
+                    fuse_relu: bool = False,
                     interpret: bool = False) -> jax.Array:
     """Launch the BCSR MXU conv kernel.
 
@@ -176,10 +176,12 @@ def bsr_conv_pallas(xpad: jax.Array, blocks: jax.Array, blockcol: jax.Array,
                 contribution is scaled by its rows' scales before the f32
                 accumulate.
       rs, s:    R*S and S of the original filter bank (column decode).
-      e, f:     output spatial dims; stride applied in-kernel.
-      te, tf:   output spatial tile dims (default: whole output).  Need not
-                divide e/f — edge tiles use ceiling-division grids + masked
-                writes.
+      e, f:     output spatial dims.
+      stride:   conv stride (>= 1).
+      te:       output row tile (default: all E rows).  Need not divide E —
+                edge tiles use a ceiling-division grid + masked writes;
+                TE*F must be a multiple of 128 unless TE covers E.
+                Columns are never tiled (Mosaic stages whole lane rows).
       fuse_relu: clamp the accumulator in-kernel (the fused epilogue).
 
     Returns: (N, gbm*bm, E, F) float32 — callers slice to the true M.
@@ -187,54 +189,49 @@ def bsr_conv_pallas(xpad: jax.Array, blocks: jax.Array, blockcol: jax.Array,
     n, c, hp, wp = xpad.shape
     gbm, kb_dim, bm, bn = blocks.shape
     te = e if te is None else min(te, e)
-    tf = f if tf is None else min(tf, f)
+    if te < e and (te * f) % 128:
+        raise ValueError(f"row tile te={te} x F={f} is not a multiple of "
+                         "128 lanes")
     r = rs // s
-    halo_h = (te - 1) * stride + r
-    halo_w = (tf - 1) * stride + s
+    planes, halo_h, wq = stage_shape(c, r, s, stride, te, f,
+                                     xpad.dtype.itemsize)
     et_n = pl.cdiv(e, te)
-    ft_n = pl.cdiv(f, tf)
-    # Zero-pad so the *last* tile's halo window stays in bounds; the extra
-    # rows/cols only ever feed output positions >= E/F, which Pallas drops.
-    need_h = (et_n * te - 1) * stride + r
-    need_w = (ft_n * tf - 1) * stride + s
-    if need_h > hp or need_w > wp:
-        xpad = jnp.pad(xpad, ((0, 0), (0, 0), (0, max(0, need_h - hp)),
-                              (0, max(0, need_w - wp))))
-    grid = (n, et_n, ft_n, gbm, kb_dim)
+    xs = phase_split(xpad, s=s, stride=stride,
+                     rows=(et_n - 1) * te * stride + halo_h, cols=wq)
+    grid = (n, et_n, gbm, kb_dim)
     has_res = residual is not None
     quantized = scale is not None
+    row_spec = pl.BlockSpec((1, bm, 1), lambda ni, et, mt, kb, *_: (mt, 0, 0))
+    flat_spec = pl.BlockSpec((1, bm, te * f),
+                             lambda ni, et, mt, kb, *_: (ni, mt, et))
     in_specs = [
-        pl.BlockSpec(memory_space=pltpu.ANY),
-        pl.BlockSpec((1, 1, bm, bn), lambda ni, et, ft, mt, kb, *_: (mt, kb, 0, 0)),
-        pl.BlockSpec((1, bm), lambda ni, et, ft, mt, kb, *_: (mt, 0)),
+        pl.BlockSpec(memory_space=pltpu.HBM),
+        pl.BlockSpec((1, 1, bm, bn), lambda ni, et, mt, kb, *_: (mt, kb, 0, 0)),
+        row_spec,
     ]
-    inputs = [blockcol, nblocks, xpad, blocks, bias]
+    inputs = [blockcol, nblocks, xs, blocks, bias.reshape(gbm, bm, 1)]
     if quantized:
-        in_specs.append(pl.BlockSpec(
-            (1, bm), lambda ni, et, ft, mt, kb, *_: (mt, 0)))
-        inputs.append(scale)
+        in_specs.append(row_spec)
+        inputs.append(scale.reshape(gbm, bm, 1))
     if has_res:
-        in_specs.append(pl.BlockSpec(
-            (1, bm, te, tf), lambda ni, et, ft, mt, kb, *_: (ni, mt, et, ft)))
-        inputs.append(residual)
-    return pl.pallas_call(
-        functools.partial(_kernel, bm=bm, bn=bn, rs=rs, s=s, c_in=c,
-                          stride=stride, te=te, tf=tf, halo_h=halo_h,
-                          halo_w=halo_w, fuse_relu=fuse_relu, has_res=has_res,
-                          quantized=quantized),
+        in_specs.append(flat_spec)
+        inputs.append(residual.reshape(n, gbm * bm, e * f))
+    out = pl.pallas_call(
+        functools.partial(_kernel, bn=bn, rs=rs, s=s, c_in=c, stride=stride,
+                          te=te, f=f, halo_h=halo_h, fuse_relu=fuse_relu,
+                          has_res=has_res, quantized=quantized),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
             in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, bm, te, tf),
-                lambda ni, et, ft, mt, kb, *_: (ni, mt, et, ft)),
+            out_specs=flat_spec,
             scratch_shapes=[
-                pltpu.VMEM((c, halo_h, halo_w), xpad.dtype),
-                pltpu.VMEM((bn, te, tf), xpad.dtype),
+                pltpu.VMEM((planes, halo_h, wq), xpad.dtype),
+                pltpu.VMEM((bn, te, f), xpad.dtype),
                 pltpu.SemaphoreType.DMA,
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((n, gbm * bm, e, f), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n, gbm * bm, e * f), jnp.float32),
         interpret=interpret,
     )(*inputs)
+    return out.reshape(n, gbm * bm, e, f)

@@ -1,8 +1,10 @@
 """Jit'd public wrapper around the BCSR MXU conv kernel.
 
-Handles: input padding (pad_in), output spatial tile selection (te, tf) with
-the halo'd-block VMEM feasibility model, channel padding (the format blocks
-M up to gbm*bm — bias and residual are padded in, the output sliced back),
+Handles: input padding (pad_in), output row tile selection (te; the kernel
+always computes whole output rows) with the halo'd-block VMEM
+feasibility model and the TPU's block rule (``budget.bsr_tiling_ok``),
+channel padding (the format blocks M up to gbm*bm — bias and residual are
+padded in, the output sliced back),
 the dtype policy (bf16/f32 in, f32 accumulate, cast back on exit), the fused
 epilogue (bias / ReLU / bottleneck residual on the f32 accumulator,
 one output write), and the fallback to the dense-reconstruction conv — with
@@ -23,11 +25,11 @@ import jax.numpy as jnp
 from repro.core.direct_conv import out_spatial
 from repro.core.sparse_format import BcsrConv, bcsr_conv_to_dense
 from repro.kernels import budget
-from repro.kernels.budget import (SMEM_BUDGET, VMEM_BUDGET, halo_extent,
-                                  value_itemsize)
+from repro.kernels.budget import SMEM_BUDGET, VMEM_BUDGET, value_itemsize
 from repro.kernels.bsr_conv.kernel import bsr_conv_pallas
 from repro.kernels.bsr_conv.ref import bsr_conv_ref
 from repro.kernels.sparse_conv.ops import apply_epilogue, spatial_candidates
+from repro.kernels.window import halo_extent
 from repro.telemetry.fallback import record_fallback
 
 # The candidate (bm, bn) block shapes the autotuner enumerates: bn pinned to
@@ -46,17 +48,18 @@ def bsr_smem_fits(gbm: int, kb: int) -> bool:
 
 
 def bsr_tiling_fits(c: int, r: int, s: int, stride: int, bm: int, bn: int,
-                    te: int, tf: int, itemsize: int = 4,
+                    te: int, f: int, itemsize: int = 4,
                     fuse_res: bool = False,
                     value_itemsize: Optional[int] = None,
                     quantized: bool = False) -> bool:
-    """Whether one (te, tf) spatial tiling's working set — halo'd input
-    block + (bm, bn) weight tile + (bn, te, tf) patch tile + f32 out tile
-    (+ the residual input tile when fused, + the (1, bm) f32 scale tile for
-    a quantised bank) — fits the VMEM budget (``repro.kernels.budget``
-    arithmetic, this module's budget alias).  ``value_itemsize`` prices the
-    weight tile at its storage width (defaults to the input itemsize)."""
-    return budget.bsr_tiling_fits(c, r, s, stride, bm, bn, te, tf,
+    """Whether one row tiling's working set over F output columns — halo'd
+    input block + (bm, bn) weight tile + (bn, te, F) patch tile + f32 out tile (+ the
+    residual input tile when fused, + the (bm, 1) f32 scale tile for a
+    quantised bank) — fits the VMEM budget (``repro.kernels.budget``
+    arithmetic, this module's budget alias).  ``value_itemsize`` prices
+    the weight tile at its storage width (defaults to the input
+    itemsize)."""
+    return budget.bsr_tiling_fits(c, r, s, stride, bm, bn, te, f,
                                   itemsize=itemsize, fuse_res=fuse_res,
                                   value_itemsize=value_itemsize,
                                   quantized=quantized,
@@ -67,24 +70,21 @@ def bsr_tile_candidates(c: int, e: int, f: int, r: int, s: int, stride: int,
                         bm: int, bn: int, itemsize: int = 4,
                         fuse_res: bool = False,
                         value_itemsize: Optional[int] = None,
-                        quantized: bool = False) -> List[Tuple[int, int]]:
-    """All (te, tf) spatial tilings whose VMEM working set fits, preferred
-    first: fewest spatial cells (least halo re-fetch and least per-cell
-    patch re-gather), then least total staged input traffic."""
-    out: List[Tuple[int, int]] = []
-    for te in spatial_candidates(e):
-        for tf in spatial_candidates(f):
-            if bsr_tiling_fits(c, r, s, stride, bm, bn, te, tf,
+                        quantized: bool = False) -> List[int]:
+    """All blockable row tiles ``te`` whose VMEM working set fits,
+    preferred first (a tile always spans all F columns): fewest spatial
+    cells (least halo re-fetch and least per-cell patch re-gather), then
+    least total staged input traffic."""
+    out = [te for te in spatial_candidates(e)
+           if budget.bsr_tiling_ok(e, f, te)
+           and bsr_tiling_fits(c, r, s, stride, bm, bn, te, f,
                                itemsize=itemsize, fuse_res=fuse_res,
                                value_itemsize=value_itemsize,
-                               quantized=quantized):
-                out.append((te, tf))
+                               quantized=quantized)]
 
-    def pref(cand: Tuple[int, int]) -> Tuple[int, int]:
-        te, tf = cand
-        cells = -(-e // te) * (-(-f // tf))
-        staged = cells * c * halo_extent(te, stride, r) * halo_extent(tf, stride, s)
-        return (cells, staged)
+    def pref(te: int) -> Tuple[int, int]:
+        cells = -(-e // te)
+        return (cells, cells * halo_extent(te, stride, r))
 
     return sorted(out, key=pref)
 
@@ -92,13 +92,12 @@ def bsr_tile_candidates(c: int, e: int, f: int, r: int, s: int, stride: int,
 def resolve_bsr_schedule(c: int, e: int, f: int, r: int, s: int, stride: int,
                          bm: int, bn: int, gbm: int, kb: int, *,
                          itemsize: int = 4, te: Optional[int] = None,
-                         tf: Optional[int] = None, fuse_res: bool = False,
+                         fuse_res: bool = False,
                          value_dtype: str = "float32",
-                         ) -> Tuple[Optional[Tuple[int, int]],
-                                    Optional[str]]:
+                         ) -> Tuple[Optional[int], Optional[str]]:
     """The dispatch decision ``bsr_conv`` makes, as a pure function.
 
-    Returns ``((te, tf), None)`` for the spatial tiling the MXU kernel
+    Returns ``(te, None)`` for the row tile the MXU kernel
     would run, or ``(None, reason)`` — a ``telemetry.fallback`` reason
     code — when the layer falls back to the dense-reconstruction conv.
     The engine's ExecutionReport and the benchmark's zero-fallback
@@ -113,40 +112,37 @@ def resolve_bsr_schedule(c: int, e: int, f: int, r: int, s: int, stride: int,
     quantized = vsize == 1
     if not bsr_smem_fits(gbm, kb):
         return None, "smem_infeasible"
-    if te is not None and tf is not None:
-        # Fully-specified tiling (tuned plan / caller override): honor it
-        # when it fits, never launch an over-budget kernel.
-        te, tf = min(te, e), min(tf, f)
-        if not bsr_tiling_fits(c, r, s, stride, bm, bn, te, tf,
-                               itemsize=itemsize, fuse_res=fuse_res,
-                               value_itemsize=vsize, quantized=quantized):
-            return None, "no_feasible_tiling"
-    else:
-        cands = bsr_tile_candidates(c, e, f, r, s, stride, bm, bn,
+    if te is not None:
+        # Pinned row tile (tuned plan / caller override): honor it when it
+        # is blockable and fits, never launch an over-budget or
+        # uncompilable kernel.
+        te = min(te, e)
+        if not (budget.bsr_tiling_ok(e, f, te)
+                and bsr_tiling_fits(c, r, s, stride, bm, bn, te, f,
                                     itemsize=itemsize, fuse_res=fuse_res,
                                     value_itemsize=vsize,
-                                    quantized=quantized)
-        if te is not None:
-            cands = [t for t in cands if t[0] == min(te, e)]
-        if tf is not None:
-            cands = [t for t in cands if t[1] == min(tf, f)]
-        if not cands:
+                                    quantized=quantized)):
             return None, "no_feasible_tiling"
-        te, tf = cands[0]
-    return (te, tf), None
+        return te, None
+    cands = bsr_tile_candidates(c, e, f, r, s, stride, bm, bn,
+                                itemsize=itemsize, fuse_res=fuse_res,
+                                value_itemsize=vsize, quantized=quantized)
+    if not cands:
+        return None, "no_feasible_tiling"
+    return cands[0], None
 
 
 def bsr_conv(x: jax.Array, bc: BcsrConv, *, stride: int = 1,
              padding: int = 0, te: Optional[int] = None,
-             tf: Optional[int] = None, bias: Optional[jax.Array] = None,
+             bias: Optional[jax.Array] = None,
              fuse_relu: bool = False, residual: Optional[jax.Array] = None,
              interpret: bool = False,
              layer: Optional[str] = None) -> jax.Array:
     """Block-sparse convolution + fused epilogue on the MXU.
 
     (N, C, H, W) input, BCSR filter bank for (M, C, R, S) weights ->
-    (N, M, E, F) in x.dtype.  Any stride >= 1 runs in-kernel; te/tf default
-    to the preferred feasible spatial tiling and are the knobs the
+    (N, M, E, F) in x.dtype.  Any stride >= 1 runs in-kernel; te defaults
+    to the preferred feasible row tile and is the knob the
     ``repro.tuning`` autotuner turns (together with the format's block
     shape).  Falls back to the dense-reconstruction conv — with the
     identical epilogue applied unfused — when the block-column table busts
@@ -174,11 +170,11 @@ def bsr_conv(x: jax.Array, bc: BcsrConv, *, stride: int = 1,
 
     sched, reason = resolve_bsr_schedule(c, e, f, r, s, stride, bm, bn,
                                          gbm, kb_dim, itemsize=itemsize,
-                                         te=te, tf=tf, fuse_res=fuse_res,
+                                         te=te, fuse_res=fuse_res,
                                          value_dtype=bc.value_dtype)
     if sched is None:
         return fallback(reason)
-    te, tf = sched
+    te = sched
     xpad = jnp.pad(x, ((0, 0), (0, 0), (padding, padding), (padding, padding)))
     # Channel padding: the kernel computes gbm*bm output channels; bias and
     # residual are padded to match, the result sliced back to M.
@@ -191,6 +187,6 @@ def bsr_conv(x: jax.Array, bc: BcsrConv, *, stride: int = 1,
         res = jnp.pad(res, ((0, 0), (0, mpad - m), (0, 0), (0, 0)))
     out = bsr_conv_pallas(
         xpad, bc.blocks, bc.blockcol, bc.nblocks, b, res, scale=bc.scale,
-        rs=r * s, s=s, e=e, f=f, stride=stride, te=te, tf=tf,
+        rs=r * s, s=s, e=e, f=f, stride=stride, te=te,
         fuse_relu=fuse_relu, interpret=interpret)
     return out[:, :m].astype(x.dtype)

@@ -1,7 +1,7 @@
 """Jit'd public wrapper around the direct sparse conv Pallas kernel.
 
 Handles: input padding (pad_in), index packing, tile selection — output
-channels ``tm`` and output spatial tiles ``(te, tf)``, the paper's
+channels ``tm`` and output row tiles ``te``, the paper's
 kernel-customisation table — dtype policy (bf16/f32 in, f32 accumulate),
 the fused epilogue (bias / ReLU / bottleneck residual applied to the f32
 accumulator in-kernel, one output write instead of three HBM passes), the
@@ -16,10 +16,12 @@ budget or for which no VMEM-feasible tiling exists — the fallback applies
 the same epilogue unfused, so ``sparse_conv`` is a complete conv+epilogue
 operator either way.
 
-Strided layers and feature maps larger than VMEM run through the Pallas
-kernel: the kernel tiles the output spatially with halo'd input blocks and
-applies the stride in-kernel, so the old stride==1 / whole-image-in-VMEM
-restrictions are gone.
+Strided layers and tall feature maps run through the Pallas kernel: the
+kernel tiles the output rows with halo'd input bands, and the wrapper's
+column phase split lets it read every strided window as a static lane
+slice.  Only tilings the TPU can block are emitted (``budget.ell_tiling_ok``:
+channel tiles a multiple of 8 or all of M, row tiles a multiple of 8 or all
+of E, whole output rows).
 """
 from __future__ import annotations
 
@@ -34,9 +36,10 @@ from repro.core.sparse_format import (EllConv, dequantize,
                                       ell_from_dense_conv,
                                       inverse_permutation)
 from repro.kernels import budget
-from repro.kernels.budget import (halo_extent,  # noqa: F401  (re-export)
-                                  value_itemsize)
+from repro.kernels.budget import (channel_tile_ok, value_itemsize,
+                                  vmem_tile_bytes)
 from repro.kernels.sparse_conv.kernel import sparse_conv_pallas
+from repro.kernels.window import halo_extent
 from repro.telemetry.fallback import record_fallback
 
 # Budget constants live in ``repro.kernels.budget`` (one source of truth for
@@ -50,69 +53,85 @@ _SMEM_BUDGET = budget.SMEM_BUDGET
 VMEM_BUDGET = _VMEM_BUDGET
 SMEM_BUDGET = _SMEM_BUDGET
 
-_TM_LADDER = (128, 64, 32, 16, 8, 4, 2, 1)
-# Output spatial tile ladder (besides the untiled full extent).
+_TM_LADDER = (128, 64, 32, 16, 8)
+# Output row tile ladder (besides the untiled full extent).
 _SPATIAL_LADDER = (128, 64, 32, 16, 8)
 
 
-def smem_fits(m: int, k: int, quantized: bool = False) -> bool:
-    """All scalar-prefetched operands fit the SMEM budget: packed indices
-    (M*K int32), the int32 nnz row (M*4 — the kernel's per-row loop bounds;
+def tm_ladder(m: int) -> List[int]:
+    """Legal channel tiles for M, largest first: the ladder's multiples of
+    8 that divide M, plus M itself when it is small or the only legal
+    tile."""
+    tms = {t for t in _TM_LADDER if channel_tile_ok(m, t)}
+    if m <= _TM_LADDER[0] or m % 8:
+        tms.add(m)
+    return sorted(tms, reverse=True)
+
+
+def smem_fits(m: int, k: int, quantized: bool = False,
+              tm: Optional[int] = None) -> bool:
+    """The kernel's SMEM operands fit at channel tile ``tm`` (default: the
+    smallest legal tile): the double-buffered (TM, K) packed-index and
+    value tiles, the int32 nnz row (M*4 — the kernel's per-row loop bounds;
     omitting it used to let index-heavy layers overshoot), the f32 bias row
     (M*4), and — for a quantised bank — the f32 per-channel scale row
     (another M*4)."""
-    return budget.smem_fits(m, k, quantized, smem_budget=_SMEM_BUDGET)
+    return budget.smem_fits(m, k, quantized, tm=tm, smem_budget=_SMEM_BUDGET)
 
 
 def spatial_candidates(e: int) -> List[int]:
-    """Output tile extents to consider for one spatial axis, largest first.
+    """Output row tiles to consider, largest first.
 
     The full extent (untiled) comes first — when it fits it is the best
-    schedule (no halo re-fetch); the ladder below it trades halo overlap for
-    a bounded VMEM block on large feature maps.
+    schedule (no halo re-fetch); the ladder below it (multiples of 8, the
+    TPU's sublane tile) trades halo overlap for a bounded VMEM block on
+    tall feature maps.  Columns are never tiled: the kernel stages whole
+    lane rows.
     """
     return [e] + [t for t in _SPATIAL_LADDER if t < e]
 
 
 def tm_candidates(m: int, c: int, hp: int, wp: int, e: int, f: int,
                   k: int, value_itemsize: int = 4) -> List[int]:
-    """Output-channel tiles that divide M and fit VMEM with the *whole*
+    """Legal output-channel tiles that fit VMEM and SMEM with the *whole*
     padded image staged (the untiled spatial schedule), largest first.
 
-    Returns ``[]`` when even TM=1 busts the budget — callers must then tile
-    spatially (``tile_candidates``) or fall back to the pure-JAX path.
-    Returning ``[1]`` here used to launch an over-budget kernel.
-    ``value_itemsize`` prices the value block at its storage width (1 for
-    int8/fp8 quantised banks).
+    Returns ``[]`` when even the smallest tile busts the budget — callers
+    must then tile spatially (``tile_candidates``) or fall back to the
+    pure-JAX path.  Returning ``[1]`` here used to launch an over-budget
+    kernel.  ``value_itemsize`` prices the SMEM value tile at its storage
+    width (1 for int8/fp8 quantised banks).
     """
-    x_bytes = c * hp * wp * 4
+    x_bytes = c * vmem_tile_bytes(hp, wp, 4)
     out: List[int] = []
-    for tm in _TM_LADDER:
-        if m % tm:
-            continue
-        val_bytes = tm * k * value_itemsize
-        out_bytes = tm * e * f * 4
-        if x_bytes + val_bytes + out_bytes <= _VMEM_BUDGET:
+    for tm in tm_ladder(m):
+        out_bytes = 2 * tm * vmem_tile_bytes(e, f, 4)
+        if (x_bytes + out_bytes <= _VMEM_BUDGET
+                and budget.smem_fits(m, k, value_itemsize == 1, tm=tm,
+                                     value_itemsize=value_itemsize,
+                                     smem_budget=_SMEM_BUDGET)):
             out.append(tm)
     return out
 
 
 def tiling_fits(m: int, c: int, e: int, f: int, k: int, r: int, s: int,
-                stride: int, tm: int, te: int, tf: int,
+                stride: int, tm: int, te: int,
                 fuse_res: bool = False, pipeline: bool = False,
                 value_itemsize: int = 4) -> bool:
-    """Whether one (tm, te, tf) tiling's working set — halo'd input block +
-    value block + f32 out tile (+ the residual input tile when the fused
-    epilogue accumulates a shortcut) — fits the VMEM budget.
+    """Whether one (tm, te) tiling is blockable on the TPU and its
+    working set fits: in VMEM the halo'd input block + the f32 out tile
+    (+ the residual input tile when the fused epilogue accumulates a
+    shortcut), in SMEM the (TM, K) index and value tiles.
 
     ``pipeline=True`` accounts the double-buffered halo DMA schedule: two
     halo-block scratch buffers are live at once (the one being computed on
     and the one being prefetched), so the staged-input term doubles.
-    ``value_itemsize`` prices the value block at its storage width."""
-    return budget.tiling_fits(m, c, e, f, k, r, s, stride, tm, te, tf,
+    ``value_itemsize`` prices the value tile at its storage width."""
+    return budget.tiling_fits(m, c, e, f, k, r, s, stride, tm, te,
                               fuse_res=fuse_res, pipeline=pipeline,
                               value_itemsize=value_itemsize,
-                              vmem_budget=_VMEM_BUDGET)
+                              vmem_budget=_VMEM_BUDGET,
+                              smem_budget=_SMEM_BUDGET)
 
 
 def tile_candidates(m: int, c: int, e: int, f: int, k: int, r: int, s: int,
@@ -120,39 +139,39 @@ def tile_candidates(m: int, c: int, e: int, f: int, k: int, r: int, s: int,
                     tms: Optional[Tuple[int, ...]] = None,
                     fuse_res: bool = False, pipeline: bool = False,
                     value_itemsize: int = 4,
-                    ) -> List[Tuple[int, int, int]]:
-    """All (tm, te, tf) tilings whose VMEM working set fits, preferred first.
+                    ) -> List[Tuple[int, int]]:
+    """All blockable (tm, te) tilings whose working set fits, preferred
+    first (a tile always spans all F columns).
 
     Preference order: fewest spatial cells (least halo re-fetch), then least
     total staged input traffic, then largest tm — so when the whole image
-    fits, the first candidate is the old untiled schedule with the largest
+    fits, the first candidate is the untiled schedule with the largest
     feasible channel tile.  ``tms`` overrides the channel-tile ladder (e.g.
     a caller-pinned tm that the ladder doesn't contain); ``fuse_res``
     reserves VMEM for the fused epilogue's residual input tile; ``pipeline``
     for the double-buffered halo schedule's second scratch block;
     ``value_itemsize`` prices the value block at its storage width.
     """
-    out: List[Tuple[int, int, int]] = []
+    out: List[Tuple[int, int]] = []
     for te in spatial_candidates(e):
-        for tf in spatial_candidates(f):
-            for tm in (tms or _TM_LADDER):
-                if tiling_fits(m, c, e, f, k, r, s, stride, tm, te, tf,
-                               fuse_res=fuse_res, pipeline=pipeline,
-                               value_itemsize=value_itemsize):
-                    out.append((tm, te, tf))
+        for tm in (tms or tm_ladder(m)):
+            if tiling_fits(m, c, e, f, k, r, s, stride, tm, te,
+                           fuse_res=fuse_res, pipeline=pipeline,
+                           value_itemsize=value_itemsize):
+                out.append((tm, te))
 
-    def pref(cand: Tuple[int, int, int]) -> Tuple[int, int, int]:
-        tm, te, tf = cand
-        cells = -(-e // te) * (-(-f // tf))
-        staged = cells * c * halo_extent(te, stride, r) * halo_extent(tf, stride, s)
+    def pref(cand: Tuple[int, int]) -> Tuple[int, int, int]:
+        tm, te = cand
+        cells = -(-e // te)
+        staged = cells * halo_extent(te, stride, r)
         return (cells, staged, -tm)
 
     return sorted(out, key=pref)
 
 
 def choose_tiles(m: int, c: int, e: int, f: int, k: int, r: int, s: int,
-                 stride: int = 1) -> Optional[Tuple[int, int, int]]:
-    """Static heuristic seed: the preferred feasible (tm, te, tf), or None
+                 stride: int = 1) -> Optional[Tuple[int, int]]:
+    """Static heuristic seed: the preferred feasible (tm, te), or None
     when no tiling fits (caller falls back to the pure-JAX direct path)."""
     cands = tile_candidates(m, c, e, f, k, r, s, stride)
     return cands[0] if cands else None
@@ -201,15 +220,15 @@ def apply_epilogue(y: jax.Array, bias: Optional[jax.Array],
 
 def resolve_schedule(m: int, c: int, e: int, f: int, k: int, r: int, s: int,
                      stride: int, *, tm: Optional[int] = None,
-                     te: Optional[int] = None, tf: Optional[int] = None,
+                     te: Optional[int] = None,
                      fuse_res: bool = False,
                      pipeline: Optional[bool] = None,
                      value_dtype: str = "float32",
-                     ) -> Tuple[Optional[Tuple[int, int, int, bool]],
+                     ) -> Tuple[Optional[Tuple[int, int, bool]],
                                 Optional[str]]:
     """The dispatch decision ``sparse_conv`` makes, as a pure function.
 
-    Returns ``((tm, te, tf, pipeline), None)`` for the schedule the Pallas
+    Returns ``((tm, te, pipeline), None)`` for the schedule the Pallas
     kernel would run, or ``(None, reason)`` — a ``telemetry.fallback``
     reason code — when the layer falls back to the pure-JAX direct path.
     Factored out so the engine's ExecutionReport and the benchmark's
@@ -224,16 +243,19 @@ def resolve_schedule(m: int, c: int, e: int, f: int, k: int, r: int, s: int,
     """
     vsize = value_itemsize(value_dtype)
     quantized = vsize == 1
-    if not smem_fits(m, k, quantized):
-        # Index-heavy layers: packed indices cannot be scalar-prefetched.
+    if not budget.smem_fits(m, k, quantized, value_itemsize=vsize,
+                            smem_budget=_SMEM_BUDGET):
+        # Index-heavy layers: even the smallest channel tile's index and
+        # value tiles overflow SMEM.
         return None, "smem_infeasible"
-    if tm is not None and te is not None and tf is not None:
+    if tm is not None and te is not None:
         # Fully-specified tiling (tuned plan / caller override): honor it
-        # when it fits, never launch an over-budget kernel.
-        te, tf = min(te, e), min(tf, f)
+        # when it is blockable and fits, never launch an over-budget or
+        # uncompilable kernel.
+        te = min(te, e)
         if tm < 1 or m % tm:
             return None, "nondividing_tm"
-        if not tiling_fits(m, c, e, f, k, r, s, stride, tm, te, tf,
+        if not tiling_fits(m, c, e, f, k, r, s, stride, tm, te,
                            fuse_res=fuse_res, value_itemsize=vsize):
             return None, "no_feasible_tiling"
     else:
@@ -246,24 +268,22 @@ def resolve_schedule(m: int, c: int, e: int, f: int, k: int, r: int, s: int,
                                 fuse_res=fuse_res, value_itemsize=vsize)
         if te is not None:
             cands = [t for t in cands if t[1] == min(te, e)]
-        if tf is not None:
-            cands = [t for t in cands if t[2] == min(tf, f)]
         if not cands:
             # No in-budget tiling (or the requested one is infeasible).
             return None, "no_feasible_tiling"
-        tm, te, tf = cands[0]
+        tm, te = cands[0]
     # Halo DMA schedule: double-buffer when allowed *and* the second halo
     # scratch block fits; otherwise the single-buffer blocking path.
     if pipeline is None or pipeline:
-        pipeline = tiling_fits(m, c, e, f, k, r, s, stride, tm, te, tf,
+        pipeline = tiling_fits(m, c, e, f, k, r, s, stride, tm, te,
                                fuse_res=fuse_res, pipeline=True,
                                value_itemsize=vsize)
-    return (tm, te, tf, bool(pipeline)), None
+    return (tm, te, bool(pipeline)), None
 
 
 def sparse_conv(x: jax.Array, ell: EllConv, *, stride: int = 1,
                 padding: int = 0, tm: Optional[int] = None,
-                te: Optional[int] = None, tf: Optional[int] = None,
+                te: Optional[int] = None,
                 bias: Optional[jax.Array] = None, fuse_relu: bool = False,
                 residual: Optional[jax.Array] = None,
                 pipeline: Optional[bool] = None,
@@ -272,7 +292,7 @@ def sparse_conv(x: jax.Array, ell: EllConv, *, stride: int = 1,
     """Direct sparse convolution + fused epilogue, Pallas-accelerated.
 
     (N, C, H, W) input, ELL filter bank for (M, C, R, S) weights ->
-    (N, M, E, F) in x.dtype.  Any stride >= 1 runs in-kernel; tm/te/tf
+    (N, M, E, F) in x.dtype.  Any stride >= 1 runs in-kernel; tm/te
     default to the static heuristic (``choose_tiles``) and are the knobs
     the ``repro.tuning`` autotuner turns.  ``bias`` (per-channel),
     ``fuse_relu`` and ``residual`` (a shortcut tensor shaped like the
@@ -324,13 +344,13 @@ def sparse_conv(x: jax.Array, ell: EllConv, *, stride: int = 1,
         return apply_epilogue(y, bias, fuse_relu, residual)
 
     sched, reason = resolve_schedule(m, c, e, f, k, r, s, stride, tm=tm,
-                                     te=te, tf=tf, fuse_res=fuse_res,
+                                     te=te, fuse_res=fuse_res,
                                      pipeline=pipeline,
                                      value_dtype=ell.value_dtype)
     if sched is None:
         # The XLA-scheduled direct path, with the same epilogue unfused.
         return fallback(reason)
-    tm, te, tf, pipeline = sched
+    tm, te, pipeline = sched
     xpad = jnp.pad(x, ((0, 0), (0, 0), (padding, padding), (padding, padding)))
     b = (jnp.zeros((m,), jnp.float32) if bias is None
          else jnp.asarray(bias, jnp.float32))
@@ -344,7 +364,7 @@ def sparse_conv(x: jax.Array, ell: EllConv, *, stride: int = 1,
     out = sparse_conv_pallas(
         xpad, ell.value, pack_indices(ell), ell.nnz, b, res,
         scale=ell.scale,
-        tm=tm, k=k, rs=r * s, s=s, e=e, f=f, stride=stride, te=te, tf=tf,
+        tm=tm, k=k, rs=r * s, s=s, e=e, f=f, stride=stride, te=te,
         fuse_relu=fuse_relu, pipeline=pipeline, interpret=interpret)
     if inv is not None:
         out = jnp.take(out, inv, axis=1)

@@ -40,6 +40,7 @@ from repro.core.pruning import block_prune
 from repro.core.sparse_format import bcsr_from_dense, bcsr_stack_from_dense
 from repro.launch.steps import make_serve_step
 from repro.models import transformer as T
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def sparsify_params(params, cfg, sparsity: float, block=(16, 16), min_dim=64):
@@ -89,6 +90,8 @@ def autotune_main(args) -> None:
     image = ({"alexnet": 99, "googlenet": 96, "resnet50": 96}[name]
              if args.smoke else 224)
     mode = args.tune_mode
+    # The plan is keyed on, and run by, the device the engine binds.
+    backend = jax.devices()[0].platform
     params = None
     rng = np.random.default_rng(args.seed)
     if mode == "wall":
@@ -97,7 +100,7 @@ def autotune_main(args) -> None:
     program = lower(net, (3, image, image))
     cache = PlanCache(args.plan_cache)
     plan = plan_program(program, batch=1, mode=mode, cache=cache,
-                        params=params)
+                        params=params, backend=backend)
     fused = sum(pe.method in ("pallas", "bsr") and pe.fuse
                 for pe in plan.values())
     print(f"tuned {name} @ {image}px: {program.summary()}; "
@@ -108,7 +111,8 @@ def autotune_main(args) -> None:
     # Round-trip: a fresh cache loaded from disk must reproduce the plan
     # without re-tuning (every layer a hit).
     replan = plan_program(program, batch=1, mode=mode,
-                          cache=PlanCache(args.plan_cache), params=params)
+                          cache=PlanCache(args.plan_cache), params=params,
+                          backend=backend)
     assert replan == plan, "plan cache reload did not reproduce the plan"
     print(f"plan cache round-trip ok ({args.plan_cache})")
 
@@ -129,7 +133,7 @@ def autotune_main(args) -> None:
     # Fresh in-memory cache: the synthetic slice geometries must not be
     # persisted into the deployment plan cache.
     splan = plan_program(slice_prog, batch=1, mode="roofline",
-                         cache=PlanCache())
+                         cache=PlanCache(), backend=backend)
     apply_plan_to_params(sparams, splan)
     engine = CnnEngine(slice_prog, sparams, splan)
     y_auto = engine(x, "auto")
@@ -187,6 +191,12 @@ def cnn_serve_main(args) -> None:
     rep = server.run_trace(trace)
     print(rep.format())
     rep.verify()  # zero lost, zero duplicated — or raise
+    fatal = rep.rejected.get("fatal_error", 0)
+    if chaos is None and fatal:
+        # Without injected faults a fatal step is a real failure (e.g. a
+        # kernel that does not compile for this device): rejecting every
+        # request with a reason still terminates them, but the run failed.
+        raise SystemExit(f"{fatal} request(s) failed with fatal_error")
     if chaos is not None:
         print("chaos:", chaos.summary())
         assert rep.degradations or rep.dropped_rungs, (
@@ -234,6 +244,7 @@ def main() -> None:
     ap.add_argument("--requests", type=int, default=40,
                     help="with --cnn-serve: arrival-trace length")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.trace:
         telemetry.enable()

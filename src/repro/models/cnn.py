@@ -15,8 +15,8 @@ selectable method (paper Table/Figs 8-11):
                   layers (Pallas kernel, interpret on CPU)
   "auto"       -- per-layer dispatch through a tuned plan from repro.tuning
                   (the paper's kernel customization, measurement-driven);
-                  plan entries carry the full schedule: method, (tm, te,
-                  tf) tiling, pad_to, fused epilogue, pipelined staging,
+                  plan entries carry the full schedule: method, (tm, te)
+                  tiling, pad_to, fused epilogue, pipelined staging,
                   nnz-balanced channel packing, and the BCSR block shape
 
 Execution goes through the compile-once graph engine (``repro.engine``):

@@ -356,11 +356,22 @@ def _xent(logits: jax.Array, labels: jax.Array) -> jax.Array:
     return (lse - gold).mean()
 
 
+def _embed_gather(table: jax.Array, ids: jax.Array) -> jax.Array:
+    """Embedding rows for ``ids``.  Under explicit sharding the output
+    follows the ids' sharding: JAX will not infer it from a table sharded
+    over both of its axes."""
+    if jax.typeof(table).sharding.mesh.empty:
+        return jnp.take(table, ids, axis=0)
+    out = jax.sharding.NamedSharding(jax.typeof(table).sharding.mesh,
+                                     P(*jax.typeof(ids).sharding.spec, None))
+    return table.at[ids].get(out_sharding=out)
+
+
 def loss_fn(params: Params, tokens: jax.Array, labels: jax.Array,
             cfg: ModelConfig, *, embeds: Optional[jax.Array] = None) -> jax.Array:
     """Next-token CE; DeepSeek-style MTP aux head adds a 2-ahead term."""
     if embeds is None:
-        embeds = jnp.take(params["embed"], tokens, axis=0).astype(_dtype(cfg))
+        embeds = _embed_gather(params["embed"], tokens).astype(_dtype(cfg))
         use_mtp = bool(cfg.mtp_depth)
     else:
         use_mtp = False
@@ -369,7 +380,7 @@ def loss_fn(params: Params, tokens: jax.Array, labels: jax.Array,
     loss = _xent(logits, labels)
     if use_mtp:
         # Predict labels[t+1] from (h_t, emb(labels_t)): one extra block.
-        nxt = jnp.take(params["embed"], labels, axis=0).astype(_dtype(cfg))
+        nxt = _embed_gather(params["embed"], labels).astype(_dtype(cfg))
         z = jnp.concatenate([L.rms_norm(h, params["mtp"]["norm"], cfg.norm_eps),
                              nxt], axis=-1)
         z = L.apply_linear(params["mtp"]["proj"], z)

@@ -342,6 +342,7 @@ class RobustCnnServer:
     def _build_bucket(self, net, spec: BucketSpec, plan, plan_cache: Optional[str],
                       max_strikes: int) -> _Bucket:
         program = lower(net, spec.shape)
+        engine = CnnEngine(program, self.params, None)
         if callable(plan):
             base = plan(program, spec.batch)
         elif plan is not None:
@@ -350,12 +351,12 @@ class RobustCnnServer:
             from repro.tuning.planner import plan_program
             cache = PlanCache(plan_cache) if plan_cache else None
             base = plan_program(program, batch=spec.batch, mode="roofline",
-                                cache=cache, params=self.params)
+                                cache=cache, params=self.params,
+                                backend=engine.platform)
         if self.chaos is not None:
             # Forced-schedule-infeasibility seam: the injector stales some
             # entries; the ladder build below must catch them statically.
             base = self.chaos.corrupt_plan(base, program)
-        engine = CnnEngine(program, self.params, None)
         rungs = self._build_ladder(spec, program, engine, base)
         return _Bucket(spec=spec, program=program, engine=engine,
                        rungs=rungs,

@@ -3,7 +3,7 @@
 Per-layer method/tile selection, measurement-driven with an analytical
 roofline fallback, persisted to a JSON plan cache:
 
-  space    -- candidate enumeration (method x (tm, te, tf) x pad_to x fuse
+  space    -- candidate enumeration (method x (tm, te) x pad_to x fuse
               x pipeline x permute x BCSR (block_m, block_n)) from
               geometry; spatial tiles come from the kernels' halo'd-block
               VMEM feasibility models (pipelined tilings reserve the

@@ -10,7 +10,7 @@ models share one entry.
 Format (``docs/autotuning.md`` documents it for humans):
 
     {"version": 6,
-     "entries": {"<key>": {"method": "bsr", "te": 32, "tf": 32,
+     "entries": {"<key>": {"method": "bsr", "te": 32,
                            "block_m": 32, "block_n": 128, "fuse": true,
                            "value_dtype": "int8",
                            "est_s": 1.2e-4, "source": "roofline"}}}
@@ -25,13 +25,15 @@ inverse permutation applied to the output) to pallas entries; v3 added the
 ``fuse`` flag (in-kernel epilogue: bias / ReLU / bottleneck shortcut
 applied to the f32 accumulator); v2 added the output spatial tile
 ``(te, tf)``.  Older documents load via migration — v1 entries get ``te =
-tf = None`` (the untiled schedule the v1 kernel executed), v1/v2 entries
+None`` (the untiled schedule the v1 kernel executed), v1/v2 entries
 get ``fuse = False`` (those kernels always ran the unfused three-pass
 epilogue), v1-v3 entries get ``pipeline = permute = False`` (those kernels
 always staged with a blocking single-buffer DMA over natural-order banks),
 v1-v4 entries get ``block_m = block_n = None`` (no pre-v5 kernel ran
 blocked), and v1-v5 entries get ``value_dtype = "float32"`` (every pre-v6
 kernel streamed f32 values) — and are re-persisted as v6 on the next save.
+A stored column tile ``tf`` is ignored on load: the kernels stage whole
+lane rows, so every tile spans all F output columns.
 A (corrupt or hand-edited) pre-v5 entry claiming ``method="bsr"``
 therefore migrates with no block shape; executors treat that as a stale
 plan and fall back to dense.  Likewise a migrated (f32) entry executed
@@ -68,8 +70,7 @@ class PlanEntry:
     method: str
     tm: Optional[int] = None
     pad_to: Optional[int] = None
-    te: Optional[int] = None      # output spatial tile (None: untiled)
-    tf: Optional[int] = None
+    te: Optional[int] = None      # output row tile (None: untiled)
     fuse: bool = False            # pallas/bsr: in-kernel epilogue
     pipeline: bool = False        # pallas: double-buffered halo DMA
     permute: bool = False         # pallas: nnz-balanced bank
@@ -89,14 +90,14 @@ class PlanEntry:
     @property
     def candidate(self) -> Candidate:
         return Candidate(method=self.method, tm=self.tm, pad_to=self.pad_to,
-                         te=self.te, tf=self.tf, fuse=self.fuse,
+                         te=self.te, fuse=self.fuse,
                          pipeline=self.pipeline, permute=self.permute,
                          block_m=self.block_m, block_n=self.block_n,
                          value_dtype=self.value_dtype)
 
     def to_dict(self) -> dict:
         return {"method": self.method, "tm": self.tm, "pad_to": self.pad_to,
-                "te": self.te, "tf": self.tf, "fuse": self.fuse,
+                "te": self.te, "fuse": self.fuse,
                 "pipeline": self.pipeline, "permute": self.permute,
                 "block_m": self.block_m, "block_n": self.block_n,
                 "value_dtype": self.value_dtype,
@@ -104,15 +105,16 @@ class PlanEntry:
 
     @classmethod
     def from_dict(cls, d: dict) -> "PlanEntry":
-        # Migration: absent te/tf means the untiled schedule (v1), absent
+        # Migration: absent te means the untiled schedule (v1), absent
         # fuse the unfused three-pass epilogue (v1/v2), absent
         # pipeline/permute the blocking single-buffer DMA over a
         # natural-order bank (v1-v3), absent block_m/block_n no BCSR tile
         # shape (v1-v4; executors fall back if such an entry claims
         # method="bsr"), absent value_dtype an f32 value stream (v1-v5) —
-        # each the schedule those kernels ran.
+        # each the schedule those kernels ran.  A stored column tile
+        # ``tf`` is dropped: every tile now spans all F columns.
         return cls(method=d["method"], tm=d.get("tm"), pad_to=d.get("pad_to"),
-                   te=d.get("te"), tf=d.get("tf"),
+                   te=d.get("te"),
                    fuse=bool(d.get("fuse", False)),
                    pipeline=bool(d.get("pipeline", False)),
                    permute=bool(d.get("permute", False)),
@@ -194,7 +196,7 @@ class PlanCache:
                 raise
             self._load_error(path, str(exc))
             return self
-        # v1-v5 migration happens in from_dict: absent te/tf default to None
+        # v1-v5 migration happens in from_dict: absent te defaults to None
         # (the untiled schedule), absent fuse to False (the unfused
         # epilogue), absent pipeline/permute to False (blocking DMA,
         # natural row order), absent block_m/block_n to None (no BCSR

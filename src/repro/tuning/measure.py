@@ -28,8 +28,8 @@ from repro.core.sparse_format import (balance_ell_conv, bcsr_conv_from_dense,
                                       ell_from_dense, ell_from_dense_conv,
                                       quantize_values)
 from repro.kernels.bsr_conv.ops import bsr_conv
-from repro.kernels.sparse_conv.ops import (apply_epilogue, halo_extent,
-                                           sparse_conv)
+from repro.kernels.sparse_conv.ops import apply_epilogue, sparse_conv
+from repro.kernels.window import halo_extent
 from repro.launch.roofline import (HBM_BW, PEAK_FLOPS, VPU_FLOPS,
                                    value_itemsize)
 from repro.tuning.space import Candidate, ConvGeometry
@@ -136,15 +136,15 @@ def permute_bytes(g: ConvGeometry, permuted: bool) -> float:
 
 def staged_input_bytes(g: ConvGeometry, cand: Candidate) -> float:
     """Input bytes the Pallas kernel stages HBM->VMEM over the whole launch:
-    one halo'd block per (image, spatial-tile) grid cell.  Smaller (te, tf)
-    tiles re-fetch more halo overlap — the tuner's main spatial signal."""
+    one halo'd band of whole rows per (image, row-tile) grid cell.  Smaller
+    te tiles re-fetch more halo overlap — the tuner's main spatial
+    signal."""
     e, f = g.e, g.f
     itemsize = 2 if g.dtype in ("bfloat16", "float16") else 4
     te = min(cand.te or e, e)
-    tf = min(cand.tf or f, f)
     halo_h = halo_extent(te, g.stride, g.r)
-    halo_w = halo_extent(tf, g.stride, g.s)
-    cells = ((e + te - 1) // te) * ((f + tf - 1) // tf)
+    halo_w = halo_extent(f, g.stride, g.s)
+    cells = (e + te - 1) // te
     return float(g.batch * cells * g.c * halo_h * halo_w * itemsize)
 
 
@@ -208,9 +208,9 @@ def _bsr_terms(g: ConvGeometry, cand: Candidate,
     """(compute_s, staged_s, other_mem_s) for one bsr (BCSR MXU) candidate.
 
     Compute has two serialized stages per kept weight tile: the *gather*
-    (VPU — bn strided windows of te*tf elements copied from the staged halo
+    (VPU — bn strided windows of te*f elements copied from the staged halo
     block into the patch tile) and the *contraction* (MXU — one
-    (bm, bn) x (bn, te*tf) systolic pass at the dense-unit peak).  Bigger
+    (bm, bn) x (bn, te*f) systolic pass at the dense-unit peak).  Bigger
     bm amortises the gather over more systolic rows; that ratio is the
     tile-gather-vs-systolic-compute tradeoff this model prices against the
     ELL kernel's pure-VPU FMA loop (:func:`_pallas_terms`).  Kept-block
@@ -229,10 +229,9 @@ def _bsr_terms(g: ConvGeometry, cand: Candidate,
     e, f = g.e, g.f
     itemsize = 2 if g.dtype in ("bfloat16", "float16") else 4
     te = min(cand.te or e, e)
-    tf = min(cand.tf or f, f)
-    cells = ((e + te - 1) // te) * ((f + tf - 1) // tf)
+    cells = (e + te - 1) // te
     mxu_fl = 2.0 * n * gbm * kept * bm * bn * e * f
-    gather_elems = float(n * cells * gbm * kept * bn * te * tf)
+    gather_elems = float(n * cells * gbm * kept * bn * te * f)
     compute_s = mxu_fl / PEAK_FLOPS + gather_elems / VPU_FLOPS
     dout = float(n * gbm * bm * e * f * 4)
     w_bytes = _value_stream_bytes(gbm * kept * bm * bn, gbm * bm, itemsize,
@@ -294,8 +293,8 @@ def roofline_estimate(g: ConvGeometry, cand: Candidate,
                   serialized VPU patch gather + MXU tile contractions
                   (:func:`_bsr_terms` — the gather-vs-systolic tradeoff).
       pallas      same traffic, but the halo'd input block is staged
-                  HBM->VMEM once per (image, spatial-tile) grid cell and
-                  reused across channel tiles: smaller (te, tf) tiles cost
+                  HBM->VMEM once per (image, row-tile) grid cell and
+                  reused across channel tiles: smaller te tiles cost
                   more halo re-fetch (the tuner's main spatial signal),
                   while the nnz loop bound skips padding, so padded K costs
                   no flops (see :func:`_pallas_terms` for why the bound is
@@ -468,11 +467,11 @@ def build_runner(g: ConvGeometry, cand: Candidate, w_dense: np.ndarray,
             bcc = quantize_values(bcc, cand.value_dtype)
         if cand.fuse:
             return jax.jit(lambda x, b=bcc: bsr_conv(
-                x, b, stride=g.stride, padding=g.pad, te=cand.te, tf=cand.tf,
+                x, b, stride=g.stride, padding=g.pad, te=cand.te,
                 bias=bias, fuse_relu=g.relu, residual=res,
                 interpret=interpret)), ()
         return jax.jit(lambda x, b=bcc: epilogue(bsr_conv(
-            x, b, stride=g.stride, padding=g.pad, te=cand.te, tf=cand.tf,
+            x, b, stride=g.stride, padding=g.pad, te=cand.te,
             interpret=interpret))), ()
     ell = ell_from_dense_conv(w_dense, pad_to=pad_to)
     if cand.method == "csr-direct":
@@ -496,12 +495,12 @@ def build_runner(g: ConvGeometry, cand: Candidate, w_dense: np.ndarray,
         if cand.fuse:
             return jax.jit(lambda x, e=ell: sparse_conv(
                 x, e, stride=g.stride, padding=g.pad, tm=cand.tm,
-                te=cand.te, tf=cand.tf, bias=bias, fuse_relu=g.relu,
+                te=cand.te, bias=bias, fuse_relu=g.relu,
                 residual=res, pipeline=cand.pipeline,
                 interpret=interpret)), ()
         return jax.jit(lambda x, e=ell: epilogue(sparse_conv(
             x, e, stride=g.stride, padding=g.pad, tm=cand.tm,
-            te=cand.te, tf=cand.tf, pipeline=cand.pipeline,
+            te=cand.te, pipeline=cand.pipeline,
             interpret=interpret))), ()
     raise ValueError(cand.method)
 
@@ -516,12 +515,11 @@ def measure_candidate(g: ConvGeometry, cand: Candidate, w_dense: np.ndarray,
     return time_fn(runner, x, warmup=warmup, iters=iters)
 
 
-def measurable(cand: Candidate, backend: Optional[str] = None) -> bool:
-    """Whether wall-timing this candidate is meaningful on this backend.
+def measurable(cand: Candidate, backend: str) -> bool:
+    """Whether wall-timing this candidate is meaningful on ``backend``.
 
     Pallas kernels (the ELL ``pallas`` path and the BCSR ``bsr`` path) in
     interpret mode are Python-executed — their wall time says nothing about
     the kernel, so off-TPU they are scored by roofline only.
     """
-    backend = backend or jax.default_backend()
     return cand.method not in ("pallas", "bsr") or backend == "tpu"

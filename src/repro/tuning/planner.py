@@ -20,14 +20,15 @@ import dataclasses
 import logging
 from typing import Any, Dict, Optional, Sequence
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro import telemetry
 from repro.core.sparse_format import (bcsr_conv_from_dense, ell_from_dense,
-                                      ell_from_dense_conv, quantize_values)
+                                      ell_from_dense_conv, ell_k,
+                                      quantize_values)
 from repro.engine import ConvOp, Program, lower
+from repro.kernels.sparse_conv.ops import resolve_schedule
 from repro.tuning.cache import PlanCache, PlanEntry, layer_key
 from repro.tuning.measure import (bcsr_true_kept, measurable,
                                   measure_candidate, roofline_estimate)
@@ -58,20 +59,26 @@ def geometry_of_op(op: ConvOp, *, batch: int = 1,
         dtype=dtype, relu=op.fuse_relu, residual=op.res is not None)
 
 
-def plan_layer(g: ConvGeometry, *, mode: str = "roofline",
-               w_dense: Optional[np.ndarray] = None, backend: str = "cpu",
-               interpret: Optional[bool] = None, warmup: int = 1,
-               iters: int = 3, quantize: bool = False) -> PlanEntry:
+def plan_layer(g: ConvGeometry, *, backend: str, mode: str = "roofline",
+               w_dense: Optional[np.ndarray] = None,
+               row_nnz: Optional[int] = None,
+               warmup: int = 1, iters: int = 3,
+               quantize: bool = False) -> PlanEntry:
     """Score every valid candidate for one layer and return the winner.
 
-    ``interpret=None`` resolves per backend: compiled on TPU, interpret
-    elsewhere — wall-timing an interpret-mode Pallas kernel would measure
-    the Python interpreter, not the kernel.  ``w_dense`` is required for
+    ``backend`` is the platform the plan will run on (``"tpu"``,
+    ``"cpu"``, ...).  Wall mode runs the Pallas kernels compiled on a TPU
+    ``backend`` and
+    times only the non-Pallas candidates elsewhere (``measurable``) —
+    wall-timing an interpret-mode kernel would measure the Python
+    interpreter, not the kernel.  ``w_dense`` is required for
     wall mode and *used* by roofline mode when given: bsr candidates are
     then priced from the actual bank's kept-block structure instead of
     the block-structured-pruning estimate (unstructured magnitude-pruned
     weights keep nearly every tile — the estimate would send such layers
-    to a slower-than-dense MXU schedule).
+    to a slower-than-dense MXU schedule).  ``row_nnz`` is the built ELL
+    bank's longest row, which sizes K; without it K is estimated from the
+    sparsity.
 
     ``quantize=True`` opts the candidate space into the narrow
     value-storage dtypes (int8, and fp8 on TPU backends).  It is opt-in
@@ -80,14 +87,13 @@ def plan_layer(g: ConvGeometry, *, mode: str = "roofline",
     planner run would silently trade accuracy for bandwidth; a plan that
     pins a narrow dtype is an explicit artifact instead.
     """
-    if interpret is None:
-        interpret = backend != "tpu"
+    interpret = backend != "tpu"
     # The value-dtype axis is backend-capability-filtered up front: a plan
     # must never pin a dtype the backend cannot execute (fp8 off-TPU) —
     # the static verifier flags any such entry as a pre-flight error.
     cands = enumerate_candidates(
         g, value_dtypes=(allowed_value_dtypes(backend) if quantize
-                         else ("float32",)))
+                         else ("float32",)), row_nnz=row_nnz)
     if mode == "wall":
         cands = [cd for cd in cands if measurable(cd, backend)]
     if not cands:
@@ -113,7 +119,7 @@ def plan_layer(g: ConvGeometry, *, mode: str = "roofline",
                 cd, t * 1e6, t.min * 1e6, t.max * 1e6)
         elif cd.method == "bsr" and w_dense is not None:
             # One bank scan per block shape, not per candidate — the
-            # ladder has ~4 shapes but ~dozens of (te, tf, fuse) points.
+            # ladder has ~4 shapes but ~dozens of (te, fuse) points.
             blk = (cd.block_m or 8, cd.block_n or 128)
             if blk not in kept_by_block:
                 kept_by_block[blk] = bcsr_true_kept(w_dense, *blk)
@@ -129,12 +135,27 @@ def plan_layer(g: ConvGeometry, *, mode: str = "roofline",
             getattr(best_t, "min", best_t) * 1e6,
             getattr(best_t, "max", best_t) * 1e6)
     return PlanEntry(method=best.method, tm=best.tm, pad_to=best.pad_to,
-                     te=best.te, tf=best.tf, fuse=best.fuse,
+                     te=best.te, fuse=best.fuse,
                      pipeline=best.pipeline, permute=best.permute,
                      block_m=best.block_m, block_n=best.block_n,
                      value_dtype=best.value_dtype,
                      est_s=best_t,
                      source="measured" if mode == "wall" else "roofline")
+
+
+def _fits_bank(pe: PlanEntry, op: ConvOp, row_nnz: int) -> bool:
+    """Whether a pallas entry's pinned schedule dispatches for this layer's
+    own bank, whose longest row is ``row_nnz``.  Layers sharing a cache key
+    share geometry, not row lengths, and the ELL K the entry's ``pad_to``
+    rounds that row to sizes the kernel's SMEM tiles."""
+    if pe.method != "pallas":
+        return True
+    sched, _ = resolve_schedule(
+        op.m, op.c, op.e, op.f, ell_k(row_nnz, pe.pad_to or 8), op.k, op.k,
+        op.stride, tm=pe.tm, te=pe.te,
+        fuse_res=pe.fuse and op.res is not None,
+        pipeline=pe.pipeline, value_dtype=pe.value_dtype)
+    return sched is not None
 
 
 def weight_structure_tag(w_dense: np.ndarray) -> str:
@@ -159,13 +180,14 @@ def plan_program(program: Program, *, batch: int = 1,
                  dtype: str = "float32", mode: str = "roofline",
                  cache: Optional[PlanCache] = None,
                  params: Optional[Dict[str, Any]] = None,
-                 backend: Optional[str] = None,
-                 interpret: Optional[bool] = None,
+                 backend: str,
                  warmup: int = 1, iters: int = 3,
                  quantize: bool = False,
                  ) -> Dict[str, PlanEntry]:
     """Tune every conv op of a lowered program; returns name -> PlanEntry.
 
+    ``backend`` is the platform the plan will run on — it keys the cache
+    and filters the candidates the backend cannot execute.
     Cache hits skip scoring entirely; misses are scored and written back (and
     persisted to ``cache.path`` if set).  Duplicate geometries — same layer
     key, which includes the fused-epilogue signature — are scored once per
@@ -179,15 +201,19 @@ def plan_program(program: Program, *, batch: int = 1,
     """
     if mode not in ("roofline", "wall"):
         raise ValueError(f"unknown tuning mode {mode!r}")
-    backend = backend or jax.default_backend()
     plan: Dict[str, PlanEntry] = {}
     scored: Dict[str, PlanEntry] = {}
     misses = 0
     for op in program.conv_ops:
         g = geometry_of_op(op, batch=batch, dtype=dtype)
-        w_dense = None
+        w_dense = row_nnz = None
         if op.sparsity > 0 and params is not None and op.name in params:
             w_dense = np.asarray(params[op.name]["w"])
+            # The built bank is what the engine runs: its longest row
+            # decides the ELL K every pallas schedule is sized for.
+            ell = params[op.name].get("ell")
+            if ell is not None:
+                row_nnz = int(np.asarray(ell.nnz).max())
         base_key = key = layer_key(g, backend)
         if w_dense is not None:
             # Weights-aware scores depend on the bank's block structure,
@@ -219,6 +245,11 @@ def plan_program(program: Program, *, batch: int = 1,
             entry = scored.get(key)
             if entry is not None and telem:
                 telemetry.counter("tuning.plan.dedup_hit").inc()
+        if (entry is not None and row_nnz is not None
+                and not _fits_bank(entry, op, row_nnz)):
+            # Scored for a same-geometry bank with shorter rows: re-score
+            # this layer on its own weights.
+            entry = None
         if entry is None:
             if op.sparsity <= 0:
                 # Dense-kept layer: one candidate, nothing to measure.
@@ -229,7 +260,7 @@ def plan_program(program: Program, *, batch: int = 1,
                     raise ValueError(
                         f"wall-mode tuning needs params for {op.name}")
                 entry = plan_layer(g, mode=mode, w_dense=w_dense,
-                                   backend=backend, interpret=interpret,
+                                   row_nnz=row_nnz, backend=backend,
                                    warmup=warmup, iters=iters,
                                    quantize=quantize)
             misses += 1
@@ -293,7 +324,7 @@ def apply_plan_to_params(params: Dict[str, Any],
 
 def format_plan(plan: Dict[str, PlanEntry]) -> str:
     """Human-readable per-layer plan table (the paper's customization table)."""
-    lines = [f"{'layer':<22} {'method':<11} {'tm':>4} {'te':>4} {'tf':>4} "
+    lines = [f"{'layer':<22} {'method':<11} {'tm':>4} {'te':>4} "
              f"{'pad_to':>6} {'block':>8} {'fuse':>5} {'pipe':>5} {'perm':>5} "
              f"{'vdtype':>8} {'est_us':>10} source"]
     for name, pe in plan.items():
@@ -303,7 +334,7 @@ def format_plan(plan: Dict[str, PlanEntry]) -> str:
             pe.value_dtype, pe.value_dtype)
         lines.append(
             f"{name:<22} {pe.method:<11} {pe.tm or '-':>4} "
-            f"{pe.te or '-':>4} {pe.tf or '-':>4} "
+            f"{pe.te or '-':>4} "
             f"{pe.pad_to or '-':>6} {block:>8} {'y' if pe.fuse else '-':>5} "
             f"{'y' if pe.pipeline else '-':>5} "
             f"{'y' if pe.permute else '-':>5} "

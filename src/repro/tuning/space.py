@@ -6,9 +6,10 @@ enumerates the discrete choices the tuner measures over:
 
   method      ∈ {dense, lowered, csr-direct, pallas, bsr}  (paper Figs. 8-11
                columns, plus the beyond-paper BCSR MXU conv path)
-  (tm,te,tf)  ∈ output-channel x output-spatial tilings whose halo'd input
-               block + value block + out tile fit the VMEM budget (pallas
-               only; te/tf = None means the untiled full-extent schedule)
+  (tm,te)     ∈ output-channel x output-row tilings whose halo'd input
+               block + out tile fit the VMEM budget (pallas only; te = None
+               means the untiled full-extent schedule; a tile always spans
+               all F columns)
   pad_to      ∈ ELL row-padding buckets (K granularity; trades padded work
                for jit-specialisation sharing)
   fuse        ∈ {False, True}  (pallas only): execute the conv's epilogue —
@@ -29,7 +30,7 @@ enumerates the discrete choices the tuner measures over:
                granularity of the block-pruned weight matrix.  Bigger bm
                amortises the per-block patch gather over more systolic
                rows; smaller bm wastes less channel padding.  bsr
-               candidates also carry (te, tf) spatial tiles and the fuse
+               candidates also carry te row tiles and the fuse
                axis, but no tm/pad_to/pipeline/permute — the block shape
                plays tm's role and the kernel's halo DMA is blocking.
 
@@ -47,6 +48,7 @@ import dataclasses
 import math
 from typing import List, Optional, Tuple
 
+from repro.core.sparse_format import ell_k
 from repro.kernels.bsr_conv.ops import BLOCK_CANDIDATES, bsr_tile_candidates
 from repro.kernels.budget import bsr_smem_fits, smem_fits, value_itemsize
 from repro.kernels.sparse_conv.ops import tile_candidates
@@ -131,9 +133,7 @@ class ConvGeometry:
 
     def k_est(self, pad_to: int) -> int:
         """Estimated padded ELL row length K for a given pad_to bucket."""
-        pad_to = max(1, pad_to)
-        k = self.row_nnz_est
-        return max(pad_to, ((k + pad_to - 1) // pad_to) * pad_to)
+        return ell_k(self.row_nnz_est, pad_to)
 
     def bsr_grid(self, bm: int, bn: int) -> Tuple[int, int, int]:
         """(gbm, gbn, kept-per-row estimate) of a (bm, bn)-blocked bank.
@@ -154,14 +154,14 @@ class ConvGeometry:
 class Candidate:
     """One point of the customization space.
 
-    tm/te/tf are only meaningful for the pallas method (te/tf = None means
-    the untiled full-extent spatial schedule); pad_to only for the sparse
+    tm/te are only meaningful for the pallas method (te = None means the
+    untiled full-extent spatial schedule); pad_to only for the sparse
     formats (lowered / csr-direct / pallas); ``fuse`` only for pallas and
     bsr — True executes the epilogue in-kernel; ``pipeline`` only for
     pallas — True double-buffers the halo DMA; ``permute`` only for pallas
     — True runs an nnz-balanced bank with the inverse permutation applied
     to the output; ``block_m``/``block_n`` only for bsr — the BCSR tile
-    shape (te/tf are meaningful for bsr too); ``value_dtype`` only for
+    shape (te is meaningful for bsr too); ``value_dtype`` only for
     pallas and bsr — the bank's value-storage dtype ("float32", or the
     quantised "int8"/"float8_e4m3fn" with per-output-channel f32 scales
     and f32 accumulation).
@@ -171,7 +171,6 @@ class Candidate:
     tm: Optional[int] = None
     pad_to: Optional[int] = None
     te: Optional[int] = None
-    tf: Optional[int] = None
     fuse: bool = False
     pipeline: bool = False
     permute: bool = False
@@ -181,7 +180,7 @@ class Candidate:
 
     def to_dict(self) -> dict:
         return {"method": self.method, "tm": self.tm, "pad_to": self.pad_to,
-                "te": self.te, "tf": self.tf, "fuse": self.fuse,
+                "te": self.te, "fuse": self.fuse,
                 "pipeline": self.pipeline, "permute": self.permute,
                 "block_m": self.block_m, "block_n": self.block_n,
                 "value_dtype": self.value_dtype}
@@ -189,7 +188,7 @@ class Candidate:
     @classmethod
     def from_dict(cls, d: dict) -> "Candidate":
         return cls(method=d["method"], tm=d.get("tm"), pad_to=d.get("pad_to"),
-                   te=d.get("te"), tf=d.get("tf"),
+                   te=d.get("te"),
                    fuse=bool(d.get("fuse", False)),
                    pipeline=bool(d.get("pipeline", False)),
                    permute=bool(d.get("permute", False)),
@@ -201,7 +200,7 @@ def pallas_feasible(g: ConvGeometry, k: int,
                     value_dtype: str = "float32") -> bool:
     """The Pallas kernel needs SMEM-resident packed indices (+ bias row, +
     the scale row for a quantised bank) and at least one VMEM-feasible
-    (tm, te, tf) tiling at the bank's value width.  Stride is handled
+    (tm, te) tiling at the bank's value width.  Stride is handled
     in-kernel."""
     vsize = value_itemsize(value_dtype)
     if not smem_fits(g.m, k, vsize == 1):
@@ -212,7 +211,7 @@ def pallas_feasible(g: ConvGeometry, k: int,
 
 def bsr_feasible(g: ConvGeometry, bm: int, bn: int) -> bool:
     """The BCSR conv kernel needs its SMEM-resident block-column table and
-    at least one VMEM-feasible (te, tf) spatial tiling for this block
+    at least one VMEM-feasible row tiling for this block
     shape.
 
     The SMEM gate uses ``gbn`` — the largest KB any real bank of this
@@ -231,10 +230,11 @@ def bsr_feasible(g: ConvGeometry, bm: int, bn: int) -> bool:
 def enumerate_candidates(g: ConvGeometry,
                          methods: Tuple[str, ...] = METHODS,
                          value_dtypes: Tuple[str, ...] = ("float32",),
+                         row_nnz: Optional[int] = None,
                          ) -> List[Candidate]:
     """All statically-valid customization points for one layer.
 
-    Every emitted pallas ``(tm, te, tf)`` fits the VMEM budget (via
+    Every emitted pallas ``(tm, te)`` fits the VMEM budget (via
     ``kernels.sparse_conv.ops.tile_candidates`` — the heuristic the tuner
     refines; the list is preference-sorted and capped at MAX_TILINGS); every
     pallas candidate fits the SMEM budget.  Pallas points enumerate the
@@ -255,6 +255,10 @@ def enumerate_candidates(g: ConvGeometry,
     backend-filtered ``allowed_value_dtypes``; fp8 is dropped off-TPU to
     keep unexecutable points out of the measured space).  Dense / lowered /
     csr-direct candidates stay float32 always.
+
+    ``row_nnz`` is the built bank's longest row when the weights are
+    known: the ELL K it pads to decides the kernel's SMEM tiles, and the
+    sparsity estimate undershoots it on a magnitude-pruned bank.
     """
     if g.sparsity <= 0.0:
         # Dense-kept layers (paper: conv1 et al.) have no sparse format.
@@ -281,12 +285,12 @@ def enumerate_candidates(g: ConvGeometry,
                         fuse_res=fuse and g.residual,
                         value_itemsize=vsize,
                         quantized=quantized)[:MAX_TILINGS]
-                    for te, tf in tilings:
-                        out.append(Candidate("bsr", te=te, tf=tf, fuse=fuse,
+                    for te in tilings:
+                        out.append(Candidate("bsr", te=te, fuse=fuse,
                                              block_m=bm, block_n=bn,
                                              value_dtype=vdt))
     for pad_to in PAD_TO_BUCKETS:
-        k = g.k_est(pad_to)
+        k = g.k_est(pad_to) if row_nnz is None else ell_k(row_nnz, pad_to)
         if "lowered" in methods:
             out.append(Candidate("lowered", pad_to=pad_to))
         if "csr-direct" in methods:
@@ -307,10 +311,10 @@ def enumerate_candidates(g: ConvGeometry,
                         g.m, g.c, g.e, g.f, k, g.r, g.s, g.stride,
                         fuse_res=fuse and g.residual,
                         pipeline=pipe, value_itemsize=vsize)[:MAX_TILINGS]
-                    for tm, te, tf in tilings:
+                    for tm, te in tilings:
                         for permute in (False, True):
                             out.append(Candidate(
-                                "pallas", tm=tm, pad_to=pad_to, te=te, tf=tf,
+                                "pallas", tm=tm, pad_to=pad_to, te=te,
                                 fuse=fuse, pipeline=pipe, permute=permute,
                                 value_dtype=vdt))
     return out

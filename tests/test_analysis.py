@@ -383,7 +383,7 @@ def test_strict_bind_clean(alexnet_bound):
 def test_strict_bind_rejects_poisoned_plan(alexnet_bound):
     program, params = alexnet_bound
     name = next(op.name for op in program.conv_ops if op.sparsity > 0)
-    plan = {name: PlanEntry(method="pallas", tm=7, pad_to=8, te=8, tf=8)}
+    plan = {name: PlanEntry(method="pallas", tm=7, pad_to=8, te=8)}
     with pytest.raises(PreflightError) as exc:
         CnnEngine(program, params, plan, strict=True)
     assert {d.rule for d in exc.value.diagnostics} == {

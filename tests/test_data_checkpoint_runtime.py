@@ -154,3 +154,22 @@ def test_plan_remesh():
     assert plan_remesh(512, model=16, pod_axis=True) == (
         (2, 16, 16), ("pod", "data", "model"))
     assert plan_remesh(15, model=16) is None
+
+
+# --------------------------- compile cache ---------------------------------
+
+def test_compile_cache_dir_env_or_checkout(monkeypatch):
+    """The env var's directory is left to JAX and no other is set; without
+    it the cache goes to ``.jax_cache`` at the checkout root.  The config
+    update is recorded, not applied: tests never turn the cache on."""
+    from repro.runtime import compile_cache
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, val: updates.append((name, val)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+    assert updates == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    root = pathlib.Path(__file__).resolve().parents[1]
+    assert compile_cache.enable_compile_cache() == str(root / ".jax_cache")
+    assert updates == [("jax_compilation_cache_dir", str(root / ".jax_cache"))]

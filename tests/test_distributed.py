@@ -1,5 +1,6 @@
 """Sharding rules, spec resolution, and a real multi-device train step
 (8 forced host devices in a subprocess, since device count locks at init)."""
+import os
 import subprocess
 import sys
 import textwrap
@@ -12,6 +13,8 @@ from jax.sharding import PartitionSpec as P
 from repro.distributed import sharding as shd
 from repro import configs as cfgs
 from repro.models import transformer as T
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_resolve_rules():
@@ -101,6 +104,6 @@ def test_train_step_on_8_devices(arch):
         [sys.executable, "-c", MULTIDEV_SCRIPT.format(arch=arch)],
         capture_output=True, text=True, timeout=600,
         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root", "JAX_PLATFORMS": "cpu"},
-        cwd="/root/repo")
+             "HOME": os.environ.get("HOME", ""), "JAX_PLATFORMS": "cpu"},
+        cwd=REPO_ROOT)
     assert "MULTIDEV_OK" in r.stdout, r.stderr[-2000:]

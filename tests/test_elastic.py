@@ -1,8 +1,11 @@
 """Elastic scaling integration: checkpoint on one mesh layout, restore onto
 another (the 1000-node failover path), in a forced-8-device subprocess."""
+import os
 import subprocess
 import sys
 import textwrap
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 SCRIPT = textwrap.dedent("""
@@ -65,7 +68,7 @@ def test_checkpoint_restores_across_mesh_shapes():
     r = subprocess.run(
         [sys.executable, "-c", SCRIPT], capture_output=True, text=True,
         timeout=600,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root",
+        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": os.environ.get("HOME", ""),
              "JAX_PLATFORMS": "cpu"},
-        cwd="/root/repo")
+        cwd=REPO_ROOT)
     assert "ELASTIC_OK" in r.stdout, r.stderr[-2000:]

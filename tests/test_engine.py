@@ -323,6 +323,36 @@ def test_engine_fused_methods_match_dense(method):
         x, "pallas", fuse=False))
     np.testing.assert_allclose(unfused, ref, rtol=1e-5, atol=1e-5)
 
+
+def test_trace_built_bcsr_bank_survives_retrace():
+    """A BCSR bank blocked from the dense weights inside one trace is cached
+    on the engine; a later trace (another method reusing the bank, or the
+    AOT ``lowered`` retrace) must read concrete arrays, not a leaked
+    tracer."""
+    import dataclasses
+
+    from repro.tuning import PlanCache, plan_program
+
+    net = [cnn.Conv("c0", 8, 3, 1, 1, sparsity=0.0), cnn.Relu(),
+           cnn.Conv("c1", 16, 3, 1, 1, sparsity=0.7), cnn.Relu()]
+    params = cnn.init_cnn(net, 3, np.random.default_rng(0), 8)
+    eng = cnn.engine_for(net, params, (3, 8, 8))
+    x = jnp.asarray(np.random.default_rng(1).standard_normal(
+        (1, 3, 8, 8)).astype(np.float32))
+    ref = np.asarray(eng(x, "dense"))
+    y = np.asarray(eng(x, "bsr"))
+    assert eng._bcc_cache
+    for bank in eng._bcc_cache.values():
+        assert not isinstance(bank.blocks, jax.core.Tracer)
+    eng.lowered(x, "bsr").compile()
+    bsr_plan = {"c1": dataclasses.replace(
+        plan_program(eng.program, batch=1, mode="roofline",
+                     cache=PlanCache(), backend="cpu")["c1"],
+        method="bsr", block_m=8, block_n=128, te=None, tm=None)}
+    y_auto = np.asarray(eng(x, "auto", plan_override=bsr_plan))
+    np.testing.assert_allclose(y, ref, rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(y_auto, ref, rtol=1e-4, atol=1e-4)
+
 # ---------------------------------------------------------------------------
 # quantised value streams: pinned plans execute narrow banks, stale plans
 # fall back loudly
@@ -340,7 +370,8 @@ def _quant_micro():
     program = lower(net, (3, 8, 8))
     params = cnn.init_cnn(net, 3, rng, 8)
     x = rng.standard_normal((1, 3, 8, 8)).astype(np.float32)
-    plan = plan_program(program, batch=1, mode="roofline", cache=PlanCache())
+    plan = plan_program(program, batch=1, mode="roofline", cache=PlanCache(),
+                        backend="cpu")
     qplan = {name: (dataclasses.replace(pe, value_dtype="int8")
                     if pe.method in ("pallas", "bsr") else pe)
              for name, pe in plan.items()}

@@ -19,6 +19,13 @@ from repro.kernels.sparse_conv.ops import sparse_conv
 pytestmark = pytest.mark.pallas
 
 
+def _row_tile(e, f):
+    """The smallest ragged row tile the TPU can block for an E x F output —
+    the kernel's flat (bm, TE*F) out block needs TE*F to be a multiple of
+    128 lanes — or all of E when no smaller one exists."""
+    return next((t for t in range(1, e) if (t * f) % 128 == 0), e)
+
+
 def _case(seed, n, c, h, w, m, r, sp, block, *, dtype=jnp.float32):
     rng = np.random.default_rng(seed)
     x = jnp.asarray(rng.standard_normal((n, c, h, w)), dtype=dtype)
@@ -37,11 +44,11 @@ def _case(seed, n, c, h, w, m, r, sp, block, *, dtype=jnp.float32):
 @pytest.mark.parametrize("residual", [False, True])
 @pytest.mark.parametrize("block", [(4, 8), (8, 16)])
 def test_bsr_parity_grid(stride, pad, residual, block):
-    """The full grid with edge tiles (te/tf deliberately not dividing E/F)
-    and a non-dividing M (channel padding path), against the dense oracle
-    — and bit-identical to the blocked structural mirror on the untiled
-    schedule."""
-    n, c, h, w, m, r = 2, 4, 13, 11, 12, 3
+    """The full grid with edge tiles (te deliberately not dividing E where
+    a blockable one exists) and a non-dividing M (channel padding path),
+    against the dense oracle — and bit-identical to the blocked structural
+    mirror on the untiled schedule."""
+    n, c, h, w, m, r = 2, 4, 13, 18, 12, 3
     seed = 5000 + 1000 * stride + 100 * pad + 10 * residual + block[0]
     rng, x, wt = _case(seed, n, c, h, w, m, r, 0.6, block)
     bc = bcsr_conv_from_dense(wt, block=block)
@@ -50,8 +57,7 @@ def test_bsr_parity_grid(stride, pad, residual, block):
     e, f = out_spatial(h, w, r, r, stride, pad)
     res = (jnp.asarray(rng.standard_normal((n, m, e, f)).astype(np.float32))
            if residual else None)
-    te, tf = max(1, (e + 1) // 2), max(1, f // 2 + 1)   # non-dividing tiles
-    got = bsr_conv(x, bc, stride=stride, padding=pad, te=te, tf=tf,
+    got = bsr_conv(x, bc, stride=stride, padding=pad, te=_row_tile(e, f),
                    bias=bias, fuse_relu=True, residual=res, interpret=True)
     ref = bsr_conv_ref(x, jnp.asarray(wt), stride=stride, padding=pad)
     ref = jax.nn.relu(ref + bias[None, :, None, None]
@@ -125,7 +131,7 @@ def test_bsr_unstructured_weights_still_correct():
 # ---------------------------------------------------------------------------
 
 def test_bsr_vmem_infeasible_falls_back(monkeypatch):
-    """When no (te, tf) tiling fits VMEM, bsr_conv must fall back to the
+    """When no row tiling fits VMEM, bsr_conv must fall back to the
     dense-reconstruction path — with the epilogue still applied — instead
     of launching the kernel."""
     rng, x, wt = _case(13, 1, 4, 10, 10, 8, 3, 0.5, (4, 8))
@@ -147,7 +153,7 @@ def test_bsr_vmem_infeasible_falls_back(monkeypatch):
 
 
 def test_bsr_stale_infeasible_tiling_falls_back(monkeypatch):
-    """A fully-specified (te, tf) from a stale tuned plan that busts VMEM
+    """A pinned row tile from a stale tuned plan that busts VMEM
     must fall back, never launch over budget."""
     rng, x, wt = _case(17, 1, 4, 16, 16, 8, 3, 0.5, (4, 8))
     bc = bcsr_conv_from_dense(wt, block=(4, 8))
@@ -160,7 +166,7 @@ def test_bsr_stale_infeasible_tiling_falls_back(monkeypatch):
         raise AssertionError("over-budget kernel launch")
 
     monkeypatch.setattr(ops, "bsr_conv_pallas", _boom)
-    got = bsr_conv(x, bc, padding=1, te=16, tf=16, interpret=True)
+    got = bsr_conv(x, bc, padding=1, te=16, interpret=True)
     ref = bsr_conv_ref(x, jnp.asarray(wt), padding=1)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
@@ -200,15 +206,20 @@ def test_bsr_fully_pruned_bank():
 def test_bsr_tiling_fits_accounts_residual_tile():
     """Reserving the fused-residual input tile can rule out tilings that
     fit without it."""
-    args = dict(c=8, r=3, s=3, stride=1, bm=8, bn=64, te=64, tf=64)
-    x_bytes = 8 * 66 * 66 * 4
-    w_bytes = 8 * 64 * 4
-    patch = 64 * 64 * 64 * 4
-    out = 8 * 64 * 64 * 4
+    args = dict(c=8, r=3, s=3, stride=1, bm=8, bn=64, te=64, f=64)
+    # 8 staged planes of 66 rows (72) x 66 columns (128); the (64, 64, 64)
+    # patch scratch (64 x 128 planes) and its flat f32 (64, 4096) operand;
+    # double-buffered (8, 64) weight, f32 (8, 4096) out and (8, 1) bias
+    # tiles — all f32, at their (8, 128)-tile-padded sizes.
+    x_bytes = 8 * 72 * 128 * 4
+    patch = 64 * 64 * 128 * 4 + 64 * 4096 * 4
+    w_bytes = 2 * 8 * 128 * 4
+    out = 2 * 8 * 4096 * 4
+    bias = 2 * 8 * 128 * 4
     import repro.kernels.bsr_conv.ops as bops
     orig = bops.VMEM_BUDGET
     try:
-        bops.VMEM_BUDGET = x_bytes + w_bytes + patch + out
+        bops.VMEM_BUDGET = x_bytes + patch + w_bytes + out + bias
         assert bsr_tiling_fits(**args)
         assert not bsr_tiling_fits(**args, fuse_res=True)
     finally:
@@ -243,8 +254,8 @@ def test_bsr_quantised_bit_identical_to_blocked_mirror(value_dtype, stride):
     mirror = bsr_conv_blocked_ref(x, q, **kw)
     np.testing.assert_array_equal(np.asarray(got, np.float32),
                                   np.asarray(mirror, np.float32))
-    te, tf = max(1, (e + 1) // 2), max(1, f // 2 + 1)   # non-dividing tiles
-    got_tiled = bsr_conv(x, q, te=te, tf=tf, interpret=True, **kw)
+    got_tiled = bsr_conv(x, q, te=_row_tile(e, f), interpret=True,
+                         **kw)
     np.testing.assert_allclose(np.asarray(got_tiled), np.asarray(got),
                                rtol=1e-5, atol=1e-5)
     ref = bsr_conv_ref(x, jnp.asarray(wt), stride=stride, padding=pad)

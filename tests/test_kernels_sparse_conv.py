@@ -7,6 +7,7 @@ import pytest
 
 from repro.core import balance_ell_conv, ell_from_dense_conv, magnitude_prune
 from repro.core.direct_conv import out_spatial
+from repro.kernels import budget as kbudget
 from repro.kernels.sparse_conv import ops
 from repro.kernels.sparse_conv.kernel import sparse_conv_pallas
 from repro.kernels.sparse_conv.ops import (choose_tiles, choose_tm,
@@ -15,6 +16,13 @@ from repro.kernels.sparse_conv.ops import (choose_tiles, choose_tm,
 from repro.kernels.sparse_conv.ref import sparse_conv_ref
 
 pytestmark = pytest.mark.pallas
+
+
+def _row_tile(e):
+    """A blockable row tile that leaves a ragged edge tile where E allows:
+    the TPU blocks rows in multiples of 8 (or all of E) and never tiles
+    columns."""
+    return 8 if e > 8 else e
 
 CASES = [
     # (N, C, H, W, M, R, pad, sparsity)
@@ -60,14 +68,22 @@ def test_kernel_dtypes(dtype):
 
 
 @pytest.mark.parametrize("tm", [1, 2, 4, 8])
-def test_kernel_channel_tiles(tm):
-    """Every channel-tile size produces identical results."""
+def test_kernel_channel_tiles(tm, monkeypatch):
+    """Every legal channel-tile size (8, 16, 32, 64 of M=64 — multiples of
+    the TPU's 8-row block) produces identical results through the
+    kernel."""
     rng = np.random.default_rng(11)
     x = jnp.asarray(rng.standard_normal((1, 4, 8, 8)).astype(np.float32))
     wt = np.asarray(magnitude_prune(
-        jnp.asarray(rng.standard_normal((8, 4, 3, 3)).astype(np.float32)), 0.7))
+        jnp.asarray(rng.standard_normal((64, 4, 3, 3)).astype(np.float32)), 0.7))
     ell = ell_from_dense_conv(wt)
-    got = sparse_conv(x, ell, tm=tm, interpret=True)
+    launches = []
+    real = ops.sparse_conv_pallas
+    monkeypatch.setattr(
+        ops, "sparse_conv_pallas",
+        lambda *a, **kw: launches.append(kw) or real(*a, **kw))
+    got = sparse_conv(x, ell, tm=8 * tm, interpret=True)
+    assert launches and launches[0]["tm"] == 8 * tm
     ref = sparse_conv_ref(x, jnp.asarray(wt))
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
@@ -99,8 +115,8 @@ def test_strided_runs_in_kernel(monkeypatch):
 @pytest.mark.parametrize("stride", [1, 2, 4])
 @pytest.mark.parametrize("pad", [0, 1, 2])
 def test_strided_tiled_parity(stride, pad):
-    """(stride, padding) grid through the spatially-tiled kernel with edge
-    tiles: te/tf deliberately do not divide E/F."""
+    """(stride, padding) grid through the row-tiled kernel with edge tiles:
+    te deliberately does not divide E where E allows it."""
     n, c, h, w, m, r = 2, 3, 15, 13, 8, 3
     rng = np.random.default_rng(100 * stride + pad)
     x = jnp.asarray(rng.standard_normal((n, c, h, w)).astype(np.float32))
@@ -108,9 +124,8 @@ def test_strided_tiled_parity(stride, pad):
         jnp.asarray(rng.standard_normal((m, c, r, r)).astype(np.float32)), 0.7))
     ell = ell_from_dense_conv(wt)
     e, f = out_spatial(h, w, r, r, stride, pad)
-    te, tf = max(1, (e + 1) // 2), max(1, f // 2 + 1)   # non-dividing tiles
     got = sparse_conv(x, ell, stride=stride, padding=pad,
-                      tm=4, te=te, tf=tf, interpret=True)
+                      tm=8, te=_row_tile(e), interpret=True)
     ref = sparse_conv_ref(x, jnp.asarray(wt), stride=stride, padding=pad)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(ref, np.float32),
@@ -139,7 +154,7 @@ def test_large_feature_map_spatially_tiled():
     refused it (and the [1]-fallback bug would have launched over budget)."""
     n, c, h, w, m, r, pad = 1, 96, 192, 192, 8, 3, 1
     hp = wp = h + 2 * pad
-    assert c * hp * wp * 4 > ops.VMEM_BUDGET  # genuinely oversized
+    assert c * kbudget.vmem_tile_bytes(hp, wp, 4) > ops.VMEM_BUDGET
     rng = np.random.default_rng(31)
     x = jnp.asarray(rng.standard_normal((n, c, h, w)).astype(np.float32))
     wt = np.asarray(magnitude_prune(
@@ -149,7 +164,7 @@ def test_large_feature_map_spatially_tiled():
     # regression: the untiled ladder must report infeasible, not [1]
     assert tm_candidates(m, c, hp, wp, e, f, ell.k) == []
     tiles = choose_tiles(m, c, e, f, ell.k, r, r, 1)
-    assert tiles is not None and (tiles[1] < e or tiles[2] < f)
+    assert tiles is not None and tiles[1] < e
     got = sparse_conv(x, ell, padding=pad, interpret=True)
     ref = sparse_conv_ref(x, jnp.asarray(wt), padding=pad)
     np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -184,7 +199,7 @@ def test_off_ladder_tm_honored(monkeypatch):
 
 
 def test_vmem_infeasible_falls_back_to_direct(monkeypatch):
-    """When no (tm, te, tf) tiling fits VMEM, sparse_conv must fall back to
+    """When no (tm, te) tiling fits VMEM, sparse_conv must fall back to
     the pure-JAX direct path instead of launching the kernel."""
     rng = np.random.default_rng(37)
     x = jnp.asarray(rng.standard_normal((1, 4, 10, 10)).astype(np.float32))
@@ -214,8 +229,8 @@ def test_vmem_infeasible_falls_back_to_direct(monkeypatch):
 def test_pipelined_matches_blocking(stride, residual, dtype):
     """Interpret-mode parity grid: the double-buffered schedule must be
     *bit-identical* to the single-buffer one (same FMA order, different
-    staging only) across stride x residual x dtype, with edge tiles (te/tf
-    deliberately not dividing E/F) so the prefetch crosses ragged cells."""
+    staging only) across stride x residual x dtype, with edge tiles (te
+    deliberately not dividing E) so the prefetch crosses ragged cells."""
     import dataclasses
     n, c, h, w, m, r, pad = 2, 4, 13, 11, 8, 3, 1
     rng = np.random.default_rng(9000 + 100 * stride + 10 * residual
@@ -230,9 +245,8 @@ def test_pipelined_matches_blocking(stride, residual, dtype):
     e, f = out_spatial(h, w, r, r, stride, pad)
     res = (jnp.asarray(rng.standard_normal((n, m, e, f)).astype(np.float32),
                        dtype=dtype) if residual else None)
-    te, tf = max(1, (e + 1) // 2), max(1, f // 2 + 1)   # non-dividing tiles
-    kw = dict(stride=stride, padding=pad, tm=4, te=te, tf=tf, bias=bias,
-              fuse_relu=True, residual=res, interpret=True)
+    kw = dict(stride=stride, padding=pad, tm=8, te=_row_tile(e),
+              bias=bias, fuse_relu=True, residual=res, interpret=True)
     y_block = sparse_conv(x, ell, pipeline=False, **kw)
     y_pipe = sparse_conv(x, ell, pipeline=True, **kw)
     np.testing.assert_array_equal(np.asarray(y_block, np.float32),
@@ -277,20 +291,22 @@ def test_pipeline_drops_to_single_buffer_when_double_halo_busts(monkeypatch):
         jnp.asarray(rng.standard_normal((8, 4, 3, 3)).astype(np.float32)), 0.7))
     ell = ell_from_dense_conv(wt)
     e = f = 16
-    tm, te, tf = 8, 16, 16
-    # Budget: exactly one halo block + values + out tile — no second buffer.
-    x_bytes = 4 * 18 * 18 * 4
-    budget = x_bytes + tm * ell.k * 4 + tm * te * tf * 4
+    tm, te = 8, 16
+    # Budget: exactly one halo block — 4 planes of 18 rows (24, whole
+    # 8-row sublane tiles) by 18 columns (one 128-lane tile), f32 — plus
+    # the double-buffered f32 out tile, 8 channels of 16 x 16 (16 x 128
+    # padded).  No room for a second halo buffer.
+    budget = 4 * 24 * 128 * 4 + 2 * 8 * 16 * 128 * 4
     monkeypatch.setattr(ops, "_VMEM_BUDGET", budget)
-    assert ops.tiling_fits(8, 4, e, f, ell.k, 3, 3, 1, tm, te, tf)
-    assert not ops.tiling_fits(8, 4, e, f, ell.k, 3, 3, 1, tm, te, tf,
+    assert ops.tiling_fits(8, 4, e, f, ell.k, 3, 3, 1, tm, te)
+    assert not ops.tiling_fits(8, 4, e, f, ell.k, 3, 3, 1, tm, te,
                                pipeline=True)
     launches = []
     real = ops.sparse_conv_pallas
     monkeypatch.setattr(
         ops, "sparse_conv_pallas",
         lambda *a, **kw: launches.append(kw) or real(*a, **kw))
-    got = sparse_conv(x, ell, padding=1, tm=tm, te=te, tf=tf, pipeline=True,
+    got = sparse_conv(x, ell, padding=1, tm=tm, te=te, pipeline=True,
                       interpret=True)
     ref = sparse_conv_ref(x, jnp.asarray(wt), padding=1)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
@@ -357,17 +373,20 @@ def test_balanced_bank_fallback_unpermutes(monkeypatch):
 # ---------------------------------------------------------------------------
 
 def test_smem_fits_budgets_nnz_row():
-    """Regression: smem_fits must account all *three* scalar-prefetched
-    operands — packed indices, the int32 nnz row, and the f32 bias row.
-    Pick (m, k) where indices + bias alone fit but adding the nnz row
-    overshoots: the old two-term check said yes and overshot SMEM."""
+    """Regression: smem_fits must account every SMEM operand — the
+    double-buffered (TM, K) index and value tiles *and* the three
+    scalar-prefetched rows, the int32 nnz row among them.  Pick (m, k)
+    where the tiles plus two rows fit but adding the nnz row overshoots:
+    a check that forgot it would say yes and overshoot SMEM."""
     budget = ops.SMEM_BUDGET
-    m = 1024
-    # m*k*4 + m*4 <= budget < m*k*4 + 2*m*4
-    k = (budget - m * 4) // (m * 4)
-    assert m * k * 4 + m * 4 <= budget < m * k * 4 + 2 * m * 4
+    m = 8192
+    row = kbudget.smem_array_bytes(1, m, 4)
+    # k a multiple of 128, so each (8, k) f32/int32 tile is exactly 32k B.
+    k = (budget - 2 * row) // (4 * 8 * 4 * 128) * 128
+    tiles = 2 * 2 * 8 * k * 4
+    assert tiles + 2 * row <= budget < tiles + 3 * row
     assert not smem_fits(m, k)
-    assert smem_fits(m, k - 1)
+    assert smem_fits(m, (budget - 3 * row) // (4 * 8 * 4 * 128) * 128)
 
 
 def test_non_dividing_tm_raises_value_error():
@@ -402,7 +421,7 @@ def test_stale_plan_non_dividing_tm_falls_back(monkeypatch):
 
     monkeypatch.setattr(ops, "sparse_conv_pallas", _boom)
     # fully-specified stale tiling: tm=3 does not divide m=8
-    got = sparse_conv(x, ell, padding=1, tm=3, te=8, tf=8, interpret=True)
+    got = sparse_conv(x, ell, padding=1, tm=3, te=8, interpret=True)
     ref = sparse_conv_ref(x, jnp.asarray(wt), padding=1)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
@@ -433,7 +452,7 @@ def _unfused_oracle(x, wt, bias, *, stride, pad, residual=None):
 @pytest.mark.parametrize("residual", [False, True])
 def test_fused_epilogue_parity(stride, residual):
     """Fused conv+bias+ReLU (and +residual) vs the unfused dense oracle,
-    with edge tiles: te/tf deliberately do not divide E/F."""
+    with edge tiles: te deliberately does not divide E where E allows."""
     n, c, h, w, m, r, pad = 2, 4, 13, 11, 8, 3, 1
     rng, x, wt, bias = _epilogue_case(1000 + 10 * stride + residual,
                                       n, c, h, w, m, r)
@@ -441,9 +460,9 @@ def test_fused_epilogue_parity(stride, residual):
     e, f = out_spatial(h, w, r, r, stride, pad)
     res = (jnp.asarray(rng.standard_normal((n, m, e, f)).astype(np.float32))
            if residual else None)
-    te, tf = max(1, (e + 1) // 2), max(1, f // 2 + 1)   # non-dividing tiles
-    got = sparse_conv(x, ell, stride=stride, padding=pad, tm=4, te=te, tf=tf,
-                      bias=bias, fuse_relu=True, residual=res, interpret=True)
+    got = sparse_conv(x, ell, stride=stride, padding=pad, tm=8,
+                      te=_row_tile(e), bias=bias, fuse_relu=True,
+                      residual=res, interpret=True)
     ref = _unfused_oracle(x, wt, bias, stride=stride, pad=pad, residual=res)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(ref, np.float32),
@@ -498,14 +517,13 @@ def test_fused_residual_tightens_vmem_feasibility(monkeypatch):
     """Reserving the residual input tile can rule out tilings that fit
     without it — tiling_fits must account the extra block."""
     from repro.kernels.sparse_conv.ops import tiling_fits
-    args = dict(m=8, c=8, e=64, f=64, k=16, r=3, s=3, stride=1,
-                tm=8, te=64, tf=64)
-    # budget sized to fit input block + values + out tile, but not a second
-    # out-tile-sized residual block
-    x_bytes = 8 * 66 * 66 * 4
-    out_bytes = 8 * 64 * 64 * 4
+    args = dict(m=8, c=8, e=64, f=64, k=16, r=3, s=3, stride=1, tm=8, te=64)
+    # Budget sized to fit the input block — 8 planes of 66 rows (72, whole
+    # 8-row sublane tiles) by 66 columns (one 128-lane tile), f32 — and the
+    # double-buffered f32 out tile, 8 channels of 64 x 64 (64 x 128
+    # padded), but not the residual tile of the same size.
     monkeypatch.setattr(ops, "_VMEM_BUDGET",
-                        x_bytes + 8 * 16 * 4 + out_bytes)
+                        8 * 72 * 128 * 4 + 2 * 8 * 64 * 128 * 4)
     assert tiling_fits(**args)
     assert not tiling_fits(**args, fuse_res=True)
 
@@ -513,7 +531,8 @@ def test_fused_residual_tightens_vmem_feasibility(monkeypatch):
 def test_choose_tm_fits_budget():
     tm = choose_tm(m=256, c=96, hp=31, wp=31, e=27, f=27, k=256)
     assert 256 % tm == 0
-    assert (96 * 31 * 31 * 4 + tm * 256 * 4 + tm * 27 * 27 * 4) <= 12 * 2**20
+    assert (96 * kbudget.vmem_tile_bytes(31, 31, 4)
+            + 2 * tm * kbudget.vmem_tile_bytes(27, 27, 4)) <= 12 * 2**20
 
 
 @pytest.mark.parametrize("pad_to", [1, 4, 8])
@@ -558,8 +577,8 @@ def test_quantised_bank_bit_identical_to_dequantised(value_dtype, pipeline,
     accumulator) performs the exact multiply dequantize() does host-side,
     so a quantised bank through either schedule is bit-identical to the
     f32 kernel run on the dequantised bank — and within quantisation
-    tolerance of the dense oracle.  Edge tiles (te/tf not dividing E/F)
-    and the fused epilogue ride along."""
+    tolerance of the dense oracle.  Edge tiles (te not dividing E) and the
+    fused epilogue ride along."""
     n, c, h, w, m, r, pad = 2, 4, 13, 11, 8, 3, 1
     rng = np.random.default_rng(31000 + 100 * stride + 10 * pipeline
                                 + len(value_dtype))
@@ -571,9 +590,9 @@ def test_quantised_bank_bit_identical_to_dequantised(value_dtype, pipeline,
     bias = jnp.asarray(rng.standard_normal((m,)).astype(np.float32))
     e, f = out_spatial(h, w, r, r, stride, pad)
     res = jnp.asarray(rng.standard_normal((n, m, e, f)).astype(np.float32))
-    te, tf = max(1, (e + 1) // 2), max(1, f // 2 + 1)   # non-dividing tiles
-    kw = dict(stride=stride, padding=pad, tm=4, te=te, tf=tf, bias=bias,
-              fuse_relu=True, residual=res, pipeline=pipeline, interpret=True)
+    kw = dict(stride=stride, padding=pad, tm=8, te=_row_tile(e),
+              bias=bias, fuse_relu=True, residual=res, pipeline=pipeline,
+              interpret=True)
     y_q = sparse_conv(x, q, **kw)
     y_f32 = sparse_conv(x, dequantize(q), **kw)
     np.testing.assert_array_equal(np.asarray(y_q), np.asarray(y_f32))

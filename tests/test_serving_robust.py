@@ -337,7 +337,7 @@ def test_corrupt_plan_cache_degrades_resiliently(tmp_path, mode, alex):
     program = lower(net, (3, 12, 12))
     path = str(tmp_path / "plans.json")
     plan_program(program, batch=2, mode="roofline", cache=PlanCache(path),
-                 params=params)
+                 params=params, backend="cpu")
     corrupt_plan_cache_file(path, mode=mode)
     with pytest.warns(PlanCacheWarning):
         srv = RobustCnnServer(net, params, [BucketSpec(3, 12, 12, batch=2)],

@@ -39,7 +39,8 @@ def _micro_engine(image=8):
     net = _micro_net()
     program = lower(net, (3, image, image))
     params = cnn.init_cnn(net, 3, rng, image)
-    plan = plan_program(program, batch=1, mode="roofline", cache=PlanCache())
+    plan = plan_program(program, batch=1, mode="roofline", cache=PlanCache(),
+                        backend="cpu")
     apply_plan_to_params(params, plan)
     x = rng.standard_normal((1, 3, image, image)).astype(np.float32)
     return CnnEngine(program, params, plan), x
@@ -224,14 +225,15 @@ def test_plan_provenance_fresh_then_cache_hit(tmp_path):
     program = lower(net, (3, 8, 8))
     path = tmp_path / "cache.json"
     cache = PlanCache(str(path))
-    plan = plan_program(program, batch=1, mode="roofline", cache=cache)
+    plan = plan_program(program, batch=1, mode="roofline", cache=cache,
+                        backend="cpu")
     assert all(pe.provenance in ("freshly_tuned", "default")
                for pe in plan.values())
     assert any(pe.provenance == "freshly_tuned" for pe in plan.values())
 
     with telemetry.enabled():
         replan = plan_program(program, batch=1, mode="roofline",
-                              cache=PlanCache(str(path)))
+                              cache=PlanCache(str(path)), backend="cpu")
         assert replan == plan  # provenance is excluded from equality
         assert all(pe.provenance == "cache_hit" for pe in replan.values())
         assert (telemetry.snapshot()["tuning.plan.cache_hit"]["value"]
@@ -248,7 +250,8 @@ def test_execution_report_all_networks(net_name, image):
     net = cnn.NETWORKS[net_name]()
     program = lower(net, (3, image, image))
     params = cnn.init_cnn(net, 3, rng, image)
-    plan = plan_program(program, batch=1, mode="roofline", cache=PlanCache())
+    plan = plan_program(program, batch=1, mode="roofline", cache=PlanCache(),
+                        backend="cpu")
     apply_plan_to_params(params, plan)
     engine = CnnEngine(program, params, plan)
 

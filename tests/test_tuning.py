@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 from repro.kernels.sparse_conv.ops import (SMEM_BUDGET, VMEM_BUDGET,
-                                           choose_tm, halo_extent,
+                                           choose_tm,
                                            tiling_fits, tm_candidates)
 from repro.models import cnn
 from repro.tuning import (Candidate, ConvGeometry, PlanCache, PlanEntry,
@@ -28,29 +28,26 @@ def _geom(**kw):
 # ---------------------------------------------------------------------------
 
 def _assert_pallas_fits(g, cands):
-    """Every pallas candidate's (tm, te, tf) halo'd working set fits VMEM —
+    """Every pallas candidate's (tm, te) halo'd working set fits VMEM —
     fused candidates accounting the residual input tile, pipelined ones the
-    second halo scratch buffer — and the three scalar-prefetch operands
-    (packed indices + nnz row + bias row) fit SMEM."""
+    second halo scratch buffer — and its (TM, K) index and value tiles plus
+    the scalar-prefetched rows fit SMEM."""
+    from repro.kernels.budget import ell_smem_bytes, ell_vmem_bytes
     assert any(c.method == "pallas" for c in cands)
     for cd in cands:
         if cd.method != "pallas":
             continue
         assert g.m % cd.tm == 0
-        assert cd.te is not None and cd.tf is not None
+        assert cd.te is not None
         k = g.k_est(cd.pad_to)
-        x_bytes = (g.c * halo_extent(cd.te, g.stride, g.r)
-                   * halo_extent(cd.tf, g.stride, g.s) * 4)
-        if cd.pipeline:
-            x_bytes *= 2
-        out_bytes = cd.tm * cd.te * cd.tf * 4
-        res_bytes = out_bytes if (cd.fuse and g.residual) else 0
-        assert x_bytes + cd.tm * k * 4 + out_bytes + res_bytes <= VMEM_BUDGET
+        fuse_res = cd.fuse and g.residual
+        assert ell_vmem_bytes(g.c, g.f, g.r, g.s, g.stride, cd.tm, cd.te,
+                              fuse_res=fuse_res,
+                              pipeline=cd.pipeline) <= VMEM_BUDGET
         assert tiling_fits(g.m, g.c, g.e, g.f, k, g.r, g.s, g.stride,
-                           cd.tm, cd.te, cd.tf,
-                           fuse_res=cd.fuse and g.residual,
+                           cd.tm, cd.te, fuse_res=fuse_res,
                            pipeline=cd.pipeline)
-        assert g.m * (k + 2) * 4 <= SMEM_BUDGET
+        assert ell_smem_bytes(g.m, k, tm=cd.tm) <= SMEM_BUDGET
 
 
 def test_candidates_tiles_divide_m_and_fit_budgets():
@@ -75,13 +72,13 @@ def test_large_map_layer_gets_spatially_tiled_pallas():
     assert g.c * g.hp * g.wp * 4 > VMEM_BUDGET
     cands = enumerate_candidates(g)
     _assert_pallas_fits(g, cands)
-    assert all(cd.te < g.e or cd.tf < g.f
-               for cd in cands if cd.method == "pallas")
+    assert all(cd.te < g.e for cd in cands if cd.method == "pallas")
 
 
 def test_smem_heavy_layer_has_no_pallas():
-    # m*k*4 far over the SMEM budget: huge M, near-dense rows.
-    g = _geom(m=8192, c=512, sparsity=0.05)
+    # Even the smallest channel tile's (8, K) index + value tiles are far
+    # over the SMEM budget: near-dense rows of a 4096-channel 3x3 bank.
+    g = _geom(m=64, c=4096, sparsity=0.05)
     assert all(c.method != "pallas" for c in enumerate_candidates(g))
 
 
@@ -117,8 +114,7 @@ def test_roofline_pallas_spatial_tiling_costs_halo():
     score no worse than a tiled one on a memory-bound geometry that fits."""
     g = _geom()
     t_full = roofline_estimate(g, Candidate("pallas", tm=8, pad_to=8))
-    t_tiled = roofline_estimate(g, Candidate("pallas", tm=8, pad_to=8,
-                                             te=8, tf=8))
+    t_tiled = roofline_estimate(g, Candidate("pallas", tm=8, pad_to=8, te=8))
     assert t_full <= t_tiled
 
 
@@ -182,7 +178,8 @@ def test_plan_program_dedups_on_op_geometry():
 
     planner_mod.plan_layer, plan = spy, None
     try:
-        plan = planner_mod.plan_program(program, batch=1, mode="roofline")
+        plan = planner_mod.plan_program(program, batch=1, mode="roofline",
+                                        backend="cpu")
     finally:
         planner_mod.plan_layer = orig
     # 4 sparse convs, but only 2 distinct (geometry, epilogue) keys:
@@ -211,11 +208,13 @@ def test_pipelined_tilings_reserve_second_halo_buffer(monkeypatch):
     """A tiling whose single halo block fits but whose doubled block busts
     VMEM must be blocking-only in the candidate space."""
     import repro.kernels.sparse_conv.ops as ops
-    args = dict(m=8, c=8, e=64, f=64, k=16, r=3, s=3, stride=1,
-                tm=8, te=64, tf=64)
-    x_bytes = 8 * 66 * 66 * 4
-    monkeypatch.setattr(ops, "_VMEM_BUDGET",
-                        x_bytes + 8 * 16 * 4 + 8 * 64 * 64 * 4)
+    args = dict(m=8, c=8, e=64, f=64, k=16, r=3, s=3, stride=1, tm=8, te=64)
+    # One halo block: 8 planes of 66 rows (72, whole 8-row sublane tiles)
+    # by 66 columns (one 128-lane tile), f32; plus the double-buffered f32
+    # out tile, 8 channels of 64 x 64 (64 x 128 padded).
+    halo = 8 * 72 * 128 * 4
+    out = 2 * 8 * 64 * 128 * 4
+    monkeypatch.setattr(ops, "_VMEM_BUDGET", halo + out)
     assert tiling_fits(**args)
     assert not tiling_fits(**args, pipeline=True)
 
@@ -227,7 +226,7 @@ def test_roofline_credits_pipelined_staging():
     from repro.tuning import staging_stall_s
 
     g = _geom()
-    base = dict(tm=8, pad_to=8, te=8, tf=8)
+    base = dict(tm=8, pad_to=8, te=8)
     blocking = Candidate("pallas", **base)
     pipelined = Candidate("pallas", **base, pipeline=True)
     assert roofline_estimate(g, pipelined) <= roofline_estimate(g, blocking)
@@ -254,7 +253,7 @@ def test_roofline_charges_permute_gather_only():
 
 
 def test_plan_entry_carries_pipeline_and_permute():
-    pe = PlanEntry(method="pallas", tm=8, te=8, tf=8, pad_to=8,
+    pe = PlanEntry(method="pallas", tm=8, te=8, pad_to=8,
                    pipeline=True, permute=True)
     assert pe.candidate.pipeline and pe.candidate.permute
     d = pe.to_dict()
@@ -280,9 +279,9 @@ def test_candidates_include_bsr_block_shapes():
     for cd in cands:
         assert cd.tm is None and cd.pad_to is None
         assert not cd.pipeline and not cd.permute
-        assert cd.te is not None and cd.tf is not None
+        assert cd.te is not None
         assert bsr_tiling_fits(g.c, g.r, g.s, g.stride, cd.block_m,
-                               cd.block_n, cd.te, cd.tf,
+                               cd.block_n, cd.te, g.f,
                                fuse_res=cd.fuse and g.residual)
 
 
@@ -313,15 +312,13 @@ def test_roofline_bsr_bigger_bm_amortises_gather():
     from repro.tuning.measure import _bsr_terms
 
     g = _geom(m=256, c=256, h=28, w=28, sparsity=0.6)
-    t8, _, _ = _bsr_terms(g, Candidate("bsr", te=28, tf=28,
-                                       block_m=8, block_n=128))
-    t64, _, _ = _bsr_terms(g, Candidate("bsr", te=28, tf=28,
-                                        block_m=64, block_n=128))
+    t8, _, _ = _bsr_terms(g, Candidate("bsr", te=28, block_m=8, block_n=128))
+    t64, _, _ = _bsr_terms(g, Candidate("bsr", te=28, block_m=64, block_n=128))
     assert t64 <= t8
 
 
 def test_plan_entry_carries_block_shape():
-    pe = PlanEntry(method="bsr", te=16, tf=16, fuse=True,
+    pe = PlanEntry(method="bsr", te=16, fuse=True,
                    block_m=32, block_n=128)
     assert pe.candidate.block_m == 32 and pe.candidate.block_n == 128
     d = pe.to_dict()
@@ -340,7 +337,7 @@ def test_auto_executes_bsr_plan():
     params = cnn.init_cnn(net, 3, rng, 10)
     x = jnp.asarray(rng.standard_normal((1, 3, 10, 10)).astype(np.float32))
     plan = {"c0": PlanEntry(method="dense"),
-            "c1": PlanEntry(method="bsr", te=6, tf=6, fuse=True,
+            "c1": PlanEntry(method="bsr", te=6, fuse=True,
                             block_m=8, block_n=32)}
     y_dense = cnn.cnn_forward(net, params, x, method="dense")
     # without apply_plan_to_params: the engine blocks the bank at trace time
@@ -375,17 +372,19 @@ def test_roofline_with_weights_recosts_bsr_from_actual_bank():
     gbn = -(-256 * 9 // 128)
     assert bcsr_true_kept(w, 8, 128) > 0.9 * gbn
     # the estimate prices bsr at ~10% of the tiles and picks it...
-    assert plan_layer(g, mode="roofline").method == "bsr"
+    assert plan_layer(g, mode="roofline", backend="cpu").method == "bsr"
     # ...the true near-dense bank costs more, and the winner flips
-    cand = Candidate("bsr", te=14, tf=14, block_m=8, block_n=128)
+    cand = Candidate("bsr", te=14, block_m=8, block_n=128)
     assert (roofline_estimate(g, cand, w_dense=w)
             > roofline_estimate(g, cand))
-    assert plan_layer(g, mode="roofline", w_dense=w).method != "bsr"
+    assert plan_layer(g, mode="roofline", w_dense=w,
+                      backend="cpu").method != "bsr"
     # a genuinely block-pruned bank keeps the MXU pick
     wb = np.asarray(block_prune_conv(jnp.asarray(
         rng.standard_normal((256, 256, 3, 3)).astype(np.float32)),
         0.9, (8, 128)))
-    assert plan_layer(g, mode="roofline", w_dense=wb).method == "bsr"
+    assert plan_layer(g, mode="roofline", w_dense=wb,
+                      backend="cpu").method == "bsr"
 
 
 def test_weights_aware_plan_reads_legacy_untagged_entries(monkeypatch):
@@ -403,7 +402,8 @@ def test_weights_aware_plan_reads_legacy_untagged_entries(monkeypatch):
     net = [cnn.Conv("c1", 8, 3, 1, 1, sparsity=0.7), cnn.Relu()]
     program = lower(net, (3, 10, 10))
     cache = PlanCache()
-    plan0 = plan_program(program, batch=1, mode="roofline", cache=cache)
+    plan0 = plan_program(program, batch=1, mode="roofline", cache=cache,
+                         backend="cpu")
     assert plan0["c1"].method != "bsr"
     legacy_keys = set(cache.entries)
     assert not any("_bk" in k for k in legacy_keys)
@@ -415,7 +415,7 @@ def test_weights_aware_plan_reads_legacy_untagged_entries(monkeypatch):
     monkeypatch.setattr(planner_mod, "plan_layer",
                         lambda g, **kw: calls.append(g.name) or orig(g, **kw))
     plan1 = plan_program(program, batch=1, mode="roofline", cache=cache,
-                         params=params)
+                         params=params, backend="cpu")
     # the untagged non-bsr entry was inherited: zero re-scoring, same plan
     assert calls == []
     assert plan1["c1"] == plan0["c1"]
@@ -426,7 +426,8 @@ def test_weights_aware_plan_reads_legacy_untagged_entries(monkeypatch):
     net2 = [cnn.Conv("c2", 192, 3, 1, 1, sparsity=0.62), cnn.Relu()]
     program2 = lower(net2, (64, 56, 56))
     cache2 = PlanCache()
-    plan2 = plan_program(program2, batch=1, mode="roofline", cache=cache2)
+    plan2 = plan_program(program2, batch=1, mode="roofline", cache=cache2,
+                         backend="cpu")
     assert plan2["c2"].method == "bsr"
     calls.clear()
     params2 = {"c2": {"w": jnp.asarray(np.asarray(magnitude_prune(jnp.asarray(
@@ -434,7 +435,7 @@ def test_weights_aware_plan_reads_legacy_untagged_entries(monkeypatch):
             (192, 64, 3, 3)).astype(np.float32)), 0.62))),
         "b": jnp.zeros((192,), jnp.float32)}}
     plan_program(program2, batch=1, mode="roofline", cache=cache2,
-                 params=params2)
+                 params=params2, backend="cpu")
     assert calls == ["c2"]  # re-scored under the structure-tagged key
     assert any("_bk" in k for k in cache2.entries)
 
@@ -486,13 +487,15 @@ def test_plan_cache_roundtrip(tmp_path):
     path = str(tmp_path / "plans" / "cache.json")
     net = cnn.alexnet()
     cache = PlanCache(path)
-    plan = plan_network(net, 3, 99, batch=1, mode="roofline", cache=cache)
+    plan = plan_network(net, 3, 99, batch=1, mode="roofline", cache=cache,
+                        backend="cpu")
     assert len(cache) > 0
     # tune -> serialize -> reload -> identical plan, with zero re-tuning
     # (a miss would write the file again; compare entries directly).
     reloaded = PlanCache(path)
     assert reloaded.entries == cache.entries
-    replan = plan_network(net, 3, 99, batch=1, mode="roofline", cache=reloaded)
+    replan = plan_network(net, 3, 99, batch=1, mode="roofline",
+                          cache=reloaded, backend="cpu")
     assert replan == plan
     # every sparse layer got a tuned sparse method under roofline scoring
     for layer, _ in cnn.conv_layer_shapes(net, 3, 99):
@@ -576,7 +579,7 @@ def test_plan_cache_load_errors_counter(tmp_path):
 
 def test_plan_cache_v1_migration(tmp_path):
     """v1 documents (no te/tf, no fuse, no pipeline/permute) load via
-    migration: entries get te=tf=None — the untiled schedule the v1 kernel
+    migration: entries get te=None — the untiled schedule the v1 kernel
     ran — fuse=False (the unfused epilogue) and pipeline=permute=False
     (blocking DMA, natural row order), and re-save as the current version."""
     import json
@@ -590,10 +593,10 @@ def test_plan_cache_v1_migration(tmp_path):
                            "est_s": 1e-5, "source": "roofline"}}}))
     cache = PlanCache(str(path))
     pe = cache.get("k1")
-    assert pe == PlanEntry(method="pallas", tm=64, pad_to=8, te=None, tf=None,
+    assert pe == PlanEntry(method="pallas", tm=64, pad_to=8, te=None,
                            fuse=False, pipeline=False, permute=False,
                            est_s=1e-5, source="roofline")
-    assert pe.candidate.te is None and pe.candidate.tf is None
+    assert pe.candidate.te is None
     assert pe.candidate.fuse is False
     assert pe.candidate.pipeline is False and pe.candidate.permute is False
     assert pe.candidate.block_m is None and pe.candidate.block_n is None
@@ -630,7 +633,7 @@ def test_plan_cache_v2_migration_roundtrip(tmp_path):
         }}))
     cache = PlanCache(str(path))
     pe = cache.get("kp")
-    assert pe == PlanEntry(method="pallas", tm=32, te=16, tf=16, pad_to=4,
+    assert pe == PlanEntry(method="pallas", tm=32, te=16, pad_to=4,
                            fuse=False, pipeline=False, permute=False,
                            est_s=2e-5, source="measured")
     assert cache.get("kd").fuse is False
@@ -666,7 +669,7 @@ def test_plan_cache_v3_migration_roundtrip(tmp_path):
         }}))
     cache = PlanCache(str(path))
     pe = cache.get("kf")
-    assert pe == PlanEntry(method="pallas", tm=16, te=32, tf=32, pad_to=8,
+    assert pe == PlanEntry(method="pallas", tm=16, te=32, pad_to=8,
                            fuse=True, pipeline=False, permute=False,
                            est_s=3e-5, source="measured")
     assert cache.get("kd").pipeline is False
@@ -699,7 +702,7 @@ def test_plan_cache_v4_migration_roundtrip(tmp_path):
         }}))
     cache = PlanCache(str(path))
     pe = cache.get("kp")
-    assert pe == PlanEntry(method="pallas", tm=8, te=16, tf=16, pad_to=8,
+    assert pe == PlanEntry(method="pallas", tm=8, te=16, pad_to=8,
                            fuse=True, pipeline=True, permute=True,
                            block_m=None, block_n=None,
                            est_s=4e-5, source="measured")
@@ -726,18 +729,18 @@ def test_plan_cache_migration_chain_v1_to_v6(tmp_path):
         1: ({"method": "pallas", "tm": 64, "pad_to": 8},
             PlanEntry(method="pallas", tm=64, pad_to=8)),
         2: ({"method": "pallas", "tm": 32, "te": 16, "tf": 16, "pad_to": 4},
-            PlanEntry(method="pallas", tm=32, te=16, tf=16, pad_to=4)),
+            PlanEntry(method="pallas", tm=32, te=16, pad_to=4)),
         3: ({"method": "pallas", "tm": 16, "te": 32, "tf": 32, "pad_to": 8,
              "fuse": True},
-            PlanEntry(method="pallas", tm=16, te=32, tf=32, pad_to=8,
+            PlanEntry(method="pallas", tm=16, te=32, pad_to=8,
                       fuse=True)),
         4: ({"method": "pallas", "tm": 8, "te": 16, "tf": 16, "pad_to": 8,
              "fuse": True, "pipeline": True, "permute": True},
-            PlanEntry(method="pallas", tm=8, te=16, tf=16, pad_to=8,
+            PlanEntry(method="pallas", tm=8, te=16, pad_to=8,
                       fuse=True, pipeline=True, permute=True)),
         5: ({"method": "bsr", "te": 16, "tf": 16, "fuse": True,
              "block_m": 8, "block_n": 128},
-            PlanEntry(method="bsr", te=16, tf=16, fuse=True,
+            PlanEntry(method="bsr", te=16, fuse=True,
                       block_m=8, block_n=128)),
     }
     assert set(fixtures) == set(MIGRATABLE_VERSIONS)
@@ -754,6 +757,8 @@ def test_plan_cache_migration_chain_v1_to_v6(tmp_path):
         doc = json.loads(out.read_text())
         assert doc["version"] == CACHE_VERSION == 6
         assert doc["entries"]["k"]["value_dtype"] == "float32"
+        # the column tile is gone: every tile spans all F columns
+        assert "tf" not in doc["entries"]["k"]
         assert PlanCache(str(out)).entries == cache.entries
 
 
@@ -787,7 +792,7 @@ def test_wall_mode_measures_and_picks(tmp_path):
     rng = np.random.default_rng(0)
     params = cnn.init_cnn(net, 4, rng, 8)
     plan = plan_network(net, 4, 8, batch=1, mode="wall", cache=PlanCache(),
-                        params=params, iters=1)
+                        params=params, iters=1, backend="cpu")
     assert plan["c1"].source == "measured"
     assert plan["c1"].method in ("dense", "lowered", "csr-direct")
 
@@ -816,7 +821,7 @@ def test_auto_matches_dense_on_slice(net_name):
     params = cnn.init_cnn(net, 3, rng, image)
     x = jnp.asarray(rng.standard_normal((1, 3, image, image)).astype(np.float32))
     plan = plan_network(net, 3, image, batch=1, mode="roofline",
-                        cache=PlanCache())
+                        cache=PlanCache(), backend="cpu")
     apply_plan_to_params(params, plan)
     y_auto = cnn.cnn_forward(net, params, x, method="auto", plan=plan)
     y_dense = cnn.cnn_forward(net, params, x, method="dense")
@@ -846,7 +851,7 @@ def test_auto_executes_pipelined_permuted_plan():
     params = cnn.init_cnn(net, 3, rng, 10)
     x = jnp.asarray(rng.standard_normal((1, 3, 10, 10)).astype(np.float32))
     plan = {"c0": PlanEntry(method="dense"),
-            "c1": PlanEntry(method="pallas", tm=4, te=6, tf=6, pad_to=8,
+            "c1": PlanEntry(method="pallas", tm=4, te=6, pad_to=8,
                             fuse=True, pipeline=True, permute=True)}
     apply_plan_to_params(params, plan)
     assert params["c1"]["ell_auto"].perm is not None  # balanced bank built
@@ -863,7 +868,7 @@ def test_auto_balances_in_trace_without_apply_plan():
     rng = np.random.default_rng(19)
     params = cnn.init_cnn(net, 3, rng, 10)
     x = jnp.asarray(rng.standard_normal((1, 3, 10, 10)).astype(np.float32))
-    plan = {"c1": PlanEntry(method="pallas", tm=4, te=6, tf=6, pad_to=8,
+    plan = {"c1": PlanEntry(method="pallas", tm=4, te=6, pad_to=8,
                             fuse=True, pipeline=True, permute=True)}
     y_auto = cnn.cnn_forward(net, params, x, method="auto", plan=plan)
     y_dense = cnn.cnn_forward(net, params, x, method="dense")
@@ -947,8 +952,8 @@ def test_plan_layer_quantize_opt_in():
     from repro.tuning import plan_layer
 
     g = _geom(m=256, c=256, h=28, w=28, sparsity=0.9)
-    assert plan_layer(g, mode="roofline").value_dtype == "float32"
-    pe = plan_layer(g, mode="roofline", quantize=True)
+    assert plan_layer(g, mode="roofline", backend="cpu").value_dtype == "float32"
+    pe = plan_layer(g, mode="roofline", backend="cpu", quantize=True)
     assert pe.method in ("pallas", "bsr")
     assert pe.value_dtype == "int8"   # cpu backend: fp8 filtered out
     pe_tpu = plan_layer(g, mode="roofline", backend="tpu", quantize=True)
@@ -958,7 +963,7 @@ def test_plan_layer_quantize_opt_in():
 def test_plan_entry_value_dtype_roundtrip():
     """value_dtype survives the cache dict round-trip, and absent keys
     (v1-v5 documents) default to the f32 value stream."""
-    pe = PlanEntry(method="bsr", te=16, tf=16, block_m=8, block_n=128,
+    pe = PlanEntry(method="bsr", te=16, block_m=8, block_n=128,
                    value_dtype="int8", est_s=1e-5, source="roofline")
     d = pe.to_dict()
     assert d["value_dtype"] == "int8"
