@@ -212,10 +212,8 @@ def _smoke_traced_forward() -> None:
         if not conv_ops or any(not o.method_executed for o in conv_ops):
             raise SystemExit("trace smoke: report missing per-op methods")
         tracer = telemetry.get_tracer()
-        if len(tracer) < len(conv_ops):
-            raise SystemExit(
-                f"trace smoke: {len(tracer)} trace events for "
-                f"{len(conv_ops)} conv ops")
+        if not any(ev["name"] == "engine.dispatch" for ev in tracer.events):
+            raise SystemExit("trace smoke: no engine.dispatch span recorded")
         with tempfile.TemporaryDirectory() as td:
             path = pathlib.Path(td) / "trace.json"
             tracer.export(str(path))  # export() validates before writing

@@ -96,6 +96,11 @@ def init_conv_params(program: Program, rng: np.random.Generator,
     return params
 
 
+def _op_name(op) -> str:
+    """A program op's name: its layer's, else its kind and output slot."""
+    return getattr(op, "name", None) or f"{type(op).__name__}:{op.out}"
+
+
 def _pool(op: PoolOp, x: jax.Array) -> jax.Array:
     if op.kind == "gap":
         return x.mean(axis=(2, 3), keepdims=True)
@@ -168,6 +173,12 @@ class CnnEngine:
         # banks built for a plan block that differs from the prebuilt one
         # are cached here instead of rebuilt every trace/report.
         self._bcc_cache: Dict[Any, Any] = {}
+        # Mean kept tiles per block-row of each BCSR bank, keyed (layer,
+        # block): read to the host once, for the report's cost attribution.
+        self._bsr_kept: Dict[Any, float] = {}
+        # One ExecutionReport per compiled function (its memo key) and
+        # rung: the dispatch decisions are static per compiled function.
+        self._reports: Dict[Any, ExecutionReport] = {}
         # The ExecutionReport of the most recent telemetry-enabled forward.
         self.last_report: Optional[ExecutionReport] = None
 
@@ -397,10 +408,15 @@ class CnnEngine:
 
     def _execute(self, x: jax.Array, *, method: str, plan,
                  fuse_override: Optional[bool]) -> jax.Array:
+        """The traced forward.  Each op runs under a ``jax.named_scope`` of
+        its layer, so the compiled program's operations carry the layer in
+        their ``op_name`` metadata; the Pallas custom calls keep their
+        kernel's name (``sparse_conv_pallas.<n>``, ``bsr_conv_pallas.<n>``)."""
         vals: Dict[int, jax.Array] = {0: x}
         for op in self.program.ops:
-            vals[op.out] = self._exec_op(op, vals, method, plan,
-                                         fuse_override)
+            with jax.named_scope(_op_name(op)):
+                vals[op.out] = self._exec_op(op, vals, method, plan,
+                                             fuse_override)
         return vals[self.program.out]
 
     def __call__(self, x: jax.Array, method: str = "dense", *,
@@ -415,23 +431,38 @@ class CnnEngine:
         its own persistent plan dict, so each (method, shape, rung plan)
         still compiles exactly once.  ``rung`` is a label recorded on the
         forward's :class:`ExecutionReport` naming the ladder rung executed.
-        """
-        fn, plan, jit_hit = self._jitted(x, method, fuse, plan_override)
-        if telemetry.is_enabled():
-            # Dispatch-time observation: the report is built from the same
-            # _plan_decision the trace uses, never from inside the jit.
-            self._record_forward(tuple(x.shape), str(x.dtype), method, plan,
-                                 fuse, jit_hit, rung=rung)
-        return fn(x)
 
-    def _jitted(self, x, method: str, fuse: Optional[bool],
-                plan_override: Optional[Dict[str, Any]]):
-        """(jitted forward, resolved plan, memo hit) for one call shape."""
+        The whole call (memo lookup, the report when telemetry is on, and
+        the enqueue of the compiled forward) is the span
+        ``engine.dispatch``; it returns before the device finishes.
+        """
+        with telemetry.span("engine.dispatch"):
+            fn, plan, key, jit_hit = self._jitted(x, method, fuse,
+                                                  plan_override)
+            if telemetry.is_enabled():
+                # Dispatch-time observation: the report is built from the
+                # same _plan_decision the trace uses, never from inside
+                # the jit.
+                self._record_forward(key, method, plan, fuse, jit_hit,
+                                     rung=rung)
+            return fn(x)
+
+    def _plan_for(self, method: str, batch: int,
+                  plan_override: Optional[Dict[str, Any]]):
+        """The plan a call runs: the override, else the bound plan, else
+        (``auto`` only) the roofline plan for this batch size."""
         if method not in METHODS:
             raise ValueError(f"unknown method {method!r}; one of {METHODS}")
         plan = plan_override if plan_override is not None else self.plan
         if method == "auto" and plan is None:
-            plan = self._auto_plan(int(x.shape[0]))
+            plan = self._auto_plan(batch)
+        return plan
+
+    def _jitted(self, x, method: str, fuse: Optional[bool],
+                plan_override: Optional[Dict[str, Any]]):
+        """(jitted forward, resolved plan, memo key, memo hit) for one call
+        shape."""
+        plan = self._plan_for(method, int(x.shape[0]), plan_override)
         key = (method, tuple(x.shape), str(x.dtype), fuse, id(plan))
         fn = self._fns.get(key)
         jit_hit = fn is not None
@@ -439,7 +470,7 @@ class CnnEngine:
             fn = jax.jit(functools.partial(
                 self._execute, method=method, plan=plan, fuse_override=fuse))
             self._fns[key] = fn
-        return fn, plan, jit_hit
+        return fn, plan, key, jit_hit
 
     def lowered(self, x, method: str = "dense", *,
                 fuse: Optional[bool] = None,
@@ -447,16 +478,25 @@ class CnnEngine:
         """The ``jax.stages.Lowered`` forward :meth:`__call__` would run for
         ``x`` (an array or a ``ShapeDtypeStruct``) — ``.compile()`` then
         gives the device program, e.g. to count its Pallas custom calls."""
-        fn, _, _ = self._jitted(x, method, fuse, plan_override)
+        fn = self._jitted(x, method, fuse, plan_override)[0]
         return fn.lower(x)
 
     # -- observability -----------------------------------------------------
 
-    def _record_forward(self, shape, dtype: str, method: str, plan,
-                        fuse_override: Optional[bool],
-                        jit_hit: bool, rung: Optional[str] = None) -> None:
-        report = self._build_report(shape, dtype, method, plan,
-                                    fuse_override, jit_hit, rung=rung)
+    def _record_forward(self, key, method: str, plan,
+                        fuse_override: Optional[bool], jit_hit: bool,
+                        rung: Optional[str] = None) -> None:
+        """Count one forward of the compiled function ``key`` and leave its
+        report on ``last_report``.  The report is built on the first
+        telemetry-enabled forward of each (compiled function, rung) and
+        reused after; only ``jit_cache_hit`` is set per call."""
+        report = self._reports.get(key + (rung,))
+        if report is None:
+            _, shape, dtype, _, _ = key
+            report = self._build_report(shape, dtype, method, plan,
+                                        fuse_override, None, rung=rung)
+            self._reports[key + (rung,)] = report
+        report = dataclasses.replace(report, jit_cache_hit=jit_hit)
         self.last_report = report
         telemetry.counter("engine.forwards").inc()
         telemetry.counter(
@@ -464,7 +504,6 @@ class CnnEngine:
         if report.fallback_count:
             telemetry.counter("engine.fallback_ops").inc(
                 report.fallback_count)
-        report.emit_spans(telemetry.get_tracer())
 
     def _build_report(self, shape, dtype: str, method: str, plan,
                       fuse_override: Optional[bool],
@@ -536,15 +575,26 @@ class CnnEngine:
             permute=d.permute if executed == "pallas" else False,
             block_m=tiling.get("block_m"), block_n=tiling.get("block_n"),
             value_dtype=vdtype)
-        w = entry.get("w") if executed == "bsr" else None
-        cost = candidate_cost(
-            g, cand, w_dense=None if w is None else np.asarray(w))
+        kept = self._kept_tiles(op, bcc) if executed == "bsr" else None
+        cost = candidate_cost(g, cand, bsr_kept=kept)
         return OpReport(
             name=op.name, method_planned=d.method_planned,
             method_executed=executed, provenance=d.provenance,
             plan_source=d.pe.source if d.pe is not None else "-",
             fallback_reason=reason, fuse=d.fuse, tiling=tiling,
             sparsity=op.sparsity, value_dtype=vdtype, **cost)
+
+    def _kept_tiles(self, op: ConvOp, bcc) -> float:
+        """Mean kept tiles per block-row of the bank ``bcc`` runs, at least
+        1 — what ``tuning.measure.bcsr_true_kept`` computes from the dense
+        weights — read from the bank's ``nblocks`` once per (layer,
+        block)."""
+        key = (op.name, bcc.block)
+        kept = self._bsr_kept.get(key)
+        if kept is None:
+            kept = max(1.0, float(np.asarray(bcc.nblocks).mean()))
+            self._bsr_kept[key] = kept
+        return kept
 
     def execution_report(self, x, method: str = "auto", *,
                          fuse: Optional[bool] = None,
@@ -561,13 +611,9 @@ class CnnEngine:
         each rung's dispatch health through this before routing traffic at
         it.
         """
-        if method not in METHODS:
-            raise ValueError(f"unknown method {method!r}; one of {METHODS}")
         shape = tuple(x.shape) if hasattr(x, "shape") else tuple(x)
         dtype = str(x.dtype) if hasattr(x, "dtype") else "float32"
-        plan = plan_override if plan_override is not None else self.plan
-        if method == "auto" and plan is None:
-            plan = self._auto_plan(int(shape[0]))
+        plan = self._plan_for(method, int(shape[0]), plan_override)
         key = (method, shape, dtype, fuse, id(plan))
         return self._build_report(shape, dtype, method, plan, fuse,
                                   jit_hit=key in self._fns, rung=rung)
@@ -575,10 +621,9 @@ class CnnEngine:
     def forward_timed(self, x: jax.Array, method: str = "auto", *,
                       fuse: Optional[bool] = None) -> jax.Array:
         """Opt-in timed mode: execute op-by-op with ``block_until_ready``
-        at every op boundary, recording real per-op wall spans on the
-        tracer's ``wall`` lane and wrapping each op in a
-        ``jax.profiler`` named scope so XLA profiles map back to layer
-        names.
+        at every op boundary, each op a span ``engine.timed.<layer>``
+        (``repro.telemetry.span``: a profiler annotation, a histogram and
+        a wall-lane event), so profiles map back to layer names.
 
         The boundaries defeat whole-program fusion and force a host sync
         per op, so this is a profiling tool, not a serving path — expect
@@ -587,29 +632,22 @@ class CnnEngine:
         measured report on ``self.last_report`` (``wall_s`` filled for
         every conv).
         """
-        if method not in METHODS:
-            raise ValueError(f"unknown method {method!r}; one of {METHODS}")
-        plan = self.plan
-        if method == "auto" and plan is None:
-            plan = self._auto_plan(int(x.shape[0]))
+        plan = self._plan_for(method, int(x.shape[0]), None)
         report = self._build_report(tuple(x.shape), str(x.dtype), method,
                                     plan, fuse, jit_hit=None)
         report.timed = True
-        tracer = telemetry.get_tracer()
         walls: Dict[str, float] = {}
         vals: Dict[int, jax.Array] = {0: x}
-        for op in self.program.ops:
-            name = getattr(op, "name", None) or f"{type(op).__name__}:{op.out}"
-            t0 = time.perf_counter()
-            with jax.profiler.TraceAnnotation(name):
-                vals[op.out] = self._exec_op(op, vals, method, plan, fuse)
-                jax.block_until_ready(vals[op.out])
-            dt = time.perf_counter() - t0
-            tracer.complete(name, start_s=t0, dur_s=dt, cat="op.timed",
-                            tid=telemetry.TID_WALL,
-                            args={"kind": type(op).__name__})
-            if isinstance(op, ConvOp):
-                walls[op.name] = dt
+        with telemetry.enabled():
+            for op in self.program.ops:
+                t0 = time.perf_counter()
+                with telemetry.span(f"engine.timed.{_op_name(op)}",
+                                    kind=type(op).__name__):
+                    vals[op.out] = self._exec_op(op, vals, method, plan,
+                                                 fuse)
+                    jax.block_until_ready(vals[op.out])
+                if isinstance(op, ConvOp):
+                    walls[op.name] = time.perf_counter() - t0
         for o in report.ops:
             o.wall_s = walls.get(o.name)
         self.last_report = report

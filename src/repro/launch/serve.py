@@ -26,7 +26,6 @@ degradation evidence.
 from __future__ import annotations
 
 import argparse
-import contextlib
 import dataclasses
 import time
 
@@ -277,14 +276,9 @@ def main() -> None:
     cache = T.init_cache(cfg, b, max_len)
     serve_step = jax.jit(make_serve_step(cfg), donate_argnums=(2,))
 
-    def span(name, **kw):
-        if telemetry.is_enabled():
-            return telemetry.get_tracer().span(name, cat="serve", **kw)
-        return contextlib.nullcontext()
-
     # prefill token-by-token (smoke-scale; production uses the prefill step)
     t0 = time.time()
-    with span("prefill", tokens=p, batch=b):
+    with telemetry.span("serve.prefill", tokens=p, batch=b):
         for i in range(p):
             nxt, cache = serve_step(params, prompts[:, i:i + 1], cache,
                                     jnp.int32(i))
@@ -293,7 +287,7 @@ def main() -> None:
 
     out = [nxt]
     t0 = time.time()
-    with span("decode", tokens=g - 1, batch=b):
+    with telemetry.span("serve.decode", tokens=g - 1, batch=b):
         for i in range(p, p + g - 1):
             nxt, cache = serve_step(params, out[-1][:, None], cache,
                                     jnp.int32(i))

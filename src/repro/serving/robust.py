@@ -569,9 +569,12 @@ class RobustCnnServer:
                 exc = self.chaos.draw_step_fault()
                 if exc is not None:
                     raise exc
-            y = np.asarray(bucket.engine(
-                self._batch_input(bucket, reqs), "auto",
-                plan_override=rung.plan, rung=rung.name))
+            with telemetry.span("serving.cnn.batch_input"):
+                x = self._batch_input(bucket, reqs)
+            y = bucket.engine(x, "auto", plan_override=rung.plan,
+                              rung=rung.name)
+            with telemetry.span("serving.cnn.fetch"):
+                y = np.asarray(y)  # waits for the forward, copies to host
         except Exception as exc:  # noqa: BLE001 - classified below
             self._on_step_failure(bucket, reqs, exc)
             return

@@ -5,7 +5,8 @@ kernel did each conv layer execute, where did its plan come from, and did
 anything silently fall back?" — the per-layer attribution the Escoin paper
 argues from, produced by ``CnnEngine`` at dispatch time (the dispatch
 decisions are static Python over shapes and plan entries, so building the
-report never touches a compiled program).
+report never touches a compiled program, and one report serves every
+forward of the same compiled function).
 
 Per :class:`OpReport` fields:
 
@@ -40,8 +41,6 @@ from __future__ import annotations
 
 import dataclasses
 from typing import Any, Dict, List, Optional, Tuple
-
-from repro.telemetry.trace import TID_ROOFLINE, Tracer
 
 
 @dataclasses.dataclass
@@ -136,30 +135,3 @@ class ExecutionReport:
                 f"{o.provenance:<13} {o.fallback_reason or '-':<20} "
                 f"{o.est_s * 1e6:9.1f} {o.staging_stall_s * 1e6:9.1f} {wall}")
         return "\n".join(lines)
-
-    def emit_spans(self, tracer: Tracer) -> None:
-        """Lay the per-op roofline estimates out as sequential spans on the
-        tracer's ``roofline`` lane.
-
-        The default (untimed) engine executes the whole program as one
-        compiled call, so per-op wall segmentation is impossible without
-        the timed mode; the estimated timeline still names every op, its
-        method, provenance, and any fallback — what the Chrome-trace view
-        is for.  Timed-mode wall spans are emitted separately by
-        ``CnnEngine.forward_timed`` on the ``wall`` lane.
-        """
-        import time
-        t = time.perf_counter()
-        for o in self.ops:
-            tracer.complete(
-                o.name, start_s=t, dur_s=o.est_s, cat="conv.roofline",
-                tid=TID_ROOFLINE,
-                args={"estimated": True, "method": o.method_executed,
-                      "planned": o.method_planned,
-                      "provenance": o.provenance,
-                      "fallback": o.fallback_reason,
-                      "fuse": o.fuse, "sparsity": o.sparsity,
-                      "value_dtype": o.value_dtype,
-                      "flops": o.flops, "hbm_bytes": o.hbm_bytes,
-                      "staging_stall_s": o.staging_stall_s})
-            t += max(o.est_s, 1e-9)

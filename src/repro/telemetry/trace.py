@@ -1,22 +1,18 @@
-"""Structured span/event tracer with Chrome-trace-format JSON export.
+"""Event tracer with Chrome-trace-format JSON export.
 
-Spans collect into an in-memory event list and export as the Chrome trace
+Events collect into an in-memory event list and export as the Chrome trace
 event format (the ``{"traceEvents": [...]}`` JSON that chrome://tracing and
 Perfetto load): complete events (``ph="X"``) for spans with a duration,
 instant events (``ph="i"``) for point markers, and metadata events
 (``ph="M"``) naming the lanes.  Timestamps are microseconds relative to the
 tracer's first event, taken from ``time.perf_counter`` — a monotonic clock,
-so spans never go backwards.
+so spans never go backwards.  It is not the profiler's clock: the spans
+that must line up with device operations are the ``jax.profiler``
+annotations that ``repro.telemetry.span`` enters beside each event.
 
-Two kinds of spans share the timeline on separate lanes (``tid``):
-
-  wall      -- real measured durations (dispatch wrappers, timed-mode op
-               segmentation, serving ticks)
-  roofline  -- analytic per-op durations from an ExecutionReport: the
-               engine's default (untimed) mode cannot time ops inside one
-               compiled program, so it lays the roofline-attributed
-               estimates out sequentially instead, tagged
-               ``args.estimated = true``
+Every event is a measured duration on the one ``wall`` lane (``tid`` 0):
+the program's spans (``repro.telemetry.span``: engine dispatch, the
+serving tick's stages, timed-mode op walls, serve prefill/decode).
 
 :func:`validate_chrome_trace` checks an exported document against the
 schema the tools require; CI runs it on a traced forward so a malformed
@@ -24,16 +20,14 @@ export fails the build instead of failing to load in Perfetto.
 """
 from __future__ import annotations
 
-import contextlib
 import json
 import time
 from typing import Any, Dict, List, Optional
 
-# Lane ids (Chrome trace "tid"): one per span kind.
+# Lane id (Chrome trace "tid") of the measured spans.
 TID_WALL = 0
-TID_ROOFLINE = 1
 
-_THREAD_NAMES = {TID_WALL: "wall", TID_ROOFLINE: "roofline (estimated)"}
+_THREAD_NAMES = {TID_WALL: "wall"}
 
 
 class Tracer:
@@ -54,26 +48,13 @@ class Tracer:
 
     # -- recording --------------------------------------------------------
 
-    @contextlib.contextmanager
-    def span(self, name: str, cat: str = "op", tid: int = TID_WALL,
-             **args: Any):
-        """Context manager recording one complete ("X") event."""
-        t0 = time.perf_counter()
-        try:
-            yield self
-        finally:
-            t1 = time.perf_counter()
-            self.complete(name, start_s=t0, dur_s=t1 - t0, cat=cat,
-                          tid=tid, args=args)
-
     def complete(self, name: str, *, start_s: Optional[float] = None,
                  dur_s: float, cat: str = "op", tid: int = TID_WALL,
                  args: Optional[Dict[str, Any]] = None) -> None:
         """Record a complete event with an explicit duration.
 
         ``start_s`` is in the ``time.perf_counter`` domain (defaults to
-        now); ``dur_s`` may be a measured wall time or an analytic
-        estimate (tag the latter via ``args={"estimated": True}``).
+        now); ``dur_s`` is the measured wall time.
         """
         self.events.append({
             "name": str(name), "cat": cat, "ph": "X",

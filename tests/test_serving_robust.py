@@ -324,6 +324,30 @@ def test_chaos_off_records_nothing(alex):
     srv.submit(_req(0))
     srv.tick()
     assert telemetry.snapshot() == {}  # zero-overhead-when-off discipline
+    assert len(telemetry.get_tracer()) == 0
+
+
+def test_tick_records_stage_spans_once_per_batch(alex):
+    """Each dispatched batch records its three stages: the padded batch
+    copied to the device, the engine's dispatch, the fetch of the logits."""
+    telemetry.reset()
+    srv = _server(alex)
+    with telemetry.enabled():
+        for i in range(3):
+            srv.submit(_req(i))
+        batches = 0
+        while srv.pending():
+            batches += srv.tick() > 0
+        snap = telemetry.snapshot()
+        names = [ev["name"] for ev in telemetry.get_tracer().events]
+    telemetry.reset()
+    assert batches == 2
+    for name in ("serving.cnn.batch_input", "engine.dispatch",
+                 "serving.cnn.fetch"):
+        assert snap[name + "_s"]["count"] == batches
+    assert snap["serving.cnn.tick_latency_s"]["count"] == batches
+    assert names == ["serving.cnn.batch_input", "engine.dispatch",
+                     "serving.cnn.fetch"] * batches
 
 
 # -- plan-cache corruption seam ---------------------------------------------

@@ -86,11 +86,11 @@ def test_reset_clears_registry():
 # ----------------------------------------------------------------- tracer
 
 def test_tracer_exports_valid_chrome_trace(tmp_path):
-    tracer = telemetry.Tracer()
-    with tracer.span("outer", cat="test", foo=1):
+    tracer = telemetry.get_tracer()
+    with telemetry.enabled(), telemetry.span("outer", foo=1):
         tracer.instant("marker", cat="test")
-    tracer.complete("op", start_s=None, dur_s=1e-3, cat="op.roofline",
-                    tid=telemetry.TID_ROOFLINE, args={"method": "pallas"})
+    tracer.complete("op", start_s=None, dur_s=1e-3, cat="test",
+                    args={"method": "pallas"})
     doc = tracer.to_chrome_trace()
     telemetry.validate_chrome_trace(doc)  # must not raise
     assert doc["displayTimeUnit"] == "ms"
@@ -112,6 +112,49 @@ def test_validate_chrome_trace_rejects_bad_docs():
         telemetry.validate_chrome_trace({"traceEvents": [
             {"name": "x", "ph": "X", "ts": 0, "dur": 1, "pid": 1, "tid": 0,
              "args": {"bad": object()}}]})
+
+
+# ------------------------------------------------------------------ spans
+
+def test_span_records_only_when_enabled():
+    with telemetry.span("t.span", k=1):
+        pass
+    assert telemetry.snapshot() == {} and len(telemetry.get_tracer()) == 0
+
+    with telemetry.enabled():
+        with telemetry.span("t.span", k=1):
+            pass
+        with pytest.raises(ValueError), telemetry.span("t.span", k=2):
+            raise ValueError("the span still closes")
+    h = telemetry.histogram("t.span_s")
+    assert h.count == 2 and h.min >= 0.0
+    events = telemetry.get_tracer().events
+    assert [(ev["name"], ev["ph"], ev["tid"], ev["args"]) for ev in events] \
+        == [("t.span", "X", telemetry.TID_WALL, {"k": 1}),
+            ("t.span", "X", telemetry.TID_WALL, {"k": 2})]
+
+
+def test_span_annotation_on_profiler_host_plane(tmp_path):
+    """The span's profiler annotation is on the host plane of the same
+    trace as the device's operations, telemetry on or off."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with telemetry.span("t.annotated"):
+            jax.block_until_ready(jnp.arange(8.0) * 2)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    host = [ev.name for plane in data.planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+    assert host.count("t.annotated") == 1
+    assert telemetry.snapshot() == {} and len(telemetry.get_tracer()) == 0
 
 
 # --------------------------------------------------- fallback warnings
@@ -273,14 +316,18 @@ def test_execution_report_all_networks(net_name, image):
                               for o in sparse_ops)
     # the rendered table carries one row per conv
     assert report.format().count("\n") >= n_convs
-    # per-op roofline spans export as a valid Chrome trace
-    tracer = telemetry.Tracer()
-    report.emit_spans(tracer)
-    doc = tracer.to_chrome_trace()
+    # a forward records the measured engine.dispatch span, which exports
+    # as a valid Chrome trace, and leaves the same report behind
+    x = np.zeros((1, 3, image, image), np.float32)
+    with telemetry.enabled():
+        engine(x, "auto")
+    doc = telemetry.get_tracer().to_chrome_trace()
     telemetry.validate_chrome_trace(doc)
-    span_names = {ev["name"] for ev in doc["traceEvents"]
-                  if ev["ph"] == "X"}
-    assert {op.name for op in report.ops} <= span_names
+    spans = [ev for ev in doc["traceEvents"] if ev["ph"] == "X"]
+    assert [ev["name"] for ev in spans] == ["engine.dispatch"]
+    assert telemetry.histogram("engine.dispatch_s").count == 1
+    assert ([o.to_dict() for o in engine.last_report.ops]
+            == [o.to_dict() for o in report.ops])
 
 
 def test_forward_records_report_and_valid_trace(tmp_path):
@@ -300,14 +347,14 @@ def test_forward_records_report_and_valid_trace(tmp_path):
     snap = telemetry.snapshot()
     assert snap["engine.forwards"]["value"] == 1
     assert snap["engine.jit_hits"]["value"] == 1
-    # roofline-attributed spans landed on the tracer and export validates
-    assert len(telemetry.get_tracer()) >= len(report.ops)
+    # the measured dispatch span landed on the tracer and export validates
+    assert snap["engine.dispatch_s"]["count"] == 1
     path = tmp_path / "trace.json"
     telemetry.get_tracer().export(str(path))
     doc = json.loads(path.read_text())
     telemetry.validate_chrome_trace(doc)
-    names = {ev["name"] for ev in doc["traceEvents"] if ev["ph"] == "X"}
-    assert {op.name for op in report.ops} <= names
+    names = [ev["name"] for ev in doc["traceEvents"] if ev["ph"] == "X"]
+    assert names == ["engine.dispatch"]
 
 
 def test_forward_timed_fills_wall_times():
@@ -322,6 +369,77 @@ def test_forward_timed_fills_wall_times():
     # timed mode records wall spans regardless of the global flag — calling
     # it is the opt-in
     assert len(telemetry.get_tracer()) > 0
+    assert all(ev["name"].startswith("engine.timed.")
+               for ev in telemetry.get_tracer().events)
+    assert telemetry.histogram("engine.timed.c1_s").count == 1
+
+
+def test_report_built_once_per_compiled_function(monkeypatch):
+    """Telemetry-on forwards reuse one report per compiled function and
+    rung: N forwards build each conv's OpReport once, and only
+    ``jit_cache_hit`` changes from call to call."""
+    engine, x = _micro_engine()
+    built = []
+    op_report = CnnEngine._op_report
+
+    def counting(self, op, *args, **kw):
+        built.append(op.name)
+        return op_report(self, op, *args, **kw)
+
+    monkeypatch.setattr(CnnEngine, "_op_report", counting)
+    convs = sorted(op.name for op in engine.program.conv_ops)
+    with telemetry.enabled():
+        hits = []
+        for _ in range(4):
+            engine(x, "auto")
+            hits.append(engine.last_report.jit_cache_hit)
+        assert hits == [False, True, True, True]
+        assert sorted(built) == convs
+        engine(x, "auto", rung="tuned")  # another rung: another report
+        assert engine.last_report.rung == "tuned"
+        assert engine.last_report.jit_cache_hit
+        assert sorted(built) == sorted(convs * 2)
+    snap = telemetry.snapshot()
+    assert snap["engine.forwards"]["value"] == 5
+    assert snap["engine.jit_misses"]["value"] == 1
+    assert snap["engine.dispatch_s"]["count"] == 5
+
+
+def test_report_kept_tiles_match_dense_scan():
+    """A bsr layer is priced at its bank's mean kept tiles per block-row,
+    read from ``nblocks``: the count ``bcsr_true_kept`` scans the dense
+    weights for, at every block shape."""
+    import jax.numpy as jnp
+
+    from repro.tuning.measure import bcsr_true_kept, candidate_cost
+    from repro.tuning.planner import geometry_of_op
+    from repro.tuning.space import Candidate
+
+    rng = np.random.default_rng(1)
+    net = [cnn.Conv("c0", 32, 3, 1, 1, sparsity=0.0), cnn.Relu(),
+           cnn.Conv("c1", 32, 3, 1, 1, sparsity=0.5), cnn.Relu(),
+           cnn.Pool("gap"), cnn.FC("fc", 10)]
+    program = lower(net, (3, 8, 8))
+    params = cnn.init_cnn(net, 3, rng, 8)
+    w = np.asarray(params["c1"]["w"]).reshape(32, -1).copy()
+    w[:8, 128:] = 0.0    # block-row 0 of (8, 128) keeps one tile of three
+    w[8:16, :] = 0.0     # block-row 1 keeps none
+    params["c1"]["w"] = jnp.asarray(w.reshape(32, 32, 3, 3))
+    engine = CnnEngine(program, params)
+    (op,) = [o for o in program.conv_ops if o.name == "c1"]
+    dense = np.asarray(params["c1"]["w"])
+    for block in ((8, 128), (16, 128), (8, 256)):
+        bcc = engine._bcsr_for(op, params["c1"], block)
+        assert engine._kept_tiles(op, bcc) == bcsr_true_kept(dense, *block)
+    report = engine.execution_report((2, 3, 8, 8), "bsr")
+    (o,) = [o for o in report.ops if o.name == "c1"]
+    assert o.method_executed == "bsr"
+    cand = Candidate(method="bsr", te=o.tiling["te"], fuse=True,
+                     block_m=o.tiling["block_m"], block_n=o.tiling["block_n"])
+    want = candidate_cost(geometry_of_op(op, batch=2, dtype="float32"), cand,
+                          w_dense=dense)
+    assert (o.flops, o.hbm_bytes, o.est_s) == (
+        want["flops"], want["hbm_bytes"], want["est_s"])
 
 
 def test_stale_bsr_plan_reports_fallback():

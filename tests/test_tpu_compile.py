@@ -127,3 +127,32 @@ def test_bsr_kernel_compiles_for_v5e(layer, value_dtype, one_chip,
                         **conv)
 
     _compile(one_chip, fwd, x, bc, bias, shortcut)
+
+
+@pytest.mark.parametrize("method,kernel", [("pallas", "sparse_conv_pallas"),
+                                           ("bsr", "bsr_conv_pallas")])
+def test_engine_layer_scopes_keep_kernel_names(method, kernel, one_chip,
+                                               no_persistent_cache):
+    """Each engine op runs under a named scope of its layer: the compiled
+    program's metadata carries the layer, while the kernel's custom call
+    keeps the name a profiler trace reports it by (``<kernel>.<n>``)."""
+    import re
+
+    from repro.engine import CnnEngine, lower
+    from repro.models import cnn
+
+    net = [cnn.Conv("c0", 128, 3, 1, 1, sparsity=0.0), cnn.Relu(),
+           cnn.Conv("c1", 128, 3, 1, 1, sparsity=0.7), cnn.Relu(),
+           cnn.Pool("gap"), cnn.FC("fc", 10)]
+    params = cnn.init_cnn(net, 3, np.random.default_rng(0), 28)
+    engine = CnnEngine(lower(net, (3, 28, 28)), params)
+    engine.interpret = False
+    x = jax.ShapeDtypeStruct((BATCH, 3, 28, 28), jnp.float32,
+                             sharding=one_chip)
+    lowered = engine.lowered(x, method)
+    assert kernel in lowered.as_text()
+    text = lowered.compile().as_text()
+    calls = re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = [^\n]*"
+                       r'custom_call_target="tpu_custom_call"', text, re.M)
+    assert len(calls) == 1 and re.fullmatch(kernel + r"\.\d+", calls[0])
+    assert re.search(r'op_name="[^"]*/c1/jit\(' + kernel, text)
