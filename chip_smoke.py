@@ -12,9 +12,9 @@ printing its last line:
 
   device   ``jax.devices()[0]`` must be a TPU — there is no CPU fallback.
   probe    each conv kernel once at tiny shapes — ELL and BCSR, strides 1
-           and 2, 3x3 and 1x1, blocking and pipelined staging, an int8
-           bank and a fused residual — against XLA's convolution at highest
-           precision.  Their halo bands are 11 to 14 rows, which the
+           and 2, 3x3 and 1x1, blocking and pipelined staging, the ELL
+           pointwise schedule, an int8 bank and a fused residual — against
+           XLA's convolution at highest precision.  Their halo bands are 11 to 14 rows, which the
            kernels stage as whole 16-row sublane tiles; a partial-tile
            staging copy is what hangs on a v5e, so a regression fails here
            in seconds, before the 224-px forwards.
@@ -80,11 +80,13 @@ _START = time.monotonic()
 # Tiny probes: (kernel, C, H, M, R, stride, pad, value dtype, pipeline,
 # fused residual).  Staged halo rows (``(E-1)*stride + R``) are 12, 11, 12
 # and 14 — none a whole sublane tile before ``window.stage_shape`` rounds
-# them up.
+# them up.  The ELL 1x1 stride-1 probes run the pointwise schedule: 288
+# pixels on 3 rows of 128 lanes, padded to one 8-row tile.
 PROBES = (
     ("pallas", 8, 10, 16, 3, 1, 1, "float32", False, False),
     ("pallas", 8, 10, 16, 3, 1, 1, "float32", True, False),
     ("pallas", 16, 11, 16, 1, 2, 0, "float32", None, False),
+    ("pallas", 16, 12, 16, 1, 1, 0, "float32", None, False),
     ("pallas", 16, 12, 16, 1, 1, 0, "int8", None, True),
     ("bsr", 16, 12, 16, 3, 1, 1, "float32", None, False),
     ("bsr", 16, 11, 16, 1, 2, 0, "float32", None, False),

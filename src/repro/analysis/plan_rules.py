@@ -150,6 +150,8 @@ def _check_entry_schedule(
             g.r,
             g.s,
             g.stride,
+            n=g.batch,
+            padding=g.pad,
             tm=tm,
             te=entry.get("te"),
             fuse_res=fuse_res,
@@ -166,7 +168,8 @@ def _check_entry_schedule(
                     key,
                 )
             )
-        elif entry.get("pipeline", False) and not sched[2]:
+        elif (entry.get("pipeline", False) and sched.tp is None
+              and not sched.pipeline):
             out.append(
                 _diag(
                     "sched.pipeline_demoted",

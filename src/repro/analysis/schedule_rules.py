@@ -275,6 +275,8 @@ def check_pallas_entry(
         op.k,
         op.k,
         op.stride,
+        n=batch,
+        padding=op.pad,
         tm=entry.tm,
         te=entry.te,
         fuse_res=fuse_res,
@@ -296,7 +298,11 @@ def check_pallas_entry(
             )
         )
         return out
-    tm, te, pipeline = sched
+    if sched.tp is not None:
+        # The pointwise schedule stages no halo and has no DMA mode to
+        # demote.
+        return out
+    tm, te, pipeline = sched.tm, sched.te, sched.pipeline
     if entry.pipeline and not pipeline:
         out.append(
             Diagnostic(
@@ -413,7 +419,8 @@ def _probe_methods(
     out: List[Diagnostic] = []
     k = _ell_k(op, None, params, batch, dtype)
     sched, reason = resolve_schedule(
-        op.m, op.c, op.e, op.f, k, op.k, op.k, op.stride
+        op.m, op.c, op.e, op.f, k, op.k, op.k, op.stride, n=batch,
+        padding=op.pad
     )
     if sched is None:
         out.append(

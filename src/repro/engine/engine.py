@@ -544,14 +544,13 @@ class CnnEngine:
                    else entry.get("ell"))
             k = ell.k if ell is not None else g.k_est(pad_to or 8)
             sched, kreason = resolve_schedule(
-                op.m, op.c, op.e, op.f, k, op.k, op.k, op.stride, tm=d.tm,
-                te=d.te, fuse_res=fuse_res, pipeline=d.pipeline,
-                value_dtype=d.value_dtype)
+                op.m, op.c, op.e, op.f, k, op.k, op.k, op.stride, n=batch,
+                padding=op.pad, tm=d.tm, te=d.te, fuse_res=fuse_res,
+                pipeline=d.pipeline, value_dtype=d.value_dtype)
             if sched is None:
                 reason, executed = kreason, "csr-direct"
             else:
-                tm, te, pipe = sched
-                tiling = {"tm": tm, "te": te, "pipeline": pipe}
+                tiling = sched.tiling()
         elif executed == "bsr":
             bcc = self._bcsr_for(op, entry, d.block)
             gbm, kb, bm, bn = bcc.blocks.shape

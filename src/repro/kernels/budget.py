@@ -19,6 +19,8 @@ arithmetic itself lives here.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 from repro.kernels.window import round_up, stage_shape, sublane_tile
 
 # v5e has 128 MiB of VMEM per TensorCore, but Mosaic gives one kernel a
@@ -151,6 +153,44 @@ def tiling_fits(m: int, c: int, e: int, f: int, k: int, r: int, s: int,
     budget = VMEM_BUDGET if vmem_budget is None else vmem_budget
     return ell_vmem_bytes(c, f, r, s, stride, tm, min(te, e),
                           fuse_res=fuse_res, pipeline=pipeline) <= budget
+
+
+def pointwise_rows(n: int, e: int, f: int) -> int:
+    """Rows of 128 lanes that hold the N*E*F pixels of a 1x1 layer in the
+    ELL kernel's pointwise layout."""
+    return -(-(n * e * f) // 128)
+
+
+def ell_pointwise_vmem_bytes(c: int, tm: int, tp: int,
+                             fuse_res: bool = False) -> int:
+    """VMEM working set of the ELL pointwise schedule at a tile of TP pixel
+    rows: the double-buffered f32 (C, TP, 128) input block, the
+    double-buffered f32 (TM, TP, 128) out block, and — when the fused
+    epilogue accumulates a shortcut — the residual block, blocked like the
+    output."""
+    plane = vmem_tile_bytes(tp, 128, 4)
+    out_bytes = 2 * tm * plane
+    return 2 * c * plane + out_bytes + (out_bytes if fuse_res else 0)
+
+
+def pointwise_tile(n: int, e: int, f: int, c: int, tm: int,
+                   fuse_res: bool = False, *,
+                   vmem_budget: int = None) -> Optional[int]:
+    """The pointwise schedule's pixel tile TP, in rows of 128 lanes, or
+    None when even one sublane tile (8 rows) busts the budget.
+
+    The largest multiple of 8 rows, up to the whole pixel axis, whose
+    working set (:func:`ell_pointwise_vmem_bytes`) fits; then evened out
+    over the same number of tiles, so the zero rows that pad the pixel
+    axis to whole tiles stay under 8 a tile."""
+    budget = VMEM_BUDGET if vmem_budget is None else vmem_budget
+    rows = pointwise_rows(n, e, f)
+    per_tile = ell_pointwise_vmem_bytes(c, tm, 8, fuse_res)
+    tp_max = min(round_up(rows, 8), 8 * (budget // per_tile))
+    if tp_max < 8:
+        return None
+    tiles = -(-rows // tp_max)
+    return round_up(-(-rows // tiles), 8)
 
 
 # -- BCSR MXU conv (kernels/bsr_conv) ---------------------------------------

@@ -58,6 +58,18 @@ discarded output positions).  Block shapes follow the TPU's (8, 128) tile
 rule: TE is a multiple of 8 or E, the tile spans all F columns, and TM is
 a multiple of 8 or M (``ops.tile_candidates`` emits only such tilings).
 
+Pointwise schedule (``tp`` set; 1x1, stride 1, no padding): every nonzero
+multiplies one input channel at every pixel of every image, so the window
+schedule's per-image (TE, F) tile — 49 of 1024 lanes at 7x7 — wastes the
+vector unit and pays the per-nonzero loop once per image.  The entry point
+instead lays the batch's pixels on lanes: the input goes to
+``(C, rows, 128)`` (``N*E*F`` pixels, zero-padded to whole tiles), the grid
+is (pixel tiles, M/TM) with channel tiles innermost, and each nonzero's FMA
+covers a whole (TP, 128) slab read at a dynamic leading index.  The output
+(and a fused residual) use the same layout and are transposed back.  Each
+output element adds the same products in the same order as the window
+schedule, so the two agree to the bit.
+
 Index packing: each nonzero's (c, r, s) is packed into one int32 as
 ``c * (R*S) + r * S + s`` to keep the index stream at 4 bytes a nonzero; the
 kernel decodes with two divmods (scalar ALU, off the critical VPU path).
@@ -90,6 +102,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.budget import pointwise_rows
 from repro.kernels.window import load_window, phase_split, stage_shape
 
 
@@ -160,46 +173,147 @@ def _kernel(*refs,                       # scalar prefetch (SMEM), then the rest
             dma.wait()
         lead = ()
 
+    def load(ml, kk):
+        packed = idx_ref[ml, kk]
+        c = packed // rs
+        rem = packed - c * rs
+        r = rem // s
+        ss = rem - r * s
+        win = load_window(xblk_ref, lead, c, r, ss, s=s, stride=stride,
+                          te=te, f=f)
+        return win.astype(jnp.float32)
+
+    def store(ml, acc):
+        out_ref[0, ml] = acc
+
+    _channel_loop(
+        tm=tm, mt=mt, nnz_ref=nnz_ref, bias_ref=bias_ref,
+        scale_ref=scale_ref, val_ref=val_ref, load=load, shape=(te, f),
+        res=(lambda ml: res_ref[0, ml]) if has_res else None, store=store,
+        fuse_relu=fuse_relu)
+
+
+def _channel_loop(*, tm, mt, nnz_ref, bias_ref, scale_ref, val_ref, load,
+                  shape, res, store, fuse_relu):
+    """The per-channel loop both schedules share: for each output channel
+    of the tile, one f32 FMA over the slab ``load(ml, kk)`` per true
+    nonzero, in bank order, then the fused epilogue and one write
+    (``store``).  ``res(ml)`` reads the channel's shortcut slab."""
+
     def channel(ml, _):
         m = mt * tm + ml
 
         def body(kk, acc):
-            packed = idx_ref[ml, kk]
-            c = packed // rs
-            rem = packed - c * rs
-            r = rem // s
-            ss = rem - r * s
-            win = load_window(xblk_ref, lead, c, r, ss, s=s, stride=stride,
-                              te=te, f=f)
             v = val_ref[ml, kk].astype(jnp.float32)
-            if quantized:
+            if scale_ref is not None:
                 # Dequantise at the FMA: multiply the int8/fp8 value by its
-                # row's f32 scale *before* the window product — the exact
+                # row's f32 scale *before* the slab product — the exact
                 # multiply ``dequantize`` performs host-side, so this kernel
                 # is bit-identical to the f32 kernel on a dequantised bank.
                 v = v * scale_ref[m]
-            return acc + v * win.astype(jnp.float32)
+            return acc + v * load(ml, kk)
 
-        acc0 = jnp.zeros((te, f), dtype=jnp.float32)
+        acc0 = jnp.zeros(shape, dtype=jnp.float32)
         # CSR semantics: iterate only this row's true nonzeros.
         acc = lax.fori_loop(0, nnz_ref[m], body, acc0)
         # Fused epilogue on the in-register f32 accumulator: one output
         # write instead of separate bias / residual / ReLU HBM passes.
         acc = acc + bias_ref[m]
-        if has_res:
-            acc = acc + res_ref[0, ml].astype(jnp.float32)
+        if res is not None:
+            acc = acc + res(ml).astype(jnp.float32)
         if fuse_relu:
             acc = jnp.maximum(acc, 0.0)
-        out_ref[0, ml] = acc
+        store(ml, acc)
         return 0
 
     lax.fori_loop(0, tm, channel, 0)
 
 
+def _pointwise_kernel(*refs, fuse_relu: bool, has_res: bool,
+                      quantized: bool, tm: int):
+    """The pointwise schedule (1x1, stride 1, no padding): the block holds
+    every input channel at TP x 128 pixels of the flattened batch, so each
+    nonzero's FMA covers a whole aligned (TP, 128) slab, read at a dynamic
+    leading index ``x[c]`` — no halo, no window decode."""
+    if quantized:
+        nnz_ref, bias_ref, scale_ref, x_ref, idx_ref, val_ref, *rest = refs
+    else:
+        scale_ref = None
+        nnz_ref, bias_ref, x_ref, idx_ref, val_ref, *rest = refs
+    res_ref, out_ref = rest if has_res else (None, *rest)
+
+    def load(ml, kk):
+        # A 1x1 bank's packed index c*(R*S) + r*S + s is its channel c.
+        return x_ref[idx_ref[ml, kk]]
+
+    def store(ml, acc):
+        out_ref[ml] = acc
+
+    _channel_loop(
+        tm=tm, mt=pl.program_id(1), nnz_ref=nnz_ref, bias_ref=bias_ref,
+        scale_ref=scale_ref, val_ref=val_ref, load=load,
+        shape=x_ref.shape[1:],
+        res=(lambda ml: res_ref[ml]) if has_res else None, store=store,
+        fuse_relu=fuse_relu)
+
+
+def _to_lanes(a: jax.Array, rows: int) -> jax.Array:
+    """(N, C, E, F) -> f32 (C, rows, 128): each channel's N*E*F pixels on
+    rows of 128 lanes, zero-padded to ``rows``."""
+    n, c, e, f = a.shape
+    flat = jnp.transpose(a, (1, 0, 2, 3)).reshape(c, n * e * f)
+    flat = jnp.pad(flat.astype(jnp.float32),
+                   ((0, 0), (0, rows * 128 - n * e * f)))
+    return flat.reshape(c, rows, 128)
+
+
+def _from_lanes(y: jax.Array, n: int, e: int, f: int) -> jax.Array:
+    """(M, rows, 128) -> (N, M, E, F), the padding rows cropped."""
+    m = y.shape[0]
+    y = y.reshape(m, -1)[:, :n * e * f].reshape(m, n, e, f)
+    return jnp.transpose(y, (1, 0, 2, 3))
+
+
+def _pointwise(x, value, packed_idx, nnz, bias, residual, scale, *, tm, k,
+               tp, fuse_relu, interpret):
+    """Launch the pointwise schedule: grid (pixel tiles, M/TM), channel
+    tiles innermost, so the (C, TP, 128) input block — its index map
+    ignores the channel tile — is fetched once per pixel tile."""
+    n, c, e, f = x.shape
+    m = value.shape[0]
+    tiles = pl.cdiv(pointwise_rows(n, e, f), tp)
+    rows = tiles * tp
+    has_res = residual is not None
+    quantized = scale is not None
+    smem_tile = pl.BlockSpec((tm, k), lambda p, mt, *_: (mt, 0),
+                             memory_space=pltpu.SMEM)
+    slab = pl.BlockSpec((tm, tp, 128), lambda p, mt, *_: (mt, p, 0))
+    in_specs = [pl.BlockSpec((c, tp, 128), lambda p, mt, *_: (0, p, 0)),
+                smem_tile, smem_tile]
+    prefetch = [nnz, bias, scale] if quantized else [nnz, bias]
+    inputs = [*prefetch, _to_lanes(x, rows), packed_idx, value]
+    if has_res:
+        in_specs.append(slab)
+        inputs.append(_to_lanes(residual, rows))
+    y = pl.pallas_call(
+        functools.partial(_pointwise_kernel, fuse_relu=fuse_relu,
+                          has_res=has_res, quantized=quantized, tm=tm),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(tiles, m // tm),
+            in_specs=in_specs,
+            out_specs=slab,
+        ),
+        out_shape=jax.ShapeDtypeStruct((m, rows, 128), jnp.float32),
+        interpret=interpret,
+    )(*inputs)
+    return _from_lanes(y, n, e, f)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("tm", "k", "rs", "s", "e", "f", "stride", "te",
-                     "fuse_relu", "pipeline", "interpret"))
+                     "fuse_relu", "pipeline", "tp", "interpret"))
 def sparse_conv_pallas(xpad: jax.Array, value: jax.Array, packed_idx: jax.Array,
                        nnz: jax.Array, bias: jax.Array,
                        residual: jax.Array | None = None,
@@ -207,8 +321,10 @@ def sparse_conv_pallas(xpad: jax.Array, value: jax.Array, packed_idx: jax.Array,
                        rs: int, s: int, e: int, f: int, stride: int = 1,
                        te: int | None = None,
                        fuse_relu: bool = False, pipeline: bool = False,
+                       tp: int | None = None,
                        interpret: bool = False) -> jax.Array:
-    """Launch the spatially-tiled direct sparse conv kernel.
+    """Launch the direct sparse conv kernel: the spatially-tiled window
+    schedule, or with ``tp`` the pointwise schedule.
 
     Args:
       xpad:       (N, C, Hp, Wp) pre-padded input (the paper's pad_in step).
@@ -237,6 +353,10 @@ def sparse_conv_pallas(xpad: jax.Array, value: jax.Array, packed_idx: jax.Array,
                   for spatial cell i+1 issued while cell i computes — at the
                   cost of a second halo-block's VMEM.  False keeps the
                   single-buffer blocking schedule.
+      tp:         pixel tile of the pointwise schedule, in rows of 128
+                  lanes (``budget.pointwise_tile``), for a 1x1, stride-1,
+                  unpadded layer only; ``te`` and ``pipeline`` then do not
+                  apply.
 
     Returns: (N, M, E, F) float32.
     """
@@ -250,6 +370,14 @@ def sparse_conv_pallas(xpad: jax.Array, value: jax.Array, packed_idx: jax.Array,
             f"channel tile tm={tm} does not divide M={m} "
             f"(geometry: n={n} c={c} hp={hp} wp={wp} k={k} rs={rs} "
             f"stride={stride} e={e} f={f})")
+    if tp is not None:
+        if rs != 1 or stride != 1 or (hp, wp) != (e, f):
+            raise ValueError(
+                f"the pointwise schedule runs 1x1, stride-1, unpadded layers "
+                f"only (rs={rs} stride={stride} hp={hp} wp={wp} e={e} f={f})")
+        return _pointwise(xpad, value, packed_idx, nnz, bias, residual,
+                          scale, tm=tm, k=k, tp=tp, fuse_relu=fuse_relu,
+                          interpret=interpret)
     te = e if te is None else min(te, e)
     r = rs // s
     planes, halo_h, wq = stage_shape(c, r, s, stride, te, f,

@@ -16,6 +16,10 @@ budget or for which no VMEM-feasible tiling exists — the fallback applies
 the same epilogue unfused, so ``sparse_conv`` is a complete conv+epilogue
 operator either way.
 
+A 1x1, stride-1, unpadded layer runs the kernel's pointwise schedule, which
+puts the batch's pixels on lanes (``EllSchedule.tp``, sized by
+``budget.pointwise_tile``); every other geometry runs the window schedule.
+
 Strided layers and tall feature maps run through the Pallas kernel: the
 kernel tiles the output rows with halo'd input bands, and the wrapper's
 column phase split lets it read every strided window as a static lane
@@ -25,12 +29,13 @@ of E, whole output rows).
 """
 from __future__ import annotations
 
-from typing import List, Optional, Tuple
+from typing import List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.core.direct_conv import direct_sparse_conv, out_spatial
 from repro.core.sparse_format import (EllConv, dequantize,
                                       ell_from_dense_conv,
@@ -218,23 +223,76 @@ def apply_epilogue(y: jax.Array, bias: Optional[jax.Array],
     return y.astype(dtype)
 
 
+class EllSchedule(NamedTuple):
+    """What the ELL kernel runs for one layer: the channel tile, and either
+    the window schedule's row tile ``te`` and halo DMA mode ``pipeline``,
+    or the pointwise schedule's pixel tile ``tp`` (rows of 128 lanes; ``te``
+    None and ``pipeline`` False)."""
+    tm: int
+    te: Optional[int]
+    pipeline: bool
+    tp: Optional[int] = None
+
+    def tiling(self) -> dict:
+        """The schedule as the ExecutionReport's ``tiling`` names it."""
+        if self.tp is None:
+            return {"schedule": "window", "tm": self.tm, "te": self.te,
+                    "pipeline": self.pipeline}
+        return {"schedule": "pointwise", "tm": self.tm, "tp": self.tp}
+
+
+def is_pointwise(r: int, s: int, stride: int, padding: int) -> bool:
+    """The geometry the pointwise schedule runs: 1x1, stride 1, no
+    padding."""
+    return r == s == 1 and stride == 1 and padding == 0
+
+
+def _pointwise_schedule(m: int, c: int, e: int, f: int, k: int, n: int,
+                        tm: Optional[int], fuse_res: bool,
+                        vsize: int) -> Optional[EllSchedule]:
+    """The pointwise schedule at the pinned ``tm`` (or, when None, the
+    ladder's tile with the fewest pixel tiles, then the largest), or None
+    when no blockable channel tile fits SMEM with at least 8 pixel rows in
+    VMEM."""
+    best = None
+    for t in ([tm] if tm is not None else tm_ladder(m)):
+        if not (channel_tile_ok(m, t)
+                and budget.smem_fits(m, k, vsize == 1, tm=t,
+                                     value_itemsize=vsize,
+                                     smem_budget=_SMEM_BUDGET)):
+            continue
+        tp = budget.pointwise_tile(n, e, f, c, t, fuse_res,
+                                   vmem_budget=_VMEM_BUDGET)
+        if tp is None:
+            continue
+        tiles = -(-budget.pointwise_rows(n, e, f) // tp)
+        if best is None or tiles < best[0]:
+            best = (tiles, EllSchedule(t, None, False, tp))
+    return None if best is None else best[1]
+
+
 def resolve_schedule(m: int, c: int, e: int, f: int, k: int, r: int, s: int,
-                     stride: int, *, tm: Optional[int] = None,
+                     stride: int, *, n: int = 1, padding: int = 0,
+                     tm: Optional[int] = None,
                      te: Optional[int] = None,
                      fuse_res: bool = False,
                      pipeline: Optional[bool] = None,
                      value_dtype: str = "float32",
-                     ) -> Tuple[Optional[Tuple[int, int, bool]],
-                                Optional[str]]:
+                     ) -> Tuple[Optional[EllSchedule], Optional[str]]:
     """The dispatch decision ``sparse_conv`` makes, as a pure function.
 
-    Returns ``((tm, te, pipeline), None)`` for the schedule the Pallas
-    kernel would run, or ``(None, reason)`` — a ``telemetry.fallback``
-    reason code — when the layer falls back to the pure-JAX direct path.
-    Factored out so the engine's ExecutionReport and the benchmark's
-    zero-fallback invariant can ask "what would this layer execute?"
-    without launching anything; ``sparse_conv`` itself dispatches through
-    this same function.
+    Returns ``(EllSchedule, None)`` for the schedule the Pallas kernel
+    would run, or ``(None, reason)`` — a ``telemetry.fallback`` reason code
+    — when the layer falls back to the pure-JAX direct path.  Factored out
+    so the engine's ExecutionReport and the benchmark's zero-fallback
+    invariant can ask "what would this layer execute?" without launching
+    anything; ``sparse_conv`` itself dispatches through this same function.
+
+    A 1x1, stride-1, unpadded layer (``is_pointwise``) takes the pointwise
+    schedule over its ``n`` images whenever 8 pixel rows fit VMEM at a
+    channel tile; ``te`` and ``pipeline`` do not apply there.  Every other
+    layer, and a pointwise one whose input channels bust VMEM even so,
+    takes the window schedule.
 
     ``value_dtype`` names the bank's storage dtype: a quantised bank
     (int8 / float8_e4m3fn) shrinks the VMEM value block to one byte per
@@ -248,21 +306,23 @@ def resolve_schedule(m: int, c: int, e: int, f: int, k: int, r: int, s: int,
         # Index-heavy layers: even the smallest channel tile's index and
         # value tiles overflow SMEM.
         return None, "smem_infeasible"
+    if tm is not None and (tm < 1 or m % tm):
+        return None, "nondividing_tm"
+    if is_pointwise(r, s, stride, padding):
+        sched = _pointwise_schedule(m, c, e, f, k, n, tm, fuse_res, vsize)
+        if sched is not None:
+            return sched, None
     if tm is not None and te is not None:
         # Fully-specified tiling (tuned plan / caller override): honor it
         # when it is blockable and fits, never launch an over-budget or
         # uncompilable kernel.
         te = min(te, e)
-        if tm < 1 or m % tm:
-            return None, "nondividing_tm"
         if not tiling_fits(m, c, e, f, k, r, s, stride, tm, te,
                            fuse_res=fuse_res, value_itemsize=vsize):
             return None, "no_feasible_tiling"
     else:
         # A pinned tm need not sit on the default ladder (e.g. tm=24 for
         # m=48): enumerate spatial tiles for exactly that tm.
-        if tm is not None and (tm < 1 or m % tm):
-            return None, "nondividing_tm"
         cands = tile_candidates(m, c, e, f, k, r, s, stride,
                                 tms=None if tm is None else (tm,),
                                 fuse_res=fuse_res, value_itemsize=vsize)
@@ -278,7 +338,7 @@ def resolve_schedule(m: int, c: int, e: int, f: int, k: int, r: int, s: int,
         pipeline = tiling_fits(m, c, e, f, k, r, s, stride, tm, te,
                                fuse_res=fuse_res, pipeline=True,
                                value_itemsize=vsize)
-    return (tm, te, bool(pipeline)), None
+    return EllSchedule(tm, te, bool(pipeline)), None
 
 
 def sparse_conv(x: jax.Array, ell: EllConv, *, stride: int = 1,
@@ -298,6 +358,11 @@ def sparse_conv(x: jax.Array, ell: EllConv, *, stride: int = 1,
     ``fuse_relu`` and ``residual`` (a shortcut tensor shaped like the
     output) execute in-kernel on the f32 accumulator so the output is
     written to HBM exactly once.
+
+    A 1x1, stride-1, unpadded layer runs the kernel's pointwise schedule
+    (the batch's pixels on lanes, ``resolve_schedule``); ``te`` and
+    ``pipeline`` then do not apply.  With telemetry on, each such layer
+    traced counts once in ``kernels.sparse_conv.pointwise``.
 
     ``pipeline`` selects the halo DMA schedule: ``True`` double-buffers the
     staged input block (the copy for spatial cell i+1 overlaps cell i's
@@ -343,14 +408,16 @@ def sparse_conv(x: jax.Array, ell: EllConv, *, stride: int = 1,
             y = jnp.take(y, inv, axis=1)
         return apply_epilogue(y, bias, fuse_relu, residual)
 
-    sched, reason = resolve_schedule(m, c, e, f, k, r, s, stride, tm=tm,
-                                     te=te, fuse_res=fuse_res,
-                                     pipeline=pipeline,
+    sched, reason = resolve_schedule(m, c, e, f, k, r, s, stride, n=n,
+                                     padding=padding, tm=tm, te=te,
+                                     fuse_res=fuse_res, pipeline=pipeline,
                                      value_dtype=ell.value_dtype)
     if sched is None:
         # The XLA-scheduled direct path, with the same epilogue unfused.
         return fallback(reason)
-    tm, te, pipeline = sched
+    if sched.tp is not None and telemetry.is_enabled():
+        # Trace time, like the fallback counters: one per layer traced.
+        telemetry.counter("kernels.sparse_conv.pointwise").inc()
     xpad = jnp.pad(x, ((0, 0), (0, 0), (padding, padding), (padding, padding)))
     b = (jnp.zeros((m,), jnp.float32) if bias is None
          else jnp.asarray(bias, jnp.float32))
@@ -364,8 +431,9 @@ def sparse_conv(x: jax.Array, ell: EllConv, *, stride: int = 1,
     out = sparse_conv_pallas(
         xpad, ell.value, pack_indices(ell), ell.nnz, b, res,
         scale=ell.scale,
-        tm=tm, k=k, rs=r * s, s=s, e=e, f=f, stride=stride, te=te,
-        fuse_relu=fuse_relu, pipeline=pipeline, interpret=interpret)
+        tm=sched.tm, k=k, rs=r * s, s=s, e=e, f=f, stride=stride,
+        te=sched.te, fuse_relu=fuse_relu, pipeline=sched.pipeline,
+        tp=sched.tp, interpret=interpret)
     if inv is not None:
         out = jnp.take(out, inv, axis=1)
     return out.astype(x.dtype)

@@ -152,7 +152,7 @@ def _fits_bank(pe: PlanEntry, op: ConvOp, row_nnz: int) -> bool:
         return True
     sched, _ = resolve_schedule(
         op.m, op.c, op.e, op.f, ell_k(row_nnz, pe.pad_to or 8), op.k, op.k,
-        op.stride, tm=pe.tm, te=pe.te,
+        op.stride, padding=op.pad, tm=pe.tm, te=pe.te,
         fuse_res=pe.fuse and op.res is not None,
         pipeline=pe.pipeline, value_dtype=pe.value_dtype)
     return sched is not None

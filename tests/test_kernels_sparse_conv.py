@@ -619,3 +619,148 @@ def test_quantised_balanced_bank_parity():
     ref = np.asarray(sparse_conv_ref(x, jnp.asarray(wt), padding=1))
     rel = np.linalg.norm(np.asarray(y_q) - ref) / np.linalg.norm(ref)
     assert rel < 0.05, rel
+
+
+# ---------------------------------------------------------------------------
+# pointwise schedule: 1x1, stride 1, no padding — the batch on lanes
+# ---------------------------------------------------------------------------
+
+def _bank_weights(ell) -> np.ndarray:
+    """The (M, C, R, S) weights a bank holds, in natural channel order."""
+    deq = dequantize(ell)
+    w = np.zeros(deq.shape, np.float32)
+    rows = (np.arange(w.shape[0]) if deq.perm is None
+            else np.asarray(deq.perm))[:, None]
+    # ELL padding entries hold zeros, so adding them is inert.
+    np.add.at(w, (rows, np.asarray(deq.cidx), np.asarray(deq.ridx),
+                  np.asarray(deq.sidx)), np.asarray(deq.value))
+    return w
+
+
+def _launches(monkeypatch):
+    """Record the keyword arguments of every kernel launch."""
+    seen = []
+    real = ops.sparse_conv_pallas
+    monkeypatch.setattr(ops, "sparse_conv_pallas",
+                        lambda *a, **kw: seen.append(kw) or real(*a, **kw))
+    return seen
+
+
+# (N, H=W, tm, pixel tiles): 49 and 588 pixels fill one partial tile (not a
+# multiple of 128 or 1024); 1176 pixels over 2 tiles of 8 rows when the
+# VMEM budget is cut to one 8-row tile.
+POINTWISE_GEOMETRIES = [(1, 7, None, 1), (3, 14, 8, 1), (6, 14, 8, 2)]
+
+
+@pytest.mark.parametrize("bank", ["float32", "int8", "float8_e4m3fn",
+                                  "balanced"])
+@pytest.mark.parametrize("residual,relu", [(False, False), (True, False),
+                                           (False, True), (True, True)])
+@pytest.mark.parametrize("geometry", POINTWISE_GEOMETRIES)
+def test_pointwise_matches_window_schedule(geometry, residual, relu, bank,
+                                           monkeypatch):
+    """A 1x1 stride-1 layer takes the pointwise schedule, whose output
+    equals the window schedule's on the same bank (the same products in
+    the same order, in f32) and agrees with the dense oracle: f32, int8
+    and fp8 banks, a balanced bank, an all-zero row, channel tiles below
+    M, a ragged pixel axis and several pixel tiles."""
+    n, hw, tm, tiles = geometry
+    c, m = 16, 32
+    rng = np.random.default_rng([n, hw, residual, relu, len(bank)])
+    x = jnp.asarray(rng.standard_normal((n, c, hw, hw)).astype(np.float32))
+    wt = np.array(magnitude_prune(
+        jnp.asarray(rng.standard_normal((m, c, 1, 1)).astype(np.float32)),
+        0.7))
+    wt[5] = 0.0                                  # an all-zero row
+    ell = ell_from_dense_conv(wt)
+    if bank == "balanced":
+        ell = balance_ell_conv(ell)
+    elif bank != "float32":
+        ell = quantize_values(ell, bank)
+    bias = jnp.asarray(rng.standard_normal(m).astype(np.float32))
+    res = (jnp.asarray(rng.standard_normal((n, m, hw, hw)).astype(np.float32))
+           if residual else None)
+    if tiles > 1:
+        monkeypatch.setattr(ops, "_VMEM_BUDGET",
+                            kbudget.ell_pointwise_vmem_bytes(
+                                c, tm, 8, fuse_res=residual))
+    kw = dict(tm=tm, bias=bias, fuse_relu=relu, residual=res, interpret=True)
+
+    launches = _launches(monkeypatch)
+    got = np.asarray(sparse_conv(x, ell, **kw))
+    assert launches[-1]["tp"] == 8 and launches[-1]["tm"] == (tm or m)
+    assert -(-n * hw * hw // 128) <= 8 * tiles
+    if tiles > 1:
+        assert -(-n * hw * hw // 128) > 8 * (tiles - 1)
+    with monkeypatch.context() as mp:
+        mp.setattr(ops, "is_pointwise", lambda *a: False)
+        want = np.asarray(sparse_conv(x, ell, **kw))
+    assert launches[-1]["tp"] is None
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=1e-6 * np.abs(want).max())
+
+    ref = sparse_conv_ref(x, jnp.asarray(_bank_weights(ell)))
+    ref = ref + bias[None, :, None, None]
+    if residual:
+        ref = ref + res
+    if relu:
+        ref = jax.nn.relu(ref)
+    np.testing.assert_allclose(got, np.asarray(ref), rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("r,stride,pad", [(3, 1, 1), (1, 2, 0), (1, 1, 1)])
+def test_other_geometries_keep_window_schedule(r, stride, pad, monkeypatch):
+    """A 3x3 layer, a strided 1x1 and a padded 1x1 take the window
+    schedule, as before the pointwise one existed."""
+    rng = np.random.default_rng(100 * r + 10 * stride + pad)
+    x = jnp.asarray(rng.standard_normal((2, 8, 10, 10)).astype(np.float32))
+    wt = np.asarray(magnitude_prune(
+        jnp.asarray(rng.standard_normal((16, 8, r, r)).astype(np.float32)),
+        0.7))
+    ell = ell_from_dense_conv(wt)
+    e, f = out_spatial(10, 10, r, r, stride, pad)
+    sched, reason = ops.resolve_schedule(16, 8, e, f, ell.k, r, r, stride,
+                                         n=2, padding=pad)
+    assert reason is None and sched.tp is None and sched.te is not None
+    assert sched.tiling()["schedule"] == "window"
+    launches = _launches(monkeypatch)
+    got = sparse_conv(x, ell, stride=stride, padding=pad, interpret=True)
+    assert launches[-1]["tp"] is None
+    ref = sparse_conv_ref(x, jnp.asarray(wt), stride=stride, padding=pad)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
+
+def test_pointwise_yields_to_window_when_channels_bust_vmem(monkeypatch):
+    """A 1x1 layer whose input channels cannot fit even 8 pixel rows in
+    VMEM keeps the window schedule rather than falling back."""
+    c, m = 16, 8
+    per_row_tile = kbudget.ell_pointwise_vmem_bytes(c, 8, 8)
+    monkeypatch.setattr(ops, "_VMEM_BUDGET", per_row_tile - 1)
+    assert kbudget.pointwise_tile(1, 12, 12, c, 8,
+                                  vmem_budget=per_row_tile - 1) is None
+    sched, reason = ops.resolve_schedule(m, c, 12, 12, 8, 1, 1, 1)
+    assert reason is None and sched.tp is None
+
+
+def test_pointwise_bf16_matches_window_schedule(monkeypatch):
+    """bf16 input, values and shortcut: the pointwise layout widens them to
+    f32 before the kernel, the window kernel after its loads — the same
+    values, so the same output."""
+    import dataclasses
+    rng = np.random.default_rng(4242)
+    x = jnp.asarray(rng.standard_normal((2, 16, 9, 9)), dtype=jnp.bfloat16)
+    wt = np.asarray(magnitude_prune(
+        jnp.asarray(rng.standard_normal((24, 16, 1, 1)).astype(np.float32)),
+        0.7))
+    ell = ell_from_dense_conv(wt)
+    ell = dataclasses.replace(ell, value=ell.value.astype(jnp.bfloat16))
+    res = jnp.asarray(rng.standard_normal((2, 24, 9, 9)), dtype=jnp.bfloat16)
+    kw = dict(tm=8, fuse_relu=True, residual=res, interpret=True)
+    launches = _launches(monkeypatch)
+    got = sparse_conv(x, ell, **kw)
+    assert launches[-1]["tp"] == 8 and got.dtype == jnp.bfloat16
+    monkeypatch.setattr(ops, "is_pointwise", lambda *a: False)
+    want = sparse_conv(x, ell, **kw)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))

@@ -80,6 +80,58 @@ def test_bsr_vmem_bytes_pins_res3_3x3():
     assert budget.bsr_vmem_bytes(**args, fuse_res=True) == total + out
 
 
+# The pointwise schedule's working set per 8 pixel rows (one (8, 128) f32
+# tile a plane): double-buffered input planes, out planes and, when fused,
+# residual planes.
+_ROW_TILE = 8 * 128 * 4
+
+
+@pytest.mark.parametrize("layer,c,tm,res,batch,hw,tp,rows", [
+    # res5 1x1b (C=512, M=2048, tm=128, fused shortcut) at 7x7: 1568
+    # pixels are 13 rows; 16 rows take 2*512*16 + 2*2*128*16 tiles of 512
+    # bytes, 12 MiB, the whole budget.  Batch 8: 392 pixels, 4 rows -> 8.
+    ("res5_1x1b", 512, 128, True, 32, 7, 16, 13),
+    ("res5_1x1b", 512, 128, True, 8, 7, 8, 4),
+    # res4 1x1a (C=1024, the tightest): 8 rows take 9 MiB, 16 would take
+    # 18; 6272 pixels are 49 rows, 7 tiles of 8.  Batch 8: 13 rows, 2.
+    ("res4_1x1a", 1024, 128, False, 32, 14, 8, 49),
+    ("res4_1x1a", 1024, 128, False, 8, 14, 8, 13),
+    # GoogLeNet 3b/1x1 (C=256, M=128): 3 MiB per 8 rows, so at most 32;
+    # 25088 pixels are 196 rows, 7 tiles of 28 -> 32.  Batch 8: 49 rows,
+    # 2 tiles of 25 -> 32.
+    ("3b_1x1", 256, 128, False, 32, 28, 32, 196),
+    ("3b_1x1", 256, 128, False, 8, 28, 32, 49),
+])
+def test_pointwise_tile_pins_real_layers(layer, c, tm, res, batch, hw, tp,
+                                         rows):
+    per8 = (2 * c + 2 * tm * (2 if res else 1)) * _ROW_TILE
+    assert budget.ell_pointwise_vmem_bytes(c, tm, 8, fuse_res=res) == per8
+    assert budget.ell_pointwise_vmem_bytes(c, tm, tp, fuse_res=res) == (
+        tp // 8 * per8)
+    assert budget.pointwise_rows(batch, hw, hw) == rows
+    assert budget.pointwise_tile(batch, hw, hw, c, tm, res) == tp
+    assert tp // 8 * per8 <= budget.VMEM_BUDGET
+
+
+def test_pointwise_vmem_bytes_pins_res5_and_res4():
+    """The two tightest fits by hand: res5 1x1b at 16 rows fills the 12 MiB
+    budget exactly; res4 1x1a at 16 rows would bust it."""
+    res5 = 2 * 512 * 16 * 128 * 4 + 2 * 2 * 128 * 16 * 128 * 4
+    assert res5 == 12582912 == budget.VMEM_BUDGET
+    assert budget.ell_pointwise_vmem_bytes(512, 128, 16, fuse_res=True) == res5
+    assert budget.ell_pointwise_vmem_bytes(1024, 128, 8) == 9437184
+    assert budget.ell_pointwise_vmem_bytes(1024, 128, 16) == 18874368
+
+
+def test_pointwise_tile_evens_out_tiles():
+    """C=128, tm=64 at 28x28, batch 32: 64 rows fit (1.5 MiB per 8), so
+    196 rows need 4 tiles; evened out they take 56 rows each (224 rows in
+    all), not 64 (256)."""
+    assert budget.ell_pointwise_vmem_bytes(128, 64, 64) <= budget.VMEM_BUDGET
+    assert budget.ell_pointwise_vmem_bytes(128, 64, 72) > budget.VMEM_BUDGET
+    assert budget.pointwise_tile(32, 28, 28, 128, 64) == 56
+
+
 def _staged_block(fn, *shapes):
     """The VMEM staging buffer a kernel allocates, read from its traced
     jaxpr (nothing runs): the first scratch operand of its pallas_call."""

@@ -460,3 +460,30 @@ def test_stale_bsr_plan_reports_fallback():
     assert bad[0].fallback_reason == "stale_plan_no_block"
     assert bad[0].method_executed == "dense"
     assert report.fallback_count == 1
+
+
+@pytest.mark.parametrize("net_name,n_pallas", [("resnet50", 18),
+                                               ("googlenet", 31)])
+def test_report_names_pointwise_schedule(net_name, n_pallas):
+    """At 224 px, batch 32, the roofline plan's ELL layers are all 1x1
+    stride-1 convs: each names the pointwise schedule in its report, none
+    falls back, and tracing the forward counts each once in
+    ``kernels.sparse_conv.pointwise``."""
+    import jax
+    import jax.numpy as jnp
+
+    net = cnn.NETWORKS[net_name]()
+    engine = CnnEngine(lower(net, (3, 224, 224)),
+                       cnn.init_cnn(net, 3, np.random.default_rng(0), 224))
+    x = jax.ShapeDtypeStruct((32, 3, 224, 224), jnp.float32)
+    report = engine.execution_report(x, "auto")
+    assert report.fallback_count == 0, report.format()
+    pallas = [o for o in report.ops if o.method_executed == "pallas"]
+    assert len(pallas) == n_pallas
+    for o in pallas:
+        assert o.tiling["schedule"] == "pointwise" and o.tiling["tp"] >= 8
+    with telemetry.enabled():
+        jax.eval_shape(lambda x: engine(x, "auto"), x)
+    snap = telemetry.snapshot()
+    assert snap["kernels.sparse_conv.pointwise"]["value"] == n_pallas
+    assert "fallback.total" not in snap
