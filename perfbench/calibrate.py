@@ -93,7 +93,7 @@ def main(argv=None) -> None:
             for ref_prec in args.reference_controls:
                 t0 = time.perf_counter()
                 y = correct.reference_logits(
-                    run.config, d["weights"], d["fc_w"], d["images"],
+                    run.config, d["weights"], d["fc_ws"], d["images"],
                     precision=ref_prec)
                 line[f"reference_{ref_prec}_max_err"] = float(
                     correct.row_errors(y, d["ref"]).max())

@@ -19,7 +19,7 @@ from typing import Dict, Sequence
 import jax
 import numpy as np
 
-from perfbench import reference
+from perfbench import references
 
 
 def row_errors(y: np.ndarray, ref: np.ndarray) -> np.ndarray:
@@ -34,13 +34,15 @@ def row_errors(y: np.ndarray, ref: np.ndarray) -> np.ndarray:
     return err
 
 
-def reference_logits(config, weights, fc_w, images, block: int = 32,
+def reference_logits(config, weights, fc_ws, images, block: int = 32,
                      precision: str = "highest") -> np.ndarray:
-    """The reference's logits for ``images`` (N, C, H, W), ``block`` rows at
-    a time, under ``jax.jit`` with the weights as arguments."""
-    fn = jax.jit(lambda w, f, x: reference.forward(config, w, f, x,
-                                                   precision))
-    out = [np.asarray(fn(weights, fc_w, images[i:i + block]))
+    """The logits of the configuration's reference for ``images``
+    (N, C, H, W), ``block`` rows at a time, under ``jax.jit`` with the
+    weights as arguments."""
+    forward = references.load(config).forward
+    fn = jax.jit(lambda w, f, x: forward(config, w, f, x, precision))
+    fc_ws = jax.device_put(fc_ws)
+    out = [np.asarray(fn(weights, fc_ws, images[i:i + block]))
            for i in range(0, images.shape[0], block)]
     return np.concatenate(out)
 

@@ -6,7 +6,7 @@ counts the same whichever method implements it.
 
 * A pruned conv requires ``2 * nnz * E * F`` operations per image (one
   multiply and one add per nonzero weight and output position), a dense
-  conv ``2 * M * C * K * K * E * F``, the FC layer ``2 * in_f * out_f``.
+  conv ``2 * M * C * K * K * E * F``, an FC layer ``2 * in_f * out_f``.
   Pools, adds and concatenations are not counted.
 * A conv's bytes are its input map read once, its output map written once,
   the fused shortcut read once where the layer has one, and its nonzero
@@ -19,7 +19,7 @@ from typing import Any, Dict, Iterable
 
 import numpy as np
 
-from perfbench import reference
+from perfbench import references
 
 DTYPE_BYTES = {"float32": 4, "bfloat16": 2, "float16": 2}
 
@@ -45,14 +45,14 @@ def nonzeros(weights: Dict[str, Any]) -> Dict[str, int]:
 
 def forward_flops(config: Dict[str, Any], nnz: Dict[str, int],
                   batch: int) -> float:
-    """Operations one forward of ``batch`` images requires: every conv at
-    its nonzeros (a dense conv's nonzeros are all its weights), plus the
-    FC layer, which runs dense."""
-    table = reference.conv_table(config)
-    fc = reference.fc_layer(config)
+    """Operations one forward of ``batch`` images requires: every conv of
+    the configuration's reference at its nonzeros (a dense conv's nonzeros
+    are all its weights), plus its FC layers, which run dense."""
+    ref = references.load(config)
     total = sum(conv_counts(cv, nnz[cv["name"]], batch)["flops"]
-                for cv in table)
-    return total + 2.0 * fc["in_f"] * fc["out_f"] * batch
+                for cv in ref.conv_table(config))
+    return total + sum(2.0 * fc["in_f"] * fc["out_f"] * batch
+                       for fc in ref.fc_layers(config))
 
 
 def least_seconds(counts: Iterable[Dict[str, float]], flops_per_s: float,

@@ -2,12 +2,17 @@
 the compilation cache, the measured window, and the result line.
 
 A cell of ``BENCHMARK.json`` names a configuration and a traffic mix.  The
-harness finds each by name: ``configs/<config>.json``,
-``traffic/<traffic>.json`` (whose ``kind`` names the module that drives it,
-``kinds/<kind>.py``), ``cells/<cell>.json`` (the cell's own parameters and
-correctness limits) and ``metrics/<metric>.py`` for each per-layer metric
-(a ``read(run)`` that returns the number, or None when it finds nothing to
-read).  Adding a cell of an existing configuration and mix adds one file.
+harness finds each by name: ``configs/<config>.json`` (whose ``reference``
+names the module that states its architecture: its conv and FC tables,
+which the program's network is checked against, its weights and its plain
+forward, see ``perfbench/references/__init__.py``),
+``traffic/<traffic>.json`` (whose ``kind`` names the module that drives
+it, ``kinds/<kind>.py``), ``cells/<cell>.json`` (the cell's own parameters
+and correctness limits) and ``metrics/<metric>.py`` for each per-layer
+metric (a ``read(run)`` that returns the number, or None when it finds
+nothing to read).  Adding a cell
+of an existing configuration and mix adds one file; adding an architecture
+adds its configuration, its reference module and its cells.
 """
 from __future__ import annotations
 

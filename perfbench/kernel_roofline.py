@@ -12,7 +12,7 @@ from __future__ import annotations
 
 from typing import Optional
 
-from perfbench import counts, peaks, reference, trace
+from perfbench import counts, peaks, references, trace
 
 # The engine method that runs a layer on each kernel, by the kernel's name
 # in the trace.
@@ -28,10 +28,10 @@ def share(run, kernel: str) -> Optional[float]:
     if not on_kernel or not seconds:
         return None
     pk = peaks.peak(run.device_kind)
+    table = references.load(run.config).conv_table(run.config)
     layers = [counts.conv_counts(cv, run.data["nnz"][cv["name"]],
                                  run.data["batch"])
-              for cv in reference.conv_table(run.config)
-              if cv["name"] in on_kernel]
+              for cv in table if cv["name"] in on_kernel]
     least = counts.least_seconds(layers, pk["flops_per_s"],
                                  pk["hbm_bytes_per_s"])
     return 100.0 * least / (seconds / run.data["forwards"])

@@ -19,7 +19,10 @@ import time
 import jax
 import numpy as np
 
-from perfbench import correct, counts, harness, reference, system, weights
+from perfbench import correct, counts, harness, references, system, weights
+
+# Gaps between fetches longer than this many forwards are logged.
+STALL_FORWARDS = 1.5
 
 
 def run(r: harness.Run) -> None:
@@ -57,13 +60,14 @@ def run(r: harness.Run) -> None:
     out = []                       # (pool index, logits on the host)
     with harness.window(r):
         t0 = r.t_window = time.perf_counter()
-        pending, i = None, 0
+        pending, i, fetched = None, 0, [t0]
         while True:
             with jax.profiler.TraceAnnotation("perfbench.dispatch"):
                 y = engine(xs[i % pool], method, plan_override=plan)
             if pending is not None:
                 with jax.profiler.TraceAnnotation("perfbench.fetch"):
                     out.append((pending[0], np.asarray(pending[1])))
+                fetched.append(time.perf_counter())
             pending, i = (i % pool, y), i + 1
             if time.perf_counter() - t0 >= r.seconds:
                 break
@@ -80,17 +84,27 @@ def run(r: harness.Run) -> None:
                   nnz=nnz)
     harness.log(f"window: {len(out)} batches of {batch} in "
                 f"{r.window_s:.6f} s, set-up {r.setup_s:.3f} s")
+    gaps = np.diff(fetched)
+    if gaps.size:
+        med = float(np.median(gaps))
+        long_ = [(g, t - t0) for g, t in zip(gaps, fetched[1:])
+                 if g > STALL_FORWARDS * med]
+        harness.log(f"fetch gaps: median {med * 1e3:.3f} ms; "
+                    f"{len(long_)} over {STALL_FORWARDS}x, "
+                    f"{sum(g - med for g, _ in long_) * 1e3:.1f} ms beyond it"
+                    + "".join(f"; {g * 1e3:.1f} ms ending at {t:.3f} s"
+                              for g, t in sorted(long_, reverse=True)[:5]))
 
     # The program's state goes before the reference runs.
     del engine, prm, y, pending
     jax.clear_caches()
     t0 = time.perf_counter()
-    fc_w = reference.fc_weight(cfg, fcs)
-    ref = correct.reference_logits(cfg, w, fc_w, images, block=batch)
+    fc_ws = references.load(cfg).fc_weights(cfg, fcs)
+    ref = correct.reference_logits(cfg, w, fc_ws, images, block=batch)
     errs = [correct.row_errors(y, ref[p * batch:(p + 1) * batch])
             for p, y in out]
     r.failed = int(sum(np.isinf(e).sum() for e in errs))
     r.numbers["max_err"] = correct.worst(errs)
-    r.data.update(weights=w, fc_w=fc_w, images=images, ref=ref)
+    r.data.update(weights=w, fc_ws=fc_ws, images=images, ref=ref)
     harness.log(f"reference: {time.perf_counter() - t0:.3f} s for "
                 f"{pool * batch} images; {len(out) * batch} rows compared")

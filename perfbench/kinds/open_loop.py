@@ -27,7 +27,7 @@ import time
 import jax
 import numpy as np
 
-from perfbench import arrivals, correct, harness, reference, system, weights
+from perfbench import arrivals, correct, harness, references, system, weights
 
 
 def nearest_rank(values, q: float) -> float:
@@ -150,12 +150,12 @@ def run(r: harness.Run) -> None:
     del server, prm, done, reqs
     jax.clear_caches()
     t0 = time.perf_counter()
-    fc_w = reference.fc_weight(cfg, fcs)
-    ref = correct.reference_logits(cfg, w, fc_w, images, block=32)
+    fc_ws = references.load(cfg).fc_weights(cfg, fcs)
+    ref = correct.reference_logits(cfg, w, fc_ws, images, block=32)
     rids = sorted(results)
     errs = correct.row_errors(np.stack([results[k] for k in rids]),
                               ref[which[rids]]) if rids else np.array([])
     r.numbers["max_err"] = correct.worst([errs])
-    r.data.update(weights=w, fc_w=fc_w, images=images, ref=ref)
+    r.data.update(weights=w, fc_ws=fc_ws, images=images, ref=ref)
     harness.log(f"reference: {time.perf_counter() - t0:.3f} s for {pool} "
                 f"images; {len(rids)} answers compared")

@@ -3,9 +3,13 @@
 The configuration's ``layers`` list is the network as it is run: ``conv``,
 ``maxpool``, ``bottleneck`` (ResNet v1: 1x1 with the stride, 3x3, 1x1, plus
 an identity or 1x1 projection shortcut, then ReLU), ``inception`` (four
-branches concatenated on channels), ``gap`` and ``fc``.  Every conv is
-followed by its bias and a ReLU, except the last 1x1 of a bottleneck and
-its projection, whose sum takes the ReLU.
+branches concatenated on channels), ``gap`` and ``fc`` (the NCHW map
+flattened, times the FC matrix, then a ReLU where the layer says
+``"relu": true``).  Every conv is followed by its bias and a ReLU, except
+the last 1x1 of a bottleneck and its projection, whose sum takes the ReLU.
+A ``conv`` layer may state ``groups`` (Caffe's ``group``): its output
+channels fall into that many groups, each of which reads only its own
+share of the input channels.
 
 Straightforward ``jax.numpy``/``lax`` in NCHW, nothing of the program
 under test imported.  ``precision="highest"`` is float32 at
@@ -13,14 +17,21 @@ under test imported.  ``precision="highest"`` is float32 at
 at ``lax.Precision.HIGH`` (three bfloat16 passes) and
 ``precision="bfloat16"`` holds every array in bfloat16: the controls, one
 precision below float32 at ``highest`` and at the TPU's default.
+
+The module interface is described in ``perfbench/references/__init__.py``.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, List
+import functools
+import json
+from typing import Any, Dict, List, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+
+from perfbench import weights as wts
 
 PRECISIONS = ("highest", "high", "bfloat16")
 
@@ -29,9 +40,14 @@ def out_size(h: int, k: int, stride: int, pad: int) -> int:
     return (h + 2 * pad - k) // stride + 1
 
 
-def _conv(name, out, k, stride=1, pad=0, sparsity=0.0):
+def _conv(name, out, k, stride=1, pad=0, sparsity=0.0, groups=1):
     return {"name": name, "out": out, "k": k, "stride": stride, "pad": pad,
-            "sparsity": sparsity}
+            "sparsity": sparsity, "groups": groups}
+
+
+def _layer_conv(layer: Dict[str, Any]) -> Dict[str, Any]:
+    return _conv(layer["conv"], layer["out"], layer["k"], layer["stride"],
+                 layer["pad"], layer["sparsity"], layer.get("groups", 1))
 
 
 def bottleneck_convs(b: Dict[str, Any]) -> Dict[str, Dict[str, Any]]:
@@ -58,12 +74,12 @@ def inception_convs(m: Dict[str, Any]) -> Dict[str, Dict[str, Any]]:
     }
 
 
-def conv_table(config: Dict[str, Any]) -> List[Dict[str, Any]]:
-    """Every conv of the network in forward order, with its input geometry
-    (``c``, ``h``, ``w``), its output size (``e``, ``f``), and ``res``:
-    whether a shortcut is added to its output before the ReLU."""
+def _tables(config: Dict[str, Any]
+            ) -> Tuple[List[Dict[str, Any]], List[Dict[str, Any]]]:
+    """The convs and the FC layers of the network, in forward order."""
     c, h = config["channels"], config["image"]
     out: List[Dict[str, Any]] = []
+    fcs: List[Dict[str, Any]] = []
 
     def add(cv, c_in, h_in, res=False):
         e = out_size(h_in, cv["k"], cv["stride"], cv["pad"])
@@ -72,9 +88,7 @@ def conv_table(config: Dict[str, Any]) -> List[Dict[str, Any]]:
 
     for layer in config["layers"]:
         if "conv" in layer:
-            c, h = add(_conv(layer["conv"], layer["out"], layer["k"],
-                             layer["stride"], layer["pad"],
-                             layer["sparsity"]), c, h)
+            c, h = add(_layer_conv(layer), c, h)
         elif "maxpool" in layer:
             h = out_size(h, layer["maxpool"], layer["stride"], layer["pad"])
         elif "bottleneck" in layer:
@@ -96,46 +110,79 @@ def conv_table(config: Dict[str, Any]) -> List[Dict[str, Any]]:
         elif "gap" in layer:
             h = 1
         elif "fc" in layer:
-            pass
+            fcs.append({"name": layer["fc"], "in_f": c * h * h,
+                        "out_f": layer["out"],
+                        "relu": layer.get("relu", False)})
+            c, h = layer["out"], 1
         else:
             raise ValueError(f"unknown layer {layer}")
-    return out
+    return out, fcs
 
 
-def fc_layer(config: Dict[str, Any]) -> Dict[str, Any]:
-    """The FC layer: its name, fan-in (channels after global pooling) and
-    outputs."""
-    c = config["channels"]
-    for layer in config["layers"]:
-        if "conv" in layer:
-            c = layer["out"]
-        elif "bottleneck" in layer:
-            c = layer["out"]
-        elif "inception" in layer:
-            c = (layer["c1"] + layer["c3"] + layer["c5"]
-                 + layer["pool_proj"])
-        elif "fc" in layer:
-            return {"name": layer["fc"], "in_f": c, "out_f": layer["out"]}
-    raise ValueError("configuration has no fc layer")
+def conv_table(config: Dict[str, Any]) -> List[Dict[str, Any]]:
+    """Every conv of the network in forward order, with its input geometry
+    (``c``, ``h``, ``w``), its output size (``e``, ``f``), its ``groups``,
+    and ``res``: whether a shortcut is added to its output before the
+    ReLU."""
+    return _tables(config)[0]
 
 
-def fc_weight(config: Dict[str, Any], fc_seed: int) -> np.ndarray:
-    """The FC weights the configuration states: N(0, 1/in_f), drawn on the
-    host by ``numpy.random.default_rng(fc_seed)`` as an (in_f, out_f)
-    matrix."""
-    fc = fc_layer(config)
+def fc_layers(config: Dict[str, Any]) -> List[Dict[str, Any]]:
+    """The FC layers in forward order: name, fan-in (the flattened map
+    before it), outputs, and whether a ReLU follows."""
+    return _tables(config)[1]
+
+
+@functools.lru_cache(maxsize=None)
+def _weight_maker(config_json: str):
+    config = json.loads(config_json)
+    table = conv_table(config)
+    bias_std = float(config["bias_std"])
+
+    @jax.jit
+    def make(kd):
+        key = wts.weights_key(kd)
+        out = {}
+        for i, cv in enumerate(table):
+            kw, kb = jax.random.split(jax.random.fold_in(key, i))
+            fan_in = cv["c"] // cv["groups"] * cv["k"] * cv["k"]
+            shape = (cv["out"], cv["c"] // cv["groups"], cv["k"], cv["k"])
+            std = (2.0 / fan_in) ** 0.5
+            w = jax.random.normal(kw, shape, jnp.float32) * std
+            b = jax.random.normal(kb, (cv["out"],), jnp.float32) * bias_std
+            out[cv["name"]] = (wts.prune(w, cv["sparsity"]), b)
+        return out
+
+    return make
+
+
+def make_weights(config: Dict[str, Any], seed: int
+                 ) -> Dict[str, Tuple[jax.Array, jax.Array]]:
+    """``{conv name: (w (M, C/groups, K, K), b (M,))}`` on the default
+    device, in one jitted call: conv i draws from the weights stream folded
+    with i, N(0, 2/fan_in) pruned by magnitude at its sparsity, bias
+    N(0, bias_std)."""
+    return _weight_maker(json.dumps(config, sort_keys=True))(
+        jnp.asarray(wts.key_data(seed)))
+
+
+def fc_weights(config: Dict[str, Any], fc_seed: int) -> List[np.ndarray]:
+    """The FC weights the configuration states, in forward order: each
+    N(0, 1/in_f), drawn on the host one after the other from
+    ``numpy.random.default_rng(fc_seed)`` as an (in_f, out_f) matrix."""
     rng = np.random.default_rng(int(fc_seed))
-    return (rng.standard_normal((fc["in_f"], fc["out_f"])).astype(np.float32)
-            * np.float32((1.0 / fc["in_f"]) ** 0.5))
+    return [rng.standard_normal((fc["in_f"], fc["out_f"])).astype(np.float32)
+            * np.float32((1.0 / fc["in_f"]) ** 0.5)
+            for fc in fc_layers(config)]
 
 
-def forward(config: Dict[str, Any], weights: Dict[str, Any], fc_w, x,
+def forward(config: Dict[str, Any], weights: Dict[str, Any], fc_ws, x,
             precision: str = "highest"):
     """Logits (N, classes) of the network on images ``x`` (N, C, H, W).
 
-    ``weights`` maps each conv name to ``(w, b)``, w of shape (M, C, K, K);
-    ``fc_w`` is the (in_f, out_f) FC matrix.  Traceable: jit it with the
-    weights as arguments.
+    ``weights`` maps each conv name to ``(w, b)``, w of shape
+    (M, C/groups, K, K); ``fc_ws`` holds the (in_f, out_f) FC matrices in forward order.
+    Traceable: jit it with the weights as arguments.
     """
     if precision not in PRECISIONS:
         raise ValueError(f"precision {precision!r} not in {PRECISIONS}")
@@ -148,7 +195,8 @@ def forward(config: Dict[str, Any], weights: Dict[str, Any], fc_w, x,
         y = lax.conv_general_dilated(
             v, w.astype(dt), (cv["stride"], cv["stride"]),
             ((cv["pad"], cv["pad"]), (cv["pad"], cv["pad"])),
-            dimension_numbers=("NCHW", "OIHW", "NCHW"), precision=prec,
+            dimension_numbers=("NCHW", "OIHW", "NCHW"),
+            feature_group_count=cv["groups"], precision=prec,
             preferred_element_type=dt)
         y = y + b.astype(dt)[None, :, None, None]
         if shortcut is not None:
@@ -161,11 +209,10 @@ def forward(config: Dict[str, Any], weights: Dict[str, Any], fc_w, x,
             (1, 1, stride, stride), ((0, 0), (0, 0), (pad, pad), (pad, pad)))
 
     v = x.astype(dt)
+    fcs = iter(fc_ws)
     for layer in config["layers"]:
         if "conv" in layer:
-            v = conv(_conv(layer["conv"], layer["out"], layer["k"],
-                           layer["stride"], layer["pad"]), v,
-                     relu=layer.get("relu", True))
+            v = conv(_layer_conv(layer), v, relu=layer.get("relu", True))
         elif "maxpool" in layer:
             v = maxpool(v, layer["maxpool"], layer["stride"], layer["pad"])
         elif "bottleneck" in layer:
@@ -184,6 +231,8 @@ def forward(config: Dict[str, Any], weights: Dict[str, Any], fc_w, x,
         elif "gap" in layer:
             v = v.mean(axis=(2, 3), keepdims=True)
         elif "fc" in layer:
-            v = jnp.matmul(v.reshape(v.shape[0], -1), fc_w.astype(dt),
+            v = jnp.matmul(v.reshape(v.shape[0], -1), next(fcs).astype(dt),
                            precision=prec, preferred_element_type=dt)
+            if layer.get("relu", False):
+                v = jnp.maximum(v, 0)
     return v.astype(jnp.float32)

@@ -3,8 +3,10 @@
 Everything the benchmark imports from the program goes through here: the
 network table and its lowering, the parameter dict that ``CnnEngine`` and
 ``RobustCnnServer`` bind, the engine, the server and the program's own
-telemetry.  The weights are the benchmark's (``perfbench.weights``); the
-program builds its sparse formats from them as ``init_cnn`` would.
+telemetry.  The weights are the benchmark's, drawn by the configuration's
+reference module (``perfbench.references``), whose conv and FC tables the
+program's network is checked against; the program builds its sparse
+formats from them as ``init_cnn`` would.
 """
 from __future__ import annotations
 
@@ -12,9 +14,10 @@ import os
 import sys
 from typing import Any, Dict, List, Tuple
 
+import jax.numpy as jnp
 import numpy as np
 
-from perfbench import reference
+from perfbench import references
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -30,18 +33,22 @@ def import_program():
 
 def network(config: Dict[str, Any]) -> Tuple[List[Any], Any]:
     """The program's layer spec for the configuration, and its lowering at
-    the configuration's input, checked against the configuration: every
-    conv's name, channels, kernel, stride, padding, input size and
-    sparsity must agree, so the program runs the network the file states.
-    """
+    the configuration's input, checked against the configuration's
+    reference: every conv's name, channels, kernel, stride, padding, input
+    size, sparsity and shortcut, and every FC layer's name, fan-in, outputs
+    and ReLU, in order, must agree, so the program runs the network the
+    file states.  A grouped conv of the reference is one conv over all its
+    input channels in the program (``params`` gives it block-diagonal
+    weights)."""
     import_program()
     from repro.engine import lower
     from repro.models import cnn
 
+    ref = references.load(config)
     net = cnn.NETWORKS[config["program_network"]]()
     c, h = config["channels"], config["image"]
     program = lower(net, (c, h, h))
-    want = {cv["name"]: cv for cv in reference.conv_table(config)}
+    want = {cv["name"]: cv for cv in ref.conv_table(config)}
     have = {op.name: op for op in program.conv_ops}
     if set(want) != set(have):
         raise SystemExit(
@@ -57,27 +64,47 @@ def network(config: Dict[str, Any]) -> Tuple[List[Any], Any]:
         if got != exp:
             raise SystemExit(f"conv {name}: program runs {got}, the "
                              f"configuration states {exp}")
-    fc = reference.fc_layer(config)
-    fcs = [(op.name, op.in_f, op.out_f) for op in program.fc_ops]
-    if fcs != [(fc["name"], fc["in_f"], fc["out_f"])]:
-        raise SystemExit(f"program FC layers {fcs} differ from {fc}")
+    relu_of = {op.src for op in program.ops if type(op).__name__ == "ReluOp"}
+    got_fc = [(op.name, op.in_f, op.out_f, op.out in relu_of)
+              for op in program.fc_ops]
+    exp_fc = [(fc["name"], fc["in_f"], fc["out_f"], fc["relu"])
+              for fc in ref.fc_layers(config)]
+    if got_fc != exp_fc:
+        raise SystemExit(f"program FC layers {got_fc} differ from the "
+                         f"configuration's {exp_fc}")
     return net, program
+
+
+def block_diagonal(w, groups: int):
+    """A grouped conv's weights (M, C/g, K, K) as the (M, C, K, K) weights
+    of one conv over all C input channels: output group i reads input
+    group i alone, every other weight is zero."""
+    if groups == 1:
+        return w
+    m, cg = w.shape[0] // groups, w.shape[1]
+    return jnp.concatenate(
+        [jnp.pad(w[i * m:(i + 1) * m],
+                 ((0, 0), (i * cg, (groups - 1 - i) * cg), (0, 0), (0, 0)))
+         for i in range(groups)])
 
 
 def params(config: Dict[str, Any], weights: Dict[str, Any], fc_seed: int
            ) -> Dict[str, Any]:
     """The parameter dict the program binds, from the benchmark's weights:
-    dense ``w`` and ``b`` on the device, and for each pruned conv the ELL
-    banks the program builds from ``w`` (as ``init_cnn`` does)."""
+    dense ``w`` and ``b`` on the device (a grouped conv's ``w``
+    block-diagonal), for each pruned conv the ELL banks the program builds
+    from ``w`` (as ``init_cnn`` does), and the FC seed under ``_fc_rng``,
+    from which the program draws the FC weights as the reference's
+    ``fc_weights`` does."""
     import_program()
     from repro.core.sparse_format import ell_from_dense, ell_from_dense_conv
 
     out: Dict[str, Any] = {}
-    for cv in reference.conv_table(config):
+    for cv in references.load(config).conv_table(config):
         w, b = weights[cv["name"]]
-        entry = {"w": w, "b": b}
+        entry = {"w": block_diagonal(w, cv.get("groups", 1)), "b": b}
         if cv["sparsity"] > 0:
-            wh = np.asarray(w)
+            wh = np.asarray(entry["w"])
             entry["ell"] = ell_from_dense_conv(wh)
             entry["ell2d"] = ell_from_dense(wh.reshape(wh.shape[0], -1))
         out[cv["name"]] = entry
@@ -116,7 +143,7 @@ def build(config: Dict[str, Any], seed: int):
     it binds: ``(weights, fc_seed, net, program, params)``."""
     from perfbench import weights as wts
 
-    w = wts.make_weights(config, seed)
+    w = references.load(config).make_weights(config, seed)
     fcs = wts.fc_seed(seed)
     net, program = network(config)
     return w, fcs, net, program, params(config, w, fcs)

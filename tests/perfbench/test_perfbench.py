@@ -20,7 +20,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
     __file__))))
 sys.path.insert(0, ROOT)
 
-from perfbench import (arrivals, counts, harness, peaks, reference,  # noqa: E402
+from perfbench import (arrivals, counts, harness, peaks, references,  # noqa: E402
                        system, trace)
 
 BENCH = harness.load_json(os.path.join(ROOT, "BENCHMARK.json"))
@@ -33,9 +33,12 @@ def _config(name):
                                           name + ".json"))
 
 
+def _table(config):
+    return references.load(config).conv_table(config)
+
+
 def _conv(config, name):
-    return next(cv for cv in reference.conv_table(config)
-                if cv["name"] == name)
+    return next(cv for cv in _table(config) if cv["name"] == name)
 
 
 # -- counts ----------------------------------------------------------------
@@ -61,7 +64,7 @@ def test_forward_flops_match_the_network_tables():
     """ResNet-50 at 224 px: 7.71 GFLOP a dense image, about 3.85 once the
     res3-res5 convs keep 30% of their weights (FC included)."""
     cfg = _config("resnet50-224")
-    table = reference.conv_table(cfg)
+    table = _table(cfg)
     dense = {cv["name"]: cv["out"] * cv["c"] * cv["k"] ** 2 for cv in table}
     pruned = {k: v - round(_conv(cfg, k)["sparsity"] * v)
               for k, v in dense.items()}
@@ -80,7 +83,7 @@ def test_mfu_cannot_pass_100_percent_under_the_dense_method():
     read = harness.load_reader("mfu.offline")
     for name in ("resnet50-224", "googlenet-224"):
         cfg = _config(name)
-        table = reference.conv_table(cfg)
+        table = _table(cfg)
         dense = {cv["name"]: cv["out"] * cv["c"] * cv["k"] ** 2
                  for cv in table}
         pruned = {k: v - round(_conv(cfg, k)["sparsity"] * v)
@@ -106,7 +109,7 @@ def test_kernel_roofline_takes_each_kernels_layers_and_events():
     import types
 
     cfg = _config("resnet50-224")
-    table = reference.conv_table(cfg)
+    table = _table(cfg)
     nnz = {cv["name"]: cv["out"] * cv["c"] * cv["k"] ** 2 for cv in table}
     method = {"res3a/3x3": "pallas", "res3b/3x3": "pallas",
               "res4b/1x1b": "bsr"}
@@ -244,13 +247,59 @@ def test_idle_gaps_go_to_the_shortest_covering_annotation():
 
 # -- the configurations and the program ------------------------------------
 
-@pytest.mark.parametrize("name", ["resnet50-224", "googlenet-224"])
+@pytest.mark.parametrize("name", ["resnet50-224", "googlenet-224",
+                                  "alexnet-227"])
 def test_configuration_is_the_network_the_program_runs(name):
     cfg = _config(name)
     _, program = system.network(cfg)
-    pruned = [cv for cv in reference.conv_table(cfg) if cv["sparsity"] > 0]
-    assert len(pruned) == {"resnet50-224": 39, "googlenet-224": 49}[name]
-    assert len(program.conv_ops) == len(reference.conv_table(cfg))
+    ref = references.load(cfg)
+    pruned = [cv for cv in ref.conv_table(cfg) if cv["sparsity"] > 0]
+    assert len(pruned) == {"resnet50-224": 39, "googlenet-224": 49,
+                           "alexnet-227": 4}[name]
+    assert len(program.conv_ops) == len(ref.conv_table(cfg))
+    fcs = [(fc["in_f"], fc["out_f"], fc["relu"]) for fc in ref.fc_layers(cfg)]
+    assert fcs == {"resnet50-224": [(2048, 1000, False)],
+                   "googlenet-224": [(1024, 1000, False)],
+                   "alexnet-227": [(9216, 4096, True), (4096, 4096, True),
+                                   (4096, 1000, False)]}[name]
+    grouped = [(cv["name"], cv["groups"]) for cv in ref.conv_table(cfg)
+               if cv["groups"] != 1]
+    assert grouped == {"resnet50-224": [], "googlenet-224": [],
+                       "alexnet-227": [("conv2", 2), ("conv4", 2),
+                                       ("conv5", 2)]}[name]
+
+
+def test_a_grouped_conv_is_a_block_diagonal_conv():
+    """The program has no grouped conv: it is given Caffe's group-2 convs
+    as one conv over all input channels with block-diagonal weights, which
+    must compute what the grouped conv computes."""
+    import jax
+    from jax import lax
+
+    kw, kx = jax.random.split(jax.random.PRNGKey(3))
+    w = jax.random.normal(kw, (6, 2, 3, 3))          # 6 out, 2 groups of 2
+    x = jax.random.normal(kx, (2, 4, 5, 5))
+    full = system.block_diagonal(w, 2)
+    assert full.shape == (6, 4, 3, 3)
+    assert not full[:3, 2:].any() and not full[3:, :2].any()
+
+    def conv(w, g):
+        return lax.conv_general_dilated(
+            x, w, (1, 1), ((1, 1), (1, 1)),
+            dimension_numbers=("NCHW", "OIHW", "NCHW"),
+            feature_group_count=g, precision=lax.Precision.HIGHEST)
+    assert np.allclose(conv(full, 1), conv(w, 2), atol=1e-5)
+    assert system.block_diagonal(w, 1) is w
+
+
+def test_a_configuration_unlike_the_program_is_refused():
+    """The program's AlexNet has a ReLU after fc6; a file that states none
+    is not the network the program runs."""
+    cfg = _config("alexnet-227")
+    cfg["layers"] = [dict(layer, relu=False) if layer.get("fc") == "fc6"
+                     else layer for layer in cfg["layers"]]
+    with pytest.raises(SystemExit, match="FC layers"):
+        system.network(cfg)
 
 
 def test_no_result_without_a_chip():
@@ -295,9 +344,27 @@ FAULTS = {"none": None, "answers_swapped": _swap_rows,
           "half_batch_left_out": _half_batch, "one_logit_moved": _one_logit}
 
 
+def _break_timed_path(monkeypatch, fault):
+    """Every forward of ``CnnEngine`` returns its logits with ``fault``."""
+    system.import_program()
+    from repro.engine import CnnEngine
+
+    if FAULTS[fault] is not None:
+        call = CnnEngine.__call__
+
+        def broken(self, x, *a, **k):
+            return FAULTS[fault](call(self, x, *a, **k))
+        monkeypatch.setattr(CnnEngine, "__call__", broken)
+
+
+# The smallest input each configuration allows: AlexNet's three pools
+# collapse a map below 67 px.
+SMALLEST_IMAGE = {"alexnet-227": 67}
+
+
 def _small_run(cell, seconds):
     files = copy.deepcopy(harness.cell_files(cell, BENCH))
-    files["config"]["image"] = 32
+    files["config"]["image"] = SMALLEST_IMAGE.get(files["config"]["name"], 32)
     if files["traffic"]["kind"] == "closed_loop":
         files["traffic"].update(batch=2, pool_batches=2)
     else:
@@ -311,20 +378,13 @@ def _small_run(cell, seconds):
 @pytest.mark.parametrize("fault", list(FAULTS))
 @pytest.mark.parametrize("cell, kind", [
     ("resnet50-offline-b32", "closed_loop"),
-    ("resnet50-server-poisson", "open_loop")])
+    ("resnet50-server-poisson", "open_loop"),
+    ("alexnet-offline-b32", "closed_loop")])
 def test_broken_timed_path_is_not_correct(monkeypatch, cell, kind, fault):
     import importlib
 
-    system.import_program()
-    from repro.engine import CnnEngine
-
     _dense_plans(monkeypatch)
-    if FAULTS[fault] is not None:
-        call = CnnEngine.__call__
-
-        def broken(self, x, *a, **k):
-            return FAULTS[fault](call(self, x, *a, **k))
-        monkeypatch.setattr(CnnEngine, "__call__", broken)
+    _break_timed_path(monkeypatch, fault)
     run = _small_run(cell, seconds=0.5)
     importlib.import_module("perfbench.kinds." + kind).run(run)
     e2e, per = harness.cell_metrics(cell, BENCH)
@@ -334,6 +394,48 @@ def test_broken_timed_path_is_not_correct(monkeypatch, cell, kind, fault):
     names = {m["name"] for m in e2e}
     assert set(out["metrics"]) == names and "setup_s" in names
     assert all(math.isfinite(out["metrics"][k]["value"]) for k in names)
+
+
+# -- an architecture is its own files --------------------------------------
+
+TOY = os.path.join(ROOT, "tests", "perfbench", "fixtures", "toy-avgpool.json")
+
+
+@pytest.mark.parametrize("fault", list(FAULTS))
+def test_an_architecture_is_its_own_files(monkeypatch, fault):
+    """A configuration whose reference module lives only with the tests, in
+    a layer vocabulary the shared CNN reference does not parse (stages of
+    conv and average pool), runs through the offline kind, the counts and
+    the comparison as they are.  The program's network for it stands in
+    for the one a change to the program would add."""
+    from perfbench.kinds import closed_loop
+    from repro.models import cnn
+    from repro.models.cnn import FC, Conv, Pool, Relu
+
+    monkeypatch.setitem(cnn.NETWORKS, "toy_avgpool", lambda: [
+        Conv("s1", 8, 3, 1, 1, sparsity=0.5), Relu(), Pool("avg", 2, 2),
+        Conv("s2", 16, 3, 1, 1, sparsity=0.6), Relu(), Pool("avg", 2, 2),
+        FC("fc", 10)])
+    _dense_plans(monkeypatch)
+    _break_timed_path(monkeypatch, fault)
+    traffic = harness.load_json(os.path.join(ROOT, "perfbench", "traffic",
+                                             "offline-b32.json"))
+    run = harness.Run(
+        cell={"name": "toy-offline-b2", "chips": 1},
+        config=harness.load_json(TOY),
+        traffic=dict(traffic, batch=2, pool_batches=2),
+        params={"limits": {"max_err": 2e-5}}, seed=2**32 + 17, seconds=0.5,
+        traced=False, t_start=time.perf_counter(), device_kind="cpu")
+    closed_loop.run(run)
+    e2e = [m for m in BENCH["end_to_end"]
+           if m["name"] in ("images_per_s", "setup_s")]
+    out = harness.result(run, e2e, [])
+    assert out["correct"] is (fault == "none"), out["checks"]
+    nnz = run.data["nnz"]
+    assert 0 < nnz["s1"] < 8 * 3 * 9 and 0 < nnz["s2"] < 16 * 8 * 9
+    # s1 at 16x16, s2 at 8x8, the FC 256 -> 10; a batch of 2.
+    assert run.data["flops_per_forward"] == 2 * 2 * (
+        nnz["s1"] * 256 + nnz["s2"] * 64 + 256 * 10)
 
 
 # -- the control: the reference one precision down, in the program's place --
@@ -346,14 +448,13 @@ def _reference_in_the_programs_place(monkeypatch, config, precision):
     system.import_program()
     from repro.engine import CnnEngine
 
-    fwd = jax.jit(lambda w, f, x: reference.forward(config, w, f, x,
-                                                    precision))
+    forward = references.load(config).forward
+    fwd = jax.jit(lambda w, f, x: forward(config, w, f, x, precision))
 
     def control(self, x, *_a, **_k):
         w = {name: (e["w"], e["b"]) for name, e in self.params.items()
              if isinstance(e, dict)}
-        (fc_w,) = self.fc_weights.values()
-        return fwd(w, fc_w, x)
+        return fwd(w, list(self.fc_weights.values()), x)
     monkeypatch.setattr(CnnEngine, "__call__", control)
 
 
